@@ -1,10 +1,11 @@
 """Native host runtime (C++ via ctypes).
 
 The reference's host hot loops are C++ (rDSN runtime + server codecs);
-ours live here. The library builds on first import with the toolchain in
-the image (g++); everything degrades gracefully to the pure-Python paths
-when the toolchain or the build is unavailable — `available()` says which
-mode is active.
+ours live here. The library builds on first use with the toolchain in
+the image (g++). The pure-Python paths stay for the tests that compare
+against them; a failed build prints the compiler's stderr once, and
+`available()` says which mode is active (bench.py and chip_smoke.py
+refuse to run without the library).
 """
 
 from __future__ import annotations
@@ -12,8 +13,9 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import sys
 import threading
-from typing import Optional
+from typing import Optional, Sequence
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "packer.cpp")
@@ -24,20 +26,33 @@ _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _build() -> bool:
+def compile_shared(src: str, so_path: str, opt: str,
+                   libs: Sequence[str] = (), timeout: float = 180) -> bool:
+    """g++ `src` into the shared library `so_path` (through a
+    per-process temp name, so concurrent builders cannot interleave). A
+    failure prints what the compiler said — callers try at most once
+    per process — and returns False."""
+    tmp = f"{so_path}.{os.getpid()}.tmp"
+    cmd = ["g++", opt, "-shared", "-fPIC", "-std=c++17", src, "-o", tmp,
+           *libs]
     try:
-        tmp = f"{_SO}.{os.getpid()}.tmp"  # per-process: concurrent
-        # builders must not interleave writes into one tmp file
-        result = subprocess.run(
-            ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC,
-             "-o", tmp, "-ldl"],
-            capture_output=True, timeout=120)
-        if result.returncode != 0:
-            return False
-        os.replace(tmp, _SO)
-        return True
-    except (OSError, subprocess.TimeoutExpired):
+        result = subprocess.run(cmd, capture_output=True, timeout=timeout)
+    except (OSError, subprocess.TimeoutExpired) as exc:
+        print(f"native build of {os.path.basename(so_path)} failed: "
+              f"{exc!r}", file=sys.stderr, flush=True)
         return False
+    if result.returncode != 0:
+        print(f"native build of {os.path.basename(so_path)} failed "
+              f"(exit {result.returncode}):\n"
+              f"{result.stderr.decode(errors='replace')}",
+              file=sys.stderr, flush=True)
+        return False
+    os.replace(tmp, so_path)
+    return True
+
+
+def _build() -> bool:
+    return compile_shared(_SRC, _SO, "-O3", libs=("-ldl",), timeout=120)
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -131,7 +146,9 @@ def _load() -> Optional[ctypes.CDLL]:
                 return None
             try:
                 _lib = bind()
-            except (OSError, AttributeError):
+            except (OSError, AttributeError) as exc:
+                print(f"native library rebuilt but not loadable: {exc!r}",
+                      file=sys.stderr, flush=True)
                 return None
         return _lib
 

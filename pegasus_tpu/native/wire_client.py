@@ -10,7 +10,6 @@ from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 import threading
 from typing import Optional, Tuple
 
@@ -24,18 +23,9 @@ _tried = False
 
 
 def _build() -> bool:
-    try:
-        tmp = f"{_SO}.{os.getpid()}.tmp"
-        result = subprocess.run(
-            ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", _SRC,
-             "-o", tmp],
-            capture_output=True, timeout=180)
-        if result.returncode != 0:
-            return False
-        os.replace(tmp, _SO)
-        return True
-    except (OSError, subprocess.TimeoutExpired):
-        return False
+    from pegasus_tpu.native import compile_shared
+
+    return compile_shared(_SRC, _SO, "-O2")
 
 
 def load() -> Optional[ctypes.CDLL]:

@@ -40,9 +40,9 @@ def radius_filter(lats: np.ndarray, lngs: np.ndarray,
 
     Every search moves its whole candidate batch to the eval device and
     the mask back, so placement follows the shared link probe
-    (ops/placement.py): co-located accelerators run it on-chip; behind a
-    high-latency tunnel the same program runs on the host XLA backend
-    instead of paying two link round-trips per query."""
+    (ops/placement.py): an accelerator on this host runs it on-chip;
+    where a round-trip costs milliseconds the same program runs on the
+    host XLA backend instead of paying two of them per query."""
     import contextlib
 
     from pegasus_tpu.ops.placement import choose_eval_device
@@ -58,8 +58,8 @@ def radius_filter(lats: np.ndarray, lngs: np.ndarray,
     lo[:n] = lngs
     va[:n] = True if valid is None else valid
     # per-query latency-bound movement (two link round-trips per search):
-    # "ttl"-class placement — host XLA unless the accelerator is
-    # co-located
+    # "ttl"-class placement — host XLA unless the accelerator's
+    # round-trip is sub-millisecond-cheap
     dev = choose_eval_device(workload="ttl")
     ctx = contextlib.nullcontext()
     if dev is not None:

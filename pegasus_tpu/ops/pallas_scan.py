@@ -14,7 +14,8 @@ columns travel as [1, B] rows. The dynamic per-record sortkey offset is
 resolved with iota masks (position == offset) instead of gathers, which
 TPUs hate.
 
-Falls back to interpret mode off-TPU (tests run it on CPU).
+Compiles for the TPU through Mosaic. `interpret=True` is an explicit
+argument that only the CPU tests pass.
 """
 
 from __future__ import annotations
@@ -142,7 +143,7 @@ def fused_scan_block(block: RecordBlock, now: int,
                      sort_filter: Optional[FilterSpec] = None,
                      pidx: int = 0, partition_version: int = -1,
                      validate_hash: bool = False,
-                     interpret: Optional[bool] = None,
+                     interpret: bool = False,
                      prepared: Optional[Tuple] = None
                      ) -> Tuple[np.ndarray, np.ndarray]:
     """Returns (keep, expired) bool arrays for the block.
@@ -161,8 +162,6 @@ def fused_scan_block(block: RecordBlock, now: int,
         ets = np.asarray(block.expire_ts)
         expired = (ets > 0) & (ets <= np.uint32(now)) & valid
         return np.zeros_like(valid), expired
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     if prepared is None:
         prepared = prepare_transposed(block)
     keys_t, klen, hklen, ets, valid, hashlo = prepared

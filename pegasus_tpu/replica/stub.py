@@ -546,7 +546,7 @@ class ReplicaStub:
             (ops/placement.py offload_breakdown) plus the live
             cost-model drift audit, operator-visible instead of
             PERF.md-only. The `mesh` block is the resident SPMD
-            serving layer: verdict share, tunnel health, watchdog
+            serving layer: verdict share, dispatch health, watchdog
             state. The breakdown's `compact` block is the compaction
             FILTER stage's mesh-vs-host verdict (drift class
             `mesh_compact`); pass n_windows to model a specific

@@ -11,8 +11,15 @@ Run:  python -m pegasus_tpu.server.node_main --config cluster.json --name node0
 cluster.json:
     {"data_root": "/path",
      "nodes": {"meta":  {"host": "127.0.0.1", "port": 34601, "role": "meta"},
-               "node0": {"host": "127.0.0.1", "port": 34801, "role": "replica"},
+               "node0": {"host": "127.0.0.1", "port": 34801, "role": "replica",
+                         "device": "tpu"},
                ...}}
+
+A replica node's optional "device" entry (default "cpu") names the JAX
+platform the launcher gave this process. The node logs what JAX found
+at boot and refuses to boot if it was given an accelerator and found
+another platform: serving on the host while the operator believes the
+chip is in use is the failure this check exists for.
 """
 
 from __future__ import annotations
@@ -35,11 +42,31 @@ def address_book(cfg: dict) -> dict:
             for name, n in cfg["nodes"].items()}
 
 
+def claim_device(name: str, want: str) -> None:
+    """Bring the JAX backend up now, say what it is, and exit unless it
+    is the platform `cluster.json` gave this node."""
+    from pegasus_tpu.utils.compile_cache import configure_compile_cache
+
+    cache_dir = configure_compile_cache()
+    import jax
+
+    devs = jax.devices()
+    print(f"[{name}] jax platform={devs[0].platform} "
+          f"device_kind={devs[0].device_kind!r} devices={len(devs)} "
+          f"compile_cache={cache_dir}", flush=True)
+    if devs[0].platform != want:
+        raise SystemExit(
+            f"[{name}] cluster.json gives this node device {want!r} but "
+            f"jax found platform {devs[0].platform!r}: refusing to boot")
+
+
 def run_node(cfg: dict, name: str) -> None:
     from pegasus_tpu.rpc.transport import TcpTransport
 
     node_cfg = cfg["nodes"][name]
     role = node_cfg["role"]
+    if role == "replica":
+        claim_device(name, node_cfg.get("device", "cpu"))
     data_root = cfg["data_root"]
     book = address_book(cfg)
     transport = TcpTransport((node_cfg["host"], node_cfg["port"]), book)

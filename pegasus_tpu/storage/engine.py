@@ -329,15 +329,17 @@ class StorageEngine:
         # untouched
         mesh_masks = None
         if entries:
+            from pegasus_tpu.parallel.mesh_resident import MESH_SERVING
             try:
-                from pegasus_tpu.parallel.mesh_resident import MESH_SERVING
                 mesh_masks = MESH_SERVING.try_compact_masks(
                     self.lsm, entries, now_s, default_ttl, pidx,
                     partition_version, do_validate, operations,
                     want_ets=ttl_may_change,
                     n_windows=window_count(len(entries)))
-            except Exception:
-                mesh_masks = None
+            except Exception:  # noqa: BLE001 - the host filter stage
+                # below produces the identical masks; the failure is
+                # logged and counted as a mesh fallback, never dropped
+                MESH_SERVING.note_compact_failure()
         meta = {
             # snapshot mode: the output only covers decrees flushed at
             # freeze time — claiming last_committed would make boot skip

@@ -170,15 +170,16 @@ def default_rules() -> List[HealthRule]:
                    "routing is deciding on bad estimates "
                    "(server/workload.DRIFT audits every stacked "
                    "mask-eval wave)"),
-        HealthRule("tunnel_wedged", "storage", "tunnel_wedged",
+        HealthRule("mesh_dispatch_wedged", "storage",
+                   "mesh_dispatch_wedged",
                    kind="threshold", threshold=0.5, hold=2,
                    severity=SEV_DEGRADED,
                    description="the mesh dispatch watchdog tripped "
-                   "(consecutive bounded-deadline overruns): serving "
-                   "fell back to the CPU-device mesh or to host "
-                   "kernels — results stay correct but the accelerator "
-                   "leg is out (hold=2: one spurious deadline alone "
-                   "must not fire it)"),
+                   "(consecutive bounded-deadline overruns or raising "
+                   "dispatches): mesh serving is disabled and the "
+                   "per-partition kernels carry — results stay correct "
+                   "but the whole-table program is out (hold=2: one "
+                   "spurious deadline alone must not fire it)"),
         HealthRule("tenant_brownout", "tenant", "tenant_cu_ratio",
                    kind="burn_rate", threshold=2.0, window_s=30.0,
                    min_points=2, hold=2, clear_hold=2,

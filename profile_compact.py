@@ -4,12 +4,11 @@
 # (bench.build_compact_store) so the synthetic-SST layout lives in ONE
 # place. CPU-only by default; PEGPROF_DEVICE=accel places eval on the
 # ambient accelerator. PEGPROF_PROFILE=1 wraps the pass in cProfile.
-"""`--mesh` is a fast no-accelerator selftest (the compaction twin of
-profile_tunnel --watchdog-selftest): over a forced 8-CPU-device mesh it
-proves one whole-table dispatch serves every partition's drop masks
-byte-identically to the host filter stage, that a wedged watchdog
-degrades to host filtering, and exits 0 on PASS — CI-drivable without
-hardware."""
+"""`--mesh` is a fast no-accelerator selftest: over a forced
+8-CPU-device mesh it proves one whole-table dispatch serves every
+partition's drop masks byte-identically to the host filter stage, that
+a wedged watchdog degrades to host filtering, and exits 0 on PASS —
+CI-drivable without hardware."""
 import os
 import sys
 import time
@@ -99,8 +98,7 @@ if "--mesh" in sys.argv[1:]:
         sys.exit(0)
 
 if os.environ.get("PEGPROF_DEVICE", "cpu") == "cpu":
-    from pegasus_tpu.utils.cpu_isolation import force_cpu
-    force_cpu()
+    os.environ["JAX_PLATFORMS"] = "cpu"  # before bench imports jax
 
 import bench as B  # noqa: E402
 

@@ -4,7 +4,7 @@ masks (and rewritten-TTL column) BYTE-IDENTICALLY to the host-serial
 and host-pipelined filter stages over every store shape — mixed
 none/dcz/dcz2 histories, empty-hashkey overflow rows, verbatim-carry
 blocks, default-TTL rewrites and user rulesets — degrade through the
-tunnel watchdog to host filtering with identical published files, and
+dispatch watchdog to host filtering with identical published files, and
 close the publish loop by survivor-gathering residency (reuse counter)
 instead of restaging every block (rebuild counter)."""
 

@@ -4,7 +4,7 @@ aggregates BYTE-IDENTICALLY to the host kernels over every store shape
 (stores written under mixed none/dcz/dcz2 codecs, empty-hashkey
 overflow rows, unflushed overlay), refresh incrementally at
 flush/compaction publish (never serving a stale image), and degrade
-through the tunnel watchdog to host serving with zero hung scans when
+through the dispatch watchdog to host serving with zero hung scans when
 dispatches overrun their deadline."""
 
 import os
@@ -253,7 +253,7 @@ def test_watchdog_trip_degrades_to_host_mid_scan(tmp_path, mesh_guard,
         force_mesh_pays(monkeypatch)
         attach_all(table)
         # every dispatch now overruns: the second consecutive failure
-        # must trip the tunnel; on the CPU mesh a trip disables mesh
+        # must trip the watchdog; a trip disables mesh
         # serving outright and the host kernels carry the rest
         MESH_SERVING.watchdog.deadline_s = 1e-9
         t0 = time.monotonic()
@@ -264,7 +264,7 @@ def test_watchdog_trip_degrades_to_host_mid_scan(tmp_path, mesh_guard,
         st = MESH_SERVING.status()
         assert st["mesh_fallback_count"] >= 2
         assert st["watchdog"]["trips"] >= 1
-        assert st["tunnel_wedged"] is True
+        assert st["dispatch_wedged"] is True
         assert MESH_SERVING.disabled and not MESH_SERVING.enabled
         # wedged is a verdict, not a wedge: later scans still correct
         clear_mask_caches(table)
@@ -363,6 +363,7 @@ def test_mesh_metrics_lint_and_health_rule():
     from pegasus_tpu.tools.metrics_lint import lint
     from pegasus_tpu.utils.health import default_rules
 
-    assert not [c for c in lint() if "mesh" in c or "tunnel" in c]
-    rules = [r for r in default_rules() if r.name == "tunnel_wedged"]
+    assert not [c for c in lint() if "mesh" in c or "wedged" in c]
+    rules = [r for r in default_rules()
+             if r.name == "mesh_dispatch_wedged"]
     assert len(rules) == 1 and rules[0].hold == 2
