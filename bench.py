@@ -1077,140 +1077,6 @@ def measure_pipelined_compact(jax, device, tmpdir, gb: float,
     return out
 
 
-def measure_trace_overhead(tmpdir, seed: int):
-    """Distributed-tracing overhead phase: the SAME batched point-get
-    and write_multi streams through a SimCluster at sample_ratio
-    0 / 0.01 / 1.0, against a hard-disabled no-tracing baseline —
-    same-run, identity-gated (per-mode result digests must match).
-    The acceptance gate: sample_ratio=0 within 2% of the no-tracing
-    baseline on both the read and the write phase (median of 3 reps)."""
-    import hashlib
-    import shutil
-
-    import numpy as np
-
-    from pegasus_tpu.base.key_schema import generate_key, key_hash_parts
-    from pegasus_tpu.base.value_schema import expire_ts_from_ttl
-    from pegasus_tpu.rpc.codec import OP_PUT
-    from pegasus_tpu.tools.cluster import SimCluster
-    from pegasus_tpu.utils import tracing
-    from pegasus_tpu.utils.flags import FLAGS
-
-    n_keys = int(os.environ.get("PEGBENCH_TRACE_KEYS", 512))
-    n_rounds = int(os.environ.get("PEGBENCH_TRACE_ROUNDS", 40))
-    reps = 3
-    batch = 32
-    cdir = os.path.join(tmpdir, "trace_overhead")
-    cluster = SimCluster(cdir, n_nodes=3, seed=seed)
-    try:
-        cluster.create_table("tr", partition_count=4, replica_count=3)
-        client = cluster.client("tr")
-        keys = [(b"tk%05d" % i, b"s") for i in range(n_keys)]
-        # preload so the read stream hits resident data
-        rng = np.random.default_rng(seed)
-        for start in range(0, n_keys, batch):
-            groups = {}
-            for hk, sk in keys[start:start + batch]:
-                ph = key_hash_parts(hk, sk)
-                groups.setdefault(ph % 4, []).append(
-                    (OP_PUT, (generate_key(hk, sk), b"v" * 64,
-                              expire_ts_from_ttl(0)), ph))
-            client.write_multi(groups)
-
-        # ONE fixed op order for every pass: after the warm-up pass the
-        # store sits at this order's write fixed point, so every later
-        # pass reads IDENTICAL state whatever mode ran before it — the
-        # per-mode digests must match exactly
-        order = np.random.default_rng(seed + 1).integers(
-            0, n_keys, size=n_rounds * batch)
-
-        def one_pass(digest):
-            t0 = time.perf_counter()
-            for r in range(n_rounds):
-                groups = {}
-                for j in order[r * batch:(r + 1) * batch]:
-                    hk, sk = keys[int(j)]
-                    ph = key_hash_parts(hk, sk)
-                    groups.setdefault(ph % 4, []).append(
-                        ("get", generate_key(hk, sk), ph))
-                res = client.point_read_multi(groups)
-                for pidx in sorted(res):
-                    for st, val in res[pidx]:
-                        digest.update(b"%d" % st)
-                        digest.update(val)
-            t_read = time.perf_counter() - t0
-            t0 = time.perf_counter()
-            for r in range(n_rounds):
-                groups = {}
-                for j in order[r * batch:(r + 1) * batch]:
-                    hk, sk = keys[int(j)]
-                    ph = key_hash_parts(hk, sk)
-                    groups.setdefault(ph % 4, []).append(
-                        (OP_PUT, (generate_key(hk, sk),
-                                  b"w%d" % r, expire_ts_from_ttl(0)),
-                         ph))
-                res = client.write_multi(groups)
-                for pidx in sorted(res):
-                    for st in res[pidx]:
-                        digest.update(b"%d" % st)
-            t_write = time.perf_counter() - t0
-            return t_read, t_write
-
-        # one unmeasured warm-up pass: absorbs cold caches AND drives
-        # the store to the order's write fixed point, so every measured
-        # pass reads identical state
-        tracing.hard_disable(True)
-        one_pass(hashlib.sha256())
-        modes = [("baseline_off", None), ("ratio_0", 0.0),
-                 ("ratio_0.01", 0.01), ("ratio_1", 1.0)]
-        out = {"keys": n_keys,
-               "ops_per_mode": n_rounds * batch * 2 * reps}
-        ops_n = n_rounds * batch
-        digests = {}
-        times = {name: ([], []) for name, _r in modes}
-        hashes = {name: hashlib.sha256() for name, _r in modes}
-        # modes INTERLEAVE across reps: slow drift (allocator state,
-        # page cache, cpu clocks) hits every mode equally instead of
-        # biasing whichever mode ran last
-        for _rep in range(reps):
-            for name, ratio in modes:
-                tracing.hard_disable(ratio is None)
-                FLAGS.set("pegasus.tracing", "sample_ratio",
-                          ratio or 0.0)
-                tr, tw = one_pass(hashes[name])
-                times[name][0].append(tr)
-                times[name][1].append(tw)
-        for name, _ratio in modes:
-            reads, writes = times[name]
-            digests[name] = hashes[name].hexdigest()
-            out[name] = {
-                "read_qps": round(ops_n * reps / sum(reads), 1),
-                "write_qps": round(ops_n * reps / sum(writes), 1),
-                "read_s_median": round(sorted(reads)[1], 4),
-                "write_s_median": round(sorted(writes)[1], 4),
-            }
-        tracing.hard_disable(False)
-        FLAGS.set("pegasus.tracing", "sample_ratio", 0.0)
-        base = out["baseline_off"]
-        r0 = out["ratio_0"]
-        out["ratio0_read_overhead"] = round(
-            r0["read_s_median"] / base["read_s_median"] - 1.0, 4)
-        out["ratio0_write_overhead"] = round(
-            r0["write_s_median"] / base["write_s_median"] - 1.0, 4)
-        out["identity_ok"] = len(set(digests.values())) == 1
-        # the bench gate: ratio-0 tracing must cost <=2% on both phases
-        out["gate_ok"] = bool(
-            out["identity_ok"]
-            and out["ratio0_read_overhead"] <= 0.02
-            and out["ratio0_write_overhead"] <= 0.02)
-        return out
-    finally:
-        tracing.hard_disable(False)
-        FLAGS.set("pegasus.tracing", "sample_ratio", 0.0)
-        cluster.close()
-        shutil.rmtree(cdir, ignore_errors=True)
-
-
 def measure_health_overhead(tmpdir, seed: int):
     """Flight-recorder overhead phase (round 16): the SAME batched
     point-get and write_multi streams through a SimCluster with the
@@ -1249,9 +1115,9 @@ def measure_health_overhead(tmpdir, seed: int):
                               expire_ts_from_ttl(0)), ph))
             client.write_multi(groups)
 
-        # ONE fixed op order for every pass (see measure_trace_overhead:
-        # the warm-up drives the store to this order's write fixed
-        # point, so every measured pass reads identical state)
+        # ONE fixed op order for every pass: the warm-up drives the
+        # store to this order's write fixed point, so every measured
+        # pass reads identical state
         order = np.random.default_rng(seed + 1).integers(
             0, n_keys, size=n_rounds * batch)
 
@@ -1390,8 +1256,8 @@ def measure_perfctx_overhead(tmpdir, seed: int):
     per-op cost-vector collection hard-OFF vs ON — same-run,
     identity-gated (per-mode result digests must match). The gate:
     contexts-enabled read AND scan paths within 2% of hard-off (median
-    of 3 reps, modes interleaved), per the trace_overhead /
-    health_overhead convention."""
+    of 3 reps, modes interleaved), per the health_overhead
+    convention."""
     import hashlib
     import shutil
 
@@ -2973,7 +2839,6 @@ def main() -> None:
     do_pipeline = os.environ.get("PEGBENCH_PIPELINE", "1") != "0"
     do_mixed = os.environ.get("PEGBENCH_MIXED", "1") != "0"
     do_geo = os.environ.get("PEGBENCH_GEO", "1") != "0"
-    do_trace = os.environ.get("PEGBENCH_TRACE", "1") != "0"
     do_dup = os.environ.get("PEGBENCH_DUP", "1") != "0"
     do_health = os.environ.get("PEGBENCH_HEALTH", "1") != "0"
     do_perfctx = os.environ.get("PEGBENCH_PERFCTX", "1") != "0"
@@ -3508,17 +3373,6 @@ def main() -> None:
                     _log(f"mixed_load: p99 on/off "
                          f"{ml.get('p99_ratio_on_vs_off')}; forward "
                          f"progress={ml['forward_progress_ok']}")
-
-                if do_trace:
-                    to = measure_trace_overhead(tmpdir, seed)
-                    details["phases"]["trace_overhead"] = to
-                    save_details()
-                    _log(f"trace_overhead: ratio-0 read "
-                         f"{to['ratio0_read_overhead']:+.2%} / write "
-                         f"{to['ratio0_write_overhead']:+.2%} vs "
-                         f"no-tracing baseline (gate<=2%: "
-                         f"{to['gate_ok']}, "
-                         f"identical={to['identity_ok']})")
 
                 if do_health:
                     ho = measure_health_overhead(tmpdir, seed)

@@ -243,16 +243,24 @@ class ClusterClient:
                 self._replies[rid] = payload
 
     def _traced(self, name: str, fn, *args):
-        """Run one client op under a sampled root span (or plain when
-        sampling says no / an outer op's span already governs)."""
-        if self._cur_span is not None or not tracing.maybe_sample():
+        """Run one client op under a root span: when sampling says so,
+        or while a jax.profiler session is active (the span tree then
+        rides the device profile). Plain otherwise, or when an outer
+        op's span already governs. The root gets a host frame and is
+        never ambient."""
+        if self._cur_span is not None:
             return fn(*args)
-        span = tracing.ring_for(self.name).start(name)
+        profiled = tracing.profiling()
+        if not profiled and not tracing.maybe_sample():
+            return fn(*args)
+        span = tracing.ring_for(self.name).start(name, profiled=profiled)
         self._cur_span = span
+        tracing.enter(span)
         try:
             return fn(*args)
         finally:
             self._cur_span = None
+            tracing.leave(span)
             span.finish()
 
     def _send_request(self, dst: str, msg_type: str, payload: dict,

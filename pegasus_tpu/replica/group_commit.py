@@ -43,6 +43,7 @@ from __future__ import annotations
 from typing import Callable, Dict, List, Tuple
 
 from pegasus_tpu.utils.flags import FLAGS, define_flag
+from pegasus_tpu.utils.tracing import layer
 
 define_flag("pegasus.replica", "plog_sync_mode", "flush",
             "private-log durability per group-commit window: 'flush' "
@@ -72,6 +73,10 @@ class WriteFlushWindow:
         # (dst, solo_kind) -> [(gpid, payload)]
         self._agg: Dict[Tuple[str, str], list] = {}
         self._group_commit_size = metrics.percentile("group_commit_size")
+        # the same as counters a window can difference: windows that
+        # hardened at least one mutation, and the mutations in them
+        self._windows = metrics.counter("group_commit_windows")
+        self._window_mutations = metrics.counter("group_commit_mutations")
         self._fsync_count = metrics.counter("plog_fsync_count")
         self._prepare_batch_size = metrics.percentile("prepare_batch_size")
 
@@ -152,6 +157,15 @@ class WriteFlushWindow:
     # ---- flush ---------------------------------------------------------
 
     def _flush(self) -> None:
+        if not (self._dirty or self._pending or self._agg):
+            return  # an empty window: most dispatches stage nothing
+        # one scope for the window's close: the shared plog flush, the
+        # after-durable callbacks (whose 2PC stage points take their
+        # intervals out of it) and the aggregated fan-out
+        with layer("repl.window_flush"):
+            self._flush_window()
+
+    def _flush_window(self) -> None:
         self._flushing = True
         mode = FLAGS.get("pegasus.replica", "plog_sync_mode")
         sync = mode == "fsync"
@@ -170,6 +184,8 @@ class WriteFlushWindow:
                         self._fsync_count.increment()
                 if staged:
                     self._group_commit_size.set(staged)
+                    self._windows.increment()
+                    self._window_mutations.increment(staged)
                 cbs = self._pending
                 self._pending = []
                 for cb in cbs:

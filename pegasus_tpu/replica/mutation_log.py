@@ -35,6 +35,13 @@ from typing import Iterable, Iterator, List, Optional, Tuple
 
 from pegasus_tpu.replica.mutation import Mutation
 from pegasus_tpu.storage.framed_log import iter_frames, pack_frame
+from pegasus_tpu.utils.metrics import METRICS
+
+# every hand-over of the private log's append buffer to the OS,
+# whichever sync mode: unbuffered appends, group-commit windows, and
+# the flush a reader forces. (`plog_fsync_count` counts fsyncs only and
+# reads 0 under the default plog_sync_mode=flush.)
+_PLOG_FLUSHES = METRICS.entity("write", "node").counter("plog_flush_count")
 
 
 class MutationLog:
@@ -77,6 +84,7 @@ class MutationLog:
         self._f.write(pack_frame(mu.encode()))
         if flush:
             self._f.flush()
+            _PLOG_FLUSHES.increment()
             if sync:
                 fsync_file(self._f)
         else:
@@ -95,6 +103,7 @@ class MutationLog:
             return
         self._f.write(b"".join(frames))
         self._f.flush()
+        _PLOG_FLUSHES.increment()
         self._buffered = False
         if sync:
             fsync_file(self._f)
@@ -103,6 +112,7 @@ class MutationLog:
         """Make every buffered append durable: one flush, one optional
         fsync, shared by all frames staged since the last commit."""
         self._f.flush()
+        _PLOG_FLUSHES.increment()
         self._buffered = False
         if sync:
             fsync_file(self._f)
@@ -112,6 +122,7 @@ class MutationLog:
         the OS first or they would serve a stale prefix."""
         if self._buffered:
             self._f.flush()
+            _PLOG_FLUSHES.increment()
             self._buffered = False
 
     @staticmethod

@@ -23,6 +23,7 @@ from pegasus_tpu.replica.replica import (
 )
 from pegasus_tpu.server import tenancy
 from pegasus_tpu.server.tenancy import TENANTS
+from pegasus_tpu.utils import tracing
 from pegasus_tpu.utils.errors import StorageCorruptionError
 
 Gpid = Tuple[int, int]  # (app_id, partition_index)
@@ -320,13 +321,11 @@ class ReplicaStub:
         def trace_dump(args):
             # the cross-node stitch's fan-out target: this node's span
             # ring (+ tail-kept traces), optionally one trace only
-            from pegasus_tpu.utils import tracing
 
             return tracing.ring_for(self.name).dump(
                 args[0] if args else None)
 
         def trace_list(args):
-            from pegasus_tpu.utils import tracing
 
             limit = int(args[0]) if args else 16
             return tracing.ring_for(self.name).slow_roots(limit)
@@ -933,7 +932,6 @@ class ReplicaStub:
         for the whole run. Each message keeps its own dispatch span
         parented to its own carried context (the transport's batch
         drain skips the generic per-message join point)."""
-        from pegasus_tpu.utils import tracing
 
         with self.write_window:
             for src, payload in items:
@@ -974,7 +972,6 @@ class ReplicaStub:
             # window. Tracing: every batched item keeps its OWN span
             # parented to its own hop context — N legs in one carrier
             # yield N spans, never N carriers
-            from pegasus_tpu.utils import tracing
 
             kind = ("prepare" if msg_type == "prepare_batch"
                     else "prepare_ack")
@@ -1193,69 +1190,72 @@ class ReplicaStub:
 
         gpid = tuple(payload["gpid"])
         rid = payload["rid"]
-        if self._deadline_expired(payload):
-            # fast-fail BEFORE the 2PC starts: an expired write has not
-            # (and will not) run, so the explicit ERR_TIMEOUT reply is
-            # unambiguous — safe to retry even for atomic ops
-            self.net.send(self.name, src, "client_write_reply", {
-                "rid": rid, "err": int(ErrorCode.ERR_TIMEOUT),
-                "results": []})
-            return
-        r = self.replicas.get(gpid)
-        if not self._client_allowed(r, payload, access="w", src=src):
-            self.net.send(self.name, src, "client_write_reply", {
-                "rid": rid, "err": int(ErrorCode.ERR_ACL_DENY),
-                "results": []})
-            return
-        # CU budget gate (writes are NEVER brownout-shed — the mutation
-        # path degrades last — but an over-budget tenant's writes do
-        # bounce typed-retryable until refill pays the debt down)
-        over = TENANTS.admit(payload.get("tenant"), kind="write")
-        if over:
-            self.net.send(self.name, src, "client_write_reply", {
-                "rid": rid, "err": over, "results": []})
-            return
-        if r is not None and getattr(r, "splitting", False):
-            # write fence during the split's final catch-up (parity: the
-            # reference fences the parent before the count flip)
-            self._split_fence_rejects.increment()
-            self.net.send(self.name, src, "client_write_reply", {
-                "rid": rid, "err": int(ErrorCode.ERR_SPLITTING),
-                "results": []})
-            return
-        if self._dup_fenced(r, payload.get("ops")):
-            # failover-drill fence: the table is draining its
-            # duplication before the flip — typed and RETRYABLE, so an
-            # in-flight client rides its backoff onto the flipped
-            # follower instead of acking a write the drill would strand
-            self._dup_fence_rejects.increment()
-            self.net.send(self.name, src, "client_write_reply", {
-                "rid": rid, "err": int(ErrorCode.ERR_DUP_FENCED),
-                "results": []})
-            return
-        if (r is None or r.status != PartitionStatus.PRIMARY
-                or getattr(r, "restoring", False)
-                or not self.lease_valid()):
-            self.net.send(self.name, src, "client_write_reply", {
-                "rid": rid, "err": int(ErrorCode.ERR_INVALID_STATE),
-                "results": []})
-            return
-        gate = r.server._hash_gate(payload.get("partition_hash"))
-        if gate:
-            self.net.send(self.name, src, "client_write_reply", {
-                "rid": rid, "err": gate, "results": []})
-            return
-        ops = [WriteOp(op, req) for op, req in payload["ops"]]
-        sgate = r.server._write_gate()
-        if sgate:
-            # deny/throttle rejections are STORAGE statuses per op (the
-            # standalone handlers return TryAgain the same way), not
-            # framework routing errors — the caller must see them, not
-            # retry into them
-            self.net.send(self.name, src, "client_write_reply", {
-                "rid": rid, "err": int(ErrorCode.ERR_OK),
-                "results": [sgate] * len(ops)})
-            return
+        # deadline, ACL, tenant budget, split and duplication fences,
+        # primary + lease, hash and throttle gates: one scope
+        with tracing.layer("gate.write"):
+            if self._deadline_expired(payload):
+                # fast-fail BEFORE the 2PC starts: an expired write has not
+                # (and will not) run, so the explicit ERR_TIMEOUT reply is
+                # unambiguous — safe to retry even for atomic ops
+                self.net.send(self.name, src, "client_write_reply", {
+                    "rid": rid, "err": int(ErrorCode.ERR_TIMEOUT),
+                    "results": []})
+                return
+            r = self.replicas.get(gpid)
+            if not self._client_allowed(r, payload, access="w", src=src):
+                self.net.send(self.name, src, "client_write_reply", {
+                    "rid": rid, "err": int(ErrorCode.ERR_ACL_DENY),
+                    "results": []})
+                return
+            # CU budget gate (writes are NEVER brownout-shed — the mutation
+            # path degrades last — but an over-budget tenant's writes do
+            # bounce typed-retryable until refill pays the debt down)
+            over = TENANTS.admit(payload.get("tenant"), kind="write")
+            if over:
+                self.net.send(self.name, src, "client_write_reply", {
+                    "rid": rid, "err": over, "results": []})
+                return
+            if r is not None and getattr(r, "splitting", False):
+                # write fence during the split's final catch-up (parity: the
+                # reference fences the parent before the count flip)
+                self._split_fence_rejects.increment()
+                self.net.send(self.name, src, "client_write_reply", {
+                    "rid": rid, "err": int(ErrorCode.ERR_SPLITTING),
+                    "results": []})
+                return
+            if self._dup_fenced(r, payload.get("ops")):
+                # failover-drill fence: the table is draining its
+                # duplication before the flip — typed and RETRYABLE, so an
+                # in-flight client rides its backoff onto the flipped
+                # follower instead of acking a write the drill would strand
+                self._dup_fence_rejects.increment()
+                self.net.send(self.name, src, "client_write_reply", {
+                    "rid": rid, "err": int(ErrorCode.ERR_DUP_FENCED),
+                    "results": []})
+                return
+            if (r is None or r.status != PartitionStatus.PRIMARY
+                    or getattr(r, "restoring", False)
+                    or not self.lease_valid()):
+                self.net.send(self.name, src, "client_write_reply", {
+                    "rid": rid, "err": int(ErrorCode.ERR_INVALID_STATE),
+                    "results": []})
+                return
+            gate = r.server._hash_gate(payload.get("partition_hash"))
+            if gate:
+                self.net.send(self.name, src, "client_write_reply", {
+                    "rid": rid, "err": gate, "results": []})
+                return
+            ops = [WriteOp(op, req) for op, req in payload["ops"]]
+            sgate = r.server._write_gate()
+            if sgate:
+                # deny/throttle rejections are STORAGE statuses per op (the
+                # standalone handlers return TryAgain the same way), not
+                # framework routing errors — the caller must see them, not
+                # retry into them
+                self.net.send(self.name, src, "client_write_reply", {
+                    "rid": rid, "err": int(ErrorCode.ERR_OK),
+                    "results": [sgate] * len(ops)})
+                return
 
         def reply(results) -> None:
             self.net.send(self.name, src, "client_write_reply", {
@@ -1330,154 +1330,158 @@ class ReplicaStub:
                 "rid": rid, "err": int(ErrorCode.ERR_TIMEOUT),
                 "result": None})
             return
-        from pegasus_tpu.utils import tracing
 
-        # CU budget gate, once for the carrier (one client = one
-        # tenant); accepted items bill the tenant per submitted run
-        # below. Writes stay exempt from brownout shedding.
-        over = TENANTS.admit(payload.get("tenant"), kind="write")
-        if over:
-            self.net.send(self.name, src, "client_write_reply", {
-                "rid": rid, "err": over, "result": None})
-            return
-        wtenant = TENANTS.resolve(payload.get("tenant")).name
-        from pegasus_tpu.server.capacity_units import client_write_units
-
-        groups = payload.get("groups") or []
-        slots: list = []
-        # batching-seam fan-out (write side): every batched item keeps
-        # its own span under the carrier's dispatch span; the shared
-        # 2PC rounds (combined runs) hang off the carrier too
-        carrier = tracing.current_span()
-        state = {"outstanding": 0, "armed": False, "replied": False}
-
-        def maybe_reply() -> None:
-            if (state["armed"] and not state["replied"]
-                    and state["outstanding"] == 0):
-                state["replied"] = True
+        # one scope: the carrier's budget gate, every partition's ACL,
+        # fence and lease gates, every item's deadline, hash and
+        # throttle gates, and the hand-off of each run to 2PC (whose
+        # stage points take their own intervals out of this scope)
+        with tracing.layer("gate.write"):
+            # CU budget gate, once for the carrier (one client = one
+            # tenant); accepted items bill the tenant per submitted run
+            # below. Writes stay exempt from brownout shedding.
+            over = TENANTS.admit(payload.get("tenant"), kind="write")
+            if over:
                 self.net.send(self.name, src, "client_write_reply", {
-                    "rid": rid, "err": ok, "result": slots})
+                    "rid": rid, "err": over, "result": None})
+                return
+            wtenant = TENANTS.resolve(payload.get("tenant")).name
+            from pegasus_tpu.server.capacity_units import client_write_units
 
-        for gpid, items in groups:
-            gpid = tuple(gpid)
-            r = self.replicas.get(gpid)
-            if not self._client_allowed(r, payload, access="w", src=src):
-                slots.append((gpid[1], int(ErrorCode.ERR_ACL_DENY),
-                              None))
-                continue
-            if r is not None and getattr(r, "splitting", False):
-                self._split_fence_rejects.increment()
-                slots.append((gpid[1], int(ErrorCode.ERR_SPLITTING),
-                              None))
-                continue
-            if self._dup_fenced(r):
-                self._dup_fence_rejects.increment()
-                slots.append((gpid[1], int(ErrorCode.ERR_DUP_FENCED),
-                              None))
-                continue
-            if (r is None or r.status != PartitionStatus.PRIMARY
-                    or getattr(r, "restoring", False)
-                    or not self.lease_valid()):
-                slots.append((gpid[1],
-                              int(ErrorCode.ERR_INVALID_STATE), None))
-                continue
-            item_res: list = [None] * len(items)
-            slots.append((gpid[1], ok, item_res))
+            groups = payload.get("groups") or []
+            slots: list = []
+            # batching-seam fan-out (write side): every batched item keeps
+            # its own span under the carrier's dispatch span; the shared
+            # 2PC rounds (combined runs) hang off the carrier too
+            carrier = tracing.current_span()
+            state = {"outstanding": 0, "armed": False, "replied": False}
 
-            def submit(spans, ops_list, replica=r, results=item_res):
-                """One client_write for a combined run; its response
-                list splits back per original item via the spans."""
-                if not ops_list:
-                    return
+            def maybe_reply() -> None:
+                if (state["armed"] and not state["replied"]
+                        and state["outstanding"] == 0):
+                    state["replied"] = True
+                    self.net.send(self.name, src, "client_write_reply", {
+                        "rid": rid, "err": ok, "result": slots})
 
-                def cb(res, spans=spans, results=results) -> None:
-                    off = 0
-                    for i, n in spans:
-                        results[i] = (ok, res[off:off + n])
-                        off += n
-                    state["outstanding"] -= 1
-                    maybe_reply()
-
-                state["outstanding"] += 1
-                try:
-                    with tenancy.bind(wtenant):
-                        replica.client_write(ops_list, cb)
-                    # accepted: bill the tenant at the primary with the
-                    # apply path's per-op math (same single-billing
-                    # rationale as the solo write handler)
-                    TENANTS.charge(wtenant, client_write_units(
-                        [(wo.op, wo.request) for wo in ops_list]))
-                except ReplicaBusyError:
-                    state["outstanding"] -= 1
-                    for i, _n in spans:
-                        results[i] = (int(ErrorCode.ERR_BUSY), [])
-                except (StorageCorruptionError, OSError) as e:
-                    state["outstanding"] -= 1
-                    code = self._on_storage_error(
-                        (replica.server.app_id, replica.server.pidx), e)
-                    for i, _n in spans:
-                        results[i] = (code, [])
-                except (RuntimeError, ValueError):
-                    state["outstanding"] -= 1
-                    for i, _n in spans:
-                        results[i] = (int(ErrorCode.ERR_INVALID_STATE),
-                                      [])
-
-            # runs of batchable ops combine into one client_write (one
-            # mutation); atomic ops ride alone, submission order kept
-            run_spans: list = []
-            run_ops: list = []
-            item_spans: list = []
-            for i, (raw_ops, ph, dl) in enumerate(items):
-                ispan = None
-                if carrier is not None:
-                    # per-item span opened around THIS item's handling
-                    # (gates + its submission leg), so a gated item is
-                    # visibly near-zero and items keep distinct windows
-                    ispan = tracing.child_of(carrier,
-                                             f"op.write.{gpid[1]}")
-                    item_spans.append(ispan)
-                if self._deadline_expired(
-                        {"deadline": dl if dl is not None
-                         else payload.get("deadline")}):
-                    # per-op deadline: THIS op fast-fails before its
-                    # 2PC starts; its window neighbors proceed
-                    item_res[i] = (int(ErrorCode.ERR_TIMEOUT), [])
-                    if ispan is not None:
-                        ispan.tags["gated"] = "deadline"
-                        ispan.finish()
+            for gpid, items in groups:
+                gpid = tuple(gpid)
+                r = self.replicas.get(gpid)
+                if not self._client_allowed(r, payload, access="w", src=src):
+                    slots.append((gpid[1], int(ErrorCode.ERR_ACL_DENY),
+                                  None))
                     continue
-                gate = r.server._hash_gate(ph)
-                if gate:
-                    item_res[i] = (gate, [])
-                    if ispan is not None:
-                        ispan.tags["gated"] = "hash"
-                        ispan.finish()
+                if r is not None and getattr(r, "splitting", False):
+                    self._split_fence_rejects.increment()
+                    slots.append((gpid[1], int(ErrorCode.ERR_SPLITTING),
+                                  None))
                     continue
-                sgate = r.server._write_gate()
-                if sgate:
-                    # deny/throttle are STORAGE statuses per op, same
-                    # as the solo handler's [sgate] * len(ops) reply
-                    item_res[i] = (ok, [sgate] * len(raw_ops))
-                    if ispan is not None:
-                        ispan.tags["gated"] = "throttle"
-                        ispan.finish()
+                if self._dup_fenced(r):
+                    self._dup_fence_rejects.increment()
+                    slots.append((gpid[1], int(ErrorCode.ERR_DUP_FENCED),
+                                  None))
                     continue
-                wos = [WriteOp(op, req) for op, req in raw_ops]
-                atomic = any(wo.op in ATOMIC_OPS for wo in wos)
-                if atomic or len(run_ops) + len(wos) > r.MAX_BATCH_OPS:
-                    submit(run_spans, run_ops)
-                    run_spans, run_ops = [], []
-                if atomic:
-                    submit([(i, len(wos))], wos)
-                    if ispan is not None:
-                        ispan.finish()  # its leg submitted inline
-                else:
-                    run_spans.append((i, len(wos)))
-                    run_ops.extend(wos)
-            submit(run_spans, run_ops)
-            for sp in item_spans:
-                sp.finish()  # idempotent: gated/atomic already closed
+                if (r is None or r.status != PartitionStatus.PRIMARY
+                        or getattr(r, "restoring", False)
+                        or not self.lease_valid()):
+                    slots.append((gpid[1],
+                                  int(ErrorCode.ERR_INVALID_STATE), None))
+                    continue
+                item_res: list = [None] * len(items)
+                slots.append((gpid[1], ok, item_res))
+
+                def submit(spans, ops_list, replica=r, results=item_res):
+                    """One client_write for a combined run; its response
+                    list splits back per original item via the spans."""
+                    if not ops_list:
+                        return
+
+                    def cb(res, spans=spans, results=results) -> None:
+                        off = 0
+                        for i, n in spans:
+                            results[i] = (ok, res[off:off + n])
+                            off += n
+                        state["outstanding"] -= 1
+                        maybe_reply()
+
+                    state["outstanding"] += 1
+                    try:
+                        with tenancy.bind(wtenant):
+                            replica.client_write(ops_list, cb)
+                        # accepted: bill the tenant at the primary with the
+                        # apply path's per-op math (same single-billing
+                        # rationale as the solo write handler)
+                        TENANTS.charge(wtenant, client_write_units(
+                            [(wo.op, wo.request) for wo in ops_list]))
+                    except ReplicaBusyError:
+                        state["outstanding"] -= 1
+                        for i, _n in spans:
+                            results[i] = (int(ErrorCode.ERR_BUSY), [])
+                    except (StorageCorruptionError, OSError) as e:
+                        state["outstanding"] -= 1
+                        code = self._on_storage_error(
+                            (replica.server.app_id, replica.server.pidx), e)
+                        for i, _n in spans:
+                            results[i] = (code, [])
+                    except (RuntimeError, ValueError):
+                        state["outstanding"] -= 1
+                        for i, _n in spans:
+                            results[i] = (int(ErrorCode.ERR_INVALID_STATE),
+                                          [])
+
+                # runs of batchable ops combine into one client_write (one
+                # mutation); atomic ops ride alone, submission order kept
+                run_spans: list = []
+                run_ops: list = []
+                item_spans: list = []
+                for i, (raw_ops, ph, dl) in enumerate(items):
+                    ispan = None
+                    if carrier is not None:
+                        # per-item span opened around THIS item's handling
+                        # (gates + its submission leg), so a gated item is
+                        # visibly near-zero and items keep distinct windows
+                        ispan = tracing.child_of(carrier,
+                                                 f"op.write.{gpid[1]}")
+                        item_spans.append(ispan)
+                    if self._deadline_expired(
+                            {"deadline": dl if dl is not None
+                             else payload.get("deadline")}):
+                        # per-op deadline: THIS op fast-fails before its
+                        # 2PC starts; its window neighbors proceed
+                        item_res[i] = (int(ErrorCode.ERR_TIMEOUT), [])
+                        if ispan is not None:
+                            ispan.tags["gated"] = "deadline"
+                            ispan.finish()
+                        continue
+                    gate = r.server._hash_gate(ph)
+                    if gate:
+                        item_res[i] = (gate, [])
+                        if ispan is not None:
+                            ispan.tags["gated"] = "hash"
+                            ispan.finish()
+                        continue
+                    sgate = r.server._write_gate()
+                    if sgate:
+                        # deny/throttle are STORAGE statuses per op, same
+                        # as the solo handler's [sgate] * len(ops) reply
+                        item_res[i] = (ok, [sgate] * len(raw_ops))
+                        if ispan is not None:
+                            ispan.tags["gated"] = "throttle"
+                            ispan.finish()
+                        continue
+                    wos = [WriteOp(op, req) for op, req in raw_ops]
+                    atomic = any(wo.op in ATOMIC_OPS for wo in wos)
+                    if atomic or len(run_ops) + len(wos) > r.MAX_BATCH_OPS:
+                        submit(run_spans, run_ops)
+                        run_spans, run_ops = [], []
+                    if atomic:
+                        submit([(i, len(wos))], wos)
+                        if ispan is not None:
+                            ispan.finish()  # its leg submitted inline
+                    else:
+                        run_spans.append((i, len(wos)))
+                        run_ops.extend(wos)
+                submit(run_spans, run_ops)
+                for sp in item_spans:
+                    sp.finish()  # idempotent: gated/atomic already closed
         state["armed"] = True
         maybe_reply()
 
@@ -1495,7 +1499,8 @@ class ReplicaStub:
 
         rid = payload["rid"]
         op = payload.get("op", "get")
-        err, r = self._client_read_gate(payload, src)
+        with tracing.layer("gate.read"):
+            err, r = self._client_read_gate(payload, src)
         if err is not None:
             self.net.send(self.name, src, "client_read_reply", {
                 "rid": rid, "err": err, "result": None})
@@ -1505,7 +1510,6 @@ class ReplicaStub:
         srv = r.server
         from pegasus_tpu.replica.replica import PartitionStatus
         from pegasus_tpu.utils import perf_context as perf
-        from pegasus_tpu.utils import tracing
 
         served_by = ("primary" if r.status == PartitionStatus.PRIMARY
                      else "secondary")
@@ -1706,42 +1710,42 @@ class ReplicaStub:
             is_point_read,
             point_read_multi,
         )
-        from pegasus_tpu.utils import tracing
         from pegasus_tpu.utils.errors import ErrorCode
 
         flush: list = []  # (src, payload, replica, span) past the gates
-        for src, payload in items:
-            op = payload.get("op", "get")
-            ctx = payload.get("trace")
-            if not is_point_read(op, payload.get("args")):
-                # solo fallback still gets its dispatch span (the
-                # transport's batch drain skipped the generic one)
-                span = tracing.start_server_span(
-                    self.name, "client_read", ctx)
-                try:
-                    with tracing.activate(span):
-                        self._on_client_read(src, payload)
-                finally:
-                    if span is not None:
-                        span.finish()
-                continue
-            err, r = self._client_read_gate(payload, src)
-            if err is not None:
-                self.net.send(self.name, src, "client_read_reply", {
-                    "rid": payload.get("rid"), "err": err,
-                    "result": None})
-                continue
-            # per-message span parented to its OWN context: a flush
-            # coalesces reads from many independent traces — each op
-            # keeps its span, the flush never becomes one carrier
-            span = tracing.start_server_span(self.name, "client_read", ctx)
-            if span is not None:
-                span.tags["served_by"] = (
-                    "primary" if r.status == PartitionStatus.PRIMARY
-                    else "secondary")
-                span.tags["tenant"] = TENANTS.resolve(
-                    payload.get("tenant")).name
-            flush.append((src, payload, r, span))
+        with tracing.layer("gate.read"):
+            for src, payload in items:
+                op = payload.get("op", "get")
+                ctx = payload.get("trace")
+                if not is_point_read(op, payload.get("args")):
+                    # solo fallback still gets its dispatch span (the
+                    # transport's batch drain skipped the generic one)
+                    span = tracing.start_server_span(
+                        self.name, "client_read", ctx)
+                    try:
+                        with tracing.activate(span):
+                            self._on_client_read(src, payload)
+                    finally:
+                        if span is not None:
+                            span.finish()
+                    continue
+                err, r = self._client_read_gate(payload, src)
+                if err is not None:
+                    self.net.send(self.name, src, "client_read_reply", {
+                        "rid": payload.get("rid"), "err": err,
+                        "result": None})
+                    continue
+                # per-message span parented to its OWN context: a flush
+                # coalesces reads from many independent traces — each op
+                # keeps its span, the flush never becomes one carrier
+                span = tracing.start_server_span(self.name, "client_read", ctx)
+                if span is not None:
+                    span.tags["served_by"] = (
+                        "primary" if r.status == PartitionStatus.PRIMARY
+                        else "secondary")
+                    span.tags["tenant"] = TENANTS.resolve(
+                        payload.get("tenant")).name
+                flush.append((src, payload, r, span))
         if not flush:
             return
         # group by (server, tenant): the transport's flush window
@@ -1825,38 +1829,39 @@ class ReplicaStub:
         slots: list = []
         decrees: list = []  # (pidx, committed decree) for served slots
         ok: list = []  # (slot index, replica, ops)
-        for gpid, ops in groups:
-            gpid = tuple(gpid)
-            # validate BEFORE planning: one malformed op must fail its
-            # own slot, never leave the whole node batch unreplied
-            if not all(len(o) == 3 and is_point_read(o[0], o[1])
-                       for o in ops):
-                slots.append((gpid[1],
-                              int(ErrorCode.ERR_INVALID_PARAMETERS),
-                              None))
-                continue
-            slot_cons = cons
-            if cons is not None:
-                slot_cons = dict(cons, min_decree=max(
-                    int(cons.get("min_decree") or 0),
-                    int(min_decrees.get(gpid[1], 0))))
-            err, r = self._client_read_gate(
-                {"gpid": gpid, "auth": payload.get("auth"),
-                 "deadline": payload.get("deadline"),
-                 "tenant": payload.get("tenant"),
-                 "consistency": slot_cons}, src)
-            if err is not None:
-                slots.append((gpid[1], err, None))
-                continue
-            slots.append((gpid[1], int(ErrorCode.ERR_OK), None))
-            decrees.append((gpid[1], r.last_committed_decree,
-                            "primary" if r.status
-                            == PartitionStatus.PRIMARY else "secondary"))
-            ok.append((len(slots) - 1, r, ops))
+        # every partition's gates of the carrier, one scope
+        with tracing.layer("gate.read"):
+            for gpid, ops in groups:
+                gpid = tuple(gpid)
+                # validate BEFORE planning: one malformed op must fail its
+                # own slot, never leave the whole node batch unreplied
+                if not all(len(o) == 3 and is_point_read(o[0], o[1])
+                           for o in ops):
+                    slots.append((gpid[1],
+                                  int(ErrorCode.ERR_INVALID_PARAMETERS),
+                                  None))
+                    continue
+                slot_cons = cons
+                if cons is not None:
+                    slot_cons = dict(cons, min_decree=max(
+                        int(cons.get("min_decree") or 0),
+                        int(min_decrees.get(gpid[1], 0))))
+                err, r = self._client_read_gate(
+                    {"gpid": gpid, "auth": payload.get("auth"),
+                     "deadline": payload.get("deadline"),
+                     "tenant": payload.get("tenant"),
+                     "consistency": slot_cons}, src)
+                if err is not None:
+                    slots.append((gpid[1], err, None))
+                    continue
+                slots.append((gpid[1], int(ErrorCode.ERR_OK), None))
+                decrees.append((gpid[1], r.last_committed_decree,
+                                "primary" if r.status
+                                == PartitionStatus.PRIMARY else "secondary"))
+                ok.append((len(slots) - 1, r, ops))
         # batching-seam fan-out: each op in the carrier gets its own
         # span parented to the CARRIER's dispatch span — N ops in one
         # carrier yield N child spans, never N carriers
-        from pegasus_tpu.utils import tracing
 
         # one carrier = one client = ONE tenant: bind it ambient around
         # the whole coordinator call so every partition's finish pass
@@ -2131,61 +2136,64 @@ class ReplicaStub:
         ok_servers = []
         slots = []
         decrees = []  # (pidx, committed decree, served_by) per served slot
-        for gpid, reqs in groups:
-            gpid = tuple(gpid)
-            r = self.replicas.get(gpid)
-            if not self._client_allowed(r, payload, access="r", src=src):
-                # auth/ACL is PERMANENT — distinct from stale-primary so
-                # the client doesn't burn retries re-resolving
-                errs = []
-                for _req in reqs:
-                    resp = ScanResponse()
-                    resp.error = int(ErrorCode.ERR_ACL_DENY)
-                    errs.append(resp)
-                slots.append((gpid[1], errs))
-                continue
-            gerr = None
-            if (r is None or getattr(r, "restoring", False)
-                    or not r.ready_to_serve()):
-                gerr = int(ErrorCode.ERR_INVALID_STATE)
-            elif r.status == PartitionStatus.PRIMARY:
-                if not self.lease_valid():
+        # the gates of every partition of the carrier, one scope:
+        # ACL, lease, follower gate, tenant brownout and admit
+        with tracing.layer("gate.read"):
+            for gpid, reqs in groups:
+                gpid = tuple(gpid)
+                r = self.replicas.get(gpid)
+                if not self._client_allowed(r, payload, access="r", src=src):
+                    # auth/ACL is PERMANENT — distinct from stale-primary so
+                    # the client doesn't burn retries re-resolving
+                    errs = []
+                    for _req in reqs:
+                        resp = ScanResponse()
+                        resp.error = int(ErrorCode.ERR_ACL_DENY)
+                        errs.append(resp)
+                    slots.append((gpid[1], errs))
+                    continue
+                gerr = None
+                if (r is None or getattr(r, "restoring", False)
+                        or not r.ready_to_serve()):
                     gerr = int(ErrorCode.ERR_INVALID_STATE)
-            else:
-                # same consistency gate as the point paths: a SECONDARY
-                # serves the scan slot under its lease + watermark, or
-                # bounces it typed so the client re-flies JUST this slot
-                slot_cons = cons
-                if cons is not None:
-                    slot_cons = dict(cons, min_decree=max(
-                        int(cons.get("min_decree") or 0),
-                        int(min_decrees.get(gpid[1], 0))))
-                gerr = self._follower_gate(
-                    r, {"consistency": slot_cons})
-            if gerr is None:
-                # same tenant gates as the point-read path: brownout
-                # sheds only the flagged aggressor, the CU budget
-                # bounces over-budget scans typed-retryable
-                tn = payload.get("tenant")
-                if TENANTS.browned(tn):
-                    self._node_read_shed.increment()
-                    TENANTS.note_shed(tn)
-                    gerr = int(ErrorCode.ERR_BUSY)
+                elif r.status == PartitionStatus.PRIMARY:
+                    if not self.lease_valid():
+                        gerr = int(ErrorCode.ERR_INVALID_STATE)
                 else:
-                    gerr = TENANTS.admit(tn, kind="read") or None
-            if gerr is not None:
-                errs = []
-                for _req in reqs:
-                    resp = ScanResponse()
-                    resp.error = gerr
-                    errs.append(resp)
-                slots.append((gpid[1], errs))
-                continue
-            slots.append((gpid[1], None))
-            decrees.append((gpid[1], r.last_committed_decree,
-                            "primary" if r.status
-                            == PartitionStatus.PRIMARY else "secondary"))
-            ok_servers.append((len(slots) - 1, r.server, reqs))
+                    # same consistency gate as the point paths: a SECONDARY
+                    # serves the scan slot under its lease + watermark, or
+                    # bounces it typed so the client re-flies JUST this slot
+                    slot_cons = cons
+                    if cons is not None:
+                        slot_cons = dict(cons, min_decree=max(
+                            int(cons.get("min_decree") or 0),
+                            int(min_decrees.get(gpid[1], 0))))
+                    gerr = self._follower_gate(
+                        r, {"consistency": slot_cons})
+                if gerr is None:
+                    # same tenant gates as the point-read path: brownout
+                    # sheds only the flagged aggressor, the CU budget
+                    # bounces over-budget scans typed-retryable
+                    tn = payload.get("tenant")
+                    if TENANTS.browned(tn):
+                        self._node_read_shed.increment()
+                        TENANTS.note_shed(tn)
+                        gerr = int(ErrorCode.ERR_BUSY)
+                    else:
+                        gerr = TENANTS.admit(tn, kind="read") or None
+                if gerr is not None:
+                    errs = []
+                    for _req in reqs:
+                        resp = ScanResponse()
+                        resp.error = gerr
+                        errs.append(resp)
+                    slots.append((gpid[1], errs))
+                    continue
+                slots.append((gpid[1], None))
+                decrees.append((gpid[1], r.last_committed_decree,
+                                "primary" if r.status
+                                == PartitionStatus.PRIMARY else "secondary"))
+                ok_servers.append((len(slots) - 1, r.server, reqs))
         if ok_servers:
             from pegasus_tpu.base.value_schema import epoch_now
 
@@ -2641,7 +2649,6 @@ class ReplicaStub:
         # channel so `shell traces --slow` is ONE meta call instead of a
         # cluster-wide fan-out (the full spans still fan out on demand
         # via the trace-dump verb)
-        from pegasus_tpu.utils import tracing
 
         ring = tracing.ring_for(self.name)
         trace_report = {

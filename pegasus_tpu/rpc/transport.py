@@ -672,7 +672,11 @@ class TcpTransport:
                 # a plain attribute safely exposes the CONNECTION the
                 # in-flight message arrived on (see current_session())
                 self._current_session = sess
-                with self.lock, tracing.activate(span):
+                # `rpc.deliver` frames the dispatch of every traced
+                # message (a batch: its first item's context); the
+                # dispatch span and the handler nest inside it
+                with self.lock, tracing.deliver(dst, msg_type, payload), \
+                        tracing.activate(span):
                     if batch is not None:
                         bh(batch)
                     else:

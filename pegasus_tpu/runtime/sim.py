@@ -160,39 +160,42 @@ class SimNetwork:
                 handler = self._handlers.get(dst)
                 if handler is not None and dst not in self._partitioned:
                     self.delivered += 1
-                    # tracing join point (same rule as the TCP
-                    # dispatcher): a sampled request context opens a
-                    # dispatch span; replies/acks only pin tail-keep
-                    span = None
-                    if isinstance(payload, dict):
-                        t_ctx = payload.get("trace")
-                        if t_ctx is not None:
-                            name = msg_type
-                            if msg_type == "replica":
-                                name = f"replica.{payload.get('type')}"
-                            if _tracing.is_reply_type(name):
-                                _tracing.on_inbound_ctx(dst, t_ctx)
-                            else:
-                                span = _tracing.start_server_span(
-                                    dst, name, t_ctx)
-                                if span is not None:
-                                    span.tags["queue_ms"] = round(
-                                        delay * 1000.0, 3)
-                    try:
-                        with _tracing.activate(span):
-                            if _PROFILER.enabled:
-                                # toollet join point (profiler.cpp:
-                                # 90-198): queue delay is the SIM link
-                                # latency; exec is wall time
-                                t0 = _perf_counter()
-                                handler(src, msg_type, payload)
-                                _PROFILER.observe(
-                                    msg_type, delay * 1000.0,
-                                    (_perf_counter() - t0) * 1000.0)
-                            else:
-                                handler(src, msg_type, payload)
-                    finally:
-                        if span is not None:
-                            span.finish()
+                    # tracing join points (same rule as the TCP
+                    # dispatcher): `rpc.deliver` frames every traced
+                    # message's delivery, reply or request; inside it a
+                    # sampled request context opens a dispatch span;
+                    # replies/acks only pin tail-keep
+                    with _tracing.deliver(dst, msg_type, payload):
+                        span = None
+                        if isinstance(payload, dict):
+                            t_ctx = payload.get("trace")
+                            if t_ctx is not None:
+                                name = msg_type
+                                if msg_type == "replica":
+                                    name = f"replica.{payload.get('type')}"
+                                if _tracing.is_reply_type(name):
+                                    _tracing.on_inbound_ctx(dst, t_ctx)
+                                else:
+                                    span = _tracing.start_server_span(
+                                        dst, name, t_ctx)
+                                    if span is not None:
+                                        span.tags["queue_ms"] = round(
+                                            delay * 1000.0, 3)
+                        try:
+                            with _tracing.activate(span):
+                                if _PROFILER.enabled:
+                                    # toollet join point (profiler.cpp:
+                                    # 90-198): queue delay is the SIM
+                                    # link latency; exec is wall time
+                                    t0 = _perf_counter()
+                                    handler(src, msg_type, payload)
+                                    _PROFILER.observe(
+                                        msg_type, delay * 1000.0,
+                                        (_perf_counter() - t0) * 1000.0)
+                                else:
+                                    handler(src, msg_type, payload)
+                        finally:
+                            if span is not None:
+                                span.finish()
 
             self.loop.schedule(delay, deliver)

@@ -88,6 +88,7 @@ from pegasus_tpu.utils.errors import (
     StorageCorruptionError,
     StorageStatus,
 )
+from pegasus_tpu.utils import tracing
 from pegasus_tpu.utils.flags import FLAGS, define_flag
 from pegasus_tpu.utils.metrics import METRICS
 
@@ -129,6 +130,17 @@ _STORAGE_BLOOM_USEFUL = METRICS.entity(
 # observability the stub's ERR_SPLITTING rejects share
 _SPLIT_FENCE_REJECTS = METRICS.entity(
     "storage", "node").counter("split_fence_reject_count")
+
+# what a scan costs against what it gives, as counters a window can
+# difference (the PerfContext holds the same per op): rows the scan
+# paths examined and rows they returned, one add per scan batch; and
+# the memtable + L0 rows _overlay_snapshot walks for every batch
+_SCAN_ROWS_EVALUATED = METRICS.entity(
+    "storage", "node").counter("scan_rows_evaluated")
+_SCAN_ROWS_RETURNED = METRICS.entity(
+    "storage", "node").counter("scan_rows_returned")
+_OVERLAY_ROWS_WALKED = METRICS.entity(
+    "storage", "node").counter("overlay_rows_walked")
 
 
 
@@ -1067,6 +1079,7 @@ class PartitionServer:
             rc_cached = rc.get_many(gid, suid, gen, ukeys)
             rc_hits = len(rc_cached)
             rc_misses = len(ukeys) - rc_hits
+        tracer.add_point("row_cache")
         uniq: dict = {}
         base_pending: list = []  # missed the row cache AND the overlay
         ov_hits = 0
@@ -1089,6 +1102,7 @@ class PartitionServer:
                 continue
             uniq[key] = None  # placeholder until base resolution
             base_pending.append(key)
+        tracer.add_point("overlay_merge")
 
         # disk-bound residue: ONE vectorized full-key hash pass feeds
         # BOTH sidecar probes — one native multi-filter bloom call for
@@ -2251,6 +2265,8 @@ class PartitionServer:
         if tracer is not None:
             tracer.add_point("assemble")
         pruned = pd_stats.get("pruned", 0)
+        _SCAN_ROWS_EVALUATED.increment(limiter.iteration_count)
+        _SCAN_ROWS_RETURNED.increment(len(records))
         pc = tracer.perf if tracer is not None else None
         if pc is not None:
             pc.ops += 1
@@ -2344,6 +2360,8 @@ class PartitionServer:
                         tracer.add_point("pushdown")
                     folded = mesh["folded"]
                     pruned = mesh["pruned"]
+                    _SCAN_ROWS_EVALUATED.increment(mesh["rows_evaluated"])
+                    _SCAN_ROWS_RETURNED.increment(folded)
                     pc = tracer.perf if tracer is not None else None
                     if pc is not None:
                         pc.ops += 1
@@ -2446,6 +2464,8 @@ class PartitionServer:
             tracer.add_point("pushdown")
         folded = state.count - folded0
         pruned = pd_stats.get("pruned", 0)
+        _SCAN_ROWS_EVALUATED.increment(limiter.iteration_count)
+        _SCAN_ROWS_RETURNED.increment(folded)
         pc = tracer.perf if tracer is not None else None
         if pc is not None:
             pc.ops += 1
@@ -2585,9 +2605,12 @@ class PartitionServer:
         validate = validates.pop()
         filter_key = filters.pop()
         vf = vfs.pop()
-        overlay = self._overlay_snapshot(now, validate, filter_key,
-                                         value_filter=vf) \
-            if overlay_count else ([], {})
+        if overlay_count:
+            with tracing.layer("overlay.snapshot"):
+                overlay = self._overlay_snapshot(now, validate, filter_key,
+                                                 value_filter=vf)
+        else:
+            overlay = ([], {})
         # 1 — per request: the block list + boundary bounds, capped a bit
         # beyond batch_size so expiry/hash drops don't starve the page.
         # Plans are CACHED per (range, want-bucket, store generation):
@@ -3214,6 +3237,8 @@ class PartitionServer:
         # (the kernels see whole blocks); survivors vs evaluated is the
         # table's scan SELECTIVITY — what a server-side pushdown saves
         rows_eval = sum(b.count for _r, _bm, b in unique.values())
+        _SCAN_ROWS_EVALUATED.increment(rows_eval)
+        _SCAN_ROWS_RETURNED.increment(total_rows)
         self.workload.note_scan(len(reqs), rows_eval, total_rows)
         pd_pruned = state.get("pushdown_pruned", 0)
         n_pushdown = sum(1 for pd in state.get("pd_list") or ()
@@ -3281,6 +3306,8 @@ class PartitionServer:
                 if key not in merged:
                     merged[key] = (None if value is None
                                    else (value, ets))
+        _OVERLAY_ROWS_WALKED.increment(
+            len(lsm.memtable) + sum(t.total_count for t in lsm.l0))
         out: dict = {}
         for key in sorted(merged):
             if hft != FT_NO_FILTER or sft != FT_NO_FILTER:

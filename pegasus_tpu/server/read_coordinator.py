@@ -89,6 +89,18 @@ def point_read_multi(servers_and_ops: List[Tuple[object, list]],
     units; None (or a None slot) leaves attribution to whatever tenant
     the caller already bound.
     """
+    from pegasus_tpu.utils.tracing import layer
+
+    # one scope for the whole flush: the partitions' stage points
+    # (plan, index probes, decode, finish) take their intervals out of
+    # it; what is left is the grouping and routing here
+    with layer("coord.route"):
+        return _point_read_multi(servers_and_ops, now, deadline, clock,
+                                 tenants)
+
+
+def _point_read_multi(servers_and_ops, now, deadline, clock,
+                      tenants) -> List[list]:
     from pegasus_tpu.base.value_schema import epoch_now, header_length
     from pegasus_tpu.server.page import build_page
 

@@ -46,6 +46,7 @@ from pegasus_tpu.ops.predicates import (
     unpack_masks,
 )
 from pegasus_tpu.ops.record_block import next_bucket
+from pegasus_tpu.utils import tracing
 from pegasus_tpu.utils.metrics import METRICS
 
 _LOG = logging.getLogger("pegasus.scan")
@@ -63,6 +64,15 @@ def scan_multi(servers_and_reqs: List[Tuple[object, list]],
     take the fast path (big overlay, gates, exotic filters) serve
     per-request.
     """
+    # one scope for the whole flush: the partitions' stage points
+    # (plan, decode, assemble, finish) and the overlay / wave scopes
+    # take their intervals out of it; what is left is the grouping and
+    # routing here
+    with tracing.layer("coord.route"):
+        return _scan_multi(servers_and_reqs, now)
+
+
+def _scan_multi(servers_and_reqs, now: int) -> List[list]:
     from pegasus_tpu.server.partition_server import _normalize_filter_key
 
     states = []
@@ -146,6 +156,9 @@ def scan_multi(servers_and_reqs: List[Tuple[object, list]],
             for state, n in fast_refs:
                 state["_served"] = served_all[off:off + n]
                 off += n
+        # the one native call packed every fast request's page: that
+        # interval is assembly, whichever partition's finish comes next
+        tracing.mark("assemble")
 
     out = []
     for server, reqs, sub in states:
@@ -206,11 +219,14 @@ def stacked_block_eval(blocks, validate: bool, pv: int,
         if served is not None:
             yield from served
             return
-    t0 = _time.perf_counter()
-    submitted = list(stacked_block_submit(blocks, validate, pv,
-                                          filter_key))
-    fetched = _fetch_wave([o[2] for o in submitted])
-    measured_s = _time.perf_counter() - t0
+    # from the call of the jitted predicate programs to their masks on
+    # the host
+    with tracing.layer("dispatch.wave"):
+        t0 = _time.perf_counter()
+        submitted = list(stacked_block_submit(blocks, validate, pv,
+                                              filter_key))
+        fetched = _fetch_wave([o[2] for o in submitted])
+        measured_s = _time.perf_counter() - t0
     _audit_kernel_wave(blocks, filter_key, measured_s, perf_ctxs)
     for (group, cap, _dev), packed in zip(submitted, fetched):
         keep_all = unpack_masks(packed, len(group) * cap)
@@ -412,11 +428,12 @@ def _eval_cross_partition_multi(flavors: dict, validate: bool,
 
     t0 = _time.perf_counter()
     submitted = []
-    for group, cap, stacked, pidx in _stacked_chunks(blocks):
-        packed = multi_static_block_predicate_submit(
-            stacked, specs, validate, pidx, pv)
-        submitted.append((group, cap, packed))
-    fetched = _fetch_wave([p for _g, _c, p in submitted])
+    with tracing.layer("dispatch.wave"):
+        for group, cap, stacked, pidx in _stacked_chunks(blocks):
+            packed = multi_static_block_predicate_submit(
+                stacked, specs, validate, pidx, pv)
+            submitted.append((group, cap, packed))
+        fetched = _fetch_wave([p for _g, _c, p in submitted])
     # the multi-flavor wave audits like the single-flavor one: any
     # filtered flavor makes it the "rules" class (its compute bound)
     audit_fkey = next(

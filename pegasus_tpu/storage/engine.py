@@ -35,6 +35,7 @@ from pegasus_tpu.storage import compact_governor  # noqa: F401
 from pegasus_tpu.storage import compact_pipeline  # noqa: F401
 from pegasus_tpu.storage.lsm import LSMStore
 from pegasus_tpu.storage.wal import OP_DEL, OP_PUT, WalRecord, WriteAheadLog
+from pegasus_tpu.utils.tracing import layer
 
 
 @dataclass
@@ -171,10 +172,12 @@ class StorageEngine:
         import time as _time
 
         t0 = _time.perf_counter()
-        table = self.lsm.flush(meta={
-            "last_flushed_decree": self.last_committed_decree,
-            "data_version": self.data_version,
-        })
+        # a flush a traced request triggers is that request's time
+        with layer("engine.flush"):
+            table = self.lsm.flush(meta={
+                "last_flushed_decree": self.last_committed_decree,
+                "data_version": self.data_version,
+            })
         if table is None:
             return False
         self.last_flushed_decree = self.last_committed_decree
@@ -571,7 +574,8 @@ class StorageEngine:
         import time as _time
 
         t0 = _time.perf_counter()
-        body()
+        with layer("engine.compact"):
+            body()
         if advance_watermark:
             self.last_flushed_decree = self.last_committed_decree
             self.wal.truncate()

@@ -12,7 +12,9 @@ import time
 from collections import deque
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
+from pegasus_tpu.utils.tracing import begin_stages as _begin_stages
 from pegasus_tpu.utils.tracing import current_span as _current_span
+from pegasus_tpu.utils.tracing import mark as _mark
 
 
 class LatencyTracer:
@@ -20,8 +22,11 @@ class LatencyTracer:
 
     When a distributed-tracing span is active at creation (or passed
     explicitly), every stage point ALSO lands on that span as an
-    annotation — the per-process stage chain and the cross-process span
-    tree share one instrumentation layer (utils/tracing.py)."""
+    annotation, and closes an interval on the host's clock: the time
+    since the previous point, less what child spans covered, is that
+    stage's self time under its layer key — the per-process stage chain
+    and the cross-process span tree share one instrumentation layer
+    (utils/tracing.py)."""
 
     __slots__ = ("name", "points", "_clock", "span", "perf")
 
@@ -35,12 +40,15 @@ class LatencyTracer:
         # to the entry so a slow dump shows counts, not just durations
         self.perf = None
         self.points: List[Tuple[str, float]] = [("start", clock())]
+        if self.span is not None:
+            _begin_stages()
 
     def add_point(self, stage: str) -> None:
         self.points.append((stage, self._clock()))
         sp = self.span
         if sp is not None:
             sp.annotate(stage)
+            _mark(stage)
 
     def total_ms(self) -> float:
         return (self.points[-1][1] - self.points[0][1]) * 1000.0

@@ -28,12 +28,36 @@ span tree:
 The span stack is thread-local: on the TCP transport the single
 dispatcher thread owns it; in the sim everything nests on one thread and
 push/pop order preserves correctness through recursive delivery.
+
+Two clocks. ``start``/``end`` live on the ring's clock (sim time on a
+SimCluster, so injected delays show and tail-keep sees them); beside
+them every span carries the host's monotonic clock
+(``time.perf_counter_ns``), and host SELF time is what the layer profile
+is made of. Self time is kept per thread as a stack of FRAMES, the way a
+profiler does it: ``activate``/``layer``/``deliver``/``enter`` push a
+frame, a finished frame adds its whole duration to its parent frame's
+child total, and a ``LatencyTracer`` stage point (``mark``) closes the
+top frame's interval since the previous point, minus what child frames
+covered — no tree walk. Each closed interval lands on
+``METRICS.entity("layer", node)`` as ``<key>_self_us`` (``LAYER_OF``
+maps span and stage names to keys); the outermost frame of a root span
+adds its duration to ``traced_us``. On one thread Σ self = traced.
+
+The switch. A client op mints a root when ``maybe_sample()`` says so or
+while a ``jax.profiler`` session is active (``profiling()``); the
+PROFILED bit rides the context, and every frame of such a trace is also
+a ``TraceAnnotation("pegasus.<span>")`` (stage intervals: events named
+``pegasus.stage`` with the stage as their ``stage`` stat — a TraceMe is
+named when it opens, a stage when it closes), so the program's spans
+sit in the device profile on the profile's own clock.
 """
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import random
+import sys
 import threading
 import time
 from collections import OrderedDict, deque
@@ -59,6 +83,7 @@ define_flag("pegasus.tracing", "kept_traces", 64,
 # context flag bits
 SAMPLED = 1
 KEEP = 2
+PROFILED = 4  # minted inside a jax.profiler session: frames annotate it
 
 # spans per kept trace (a runaway trace must not pin unbounded memory)
 KEPT_SPAN_CAP = 1024
@@ -80,7 +105,6 @@ _rng = random.Random()
 _prefix = _rng.getrandbits(32)
 _trace_ids = itertools.count(1)
 _span_ids = itertools.count(1)
-_hard_off = False  # bench baseline switch: bypass even the flag read
 
 
 def seed(n: int) -> None:
@@ -93,13 +117,6 @@ def seed(n: int) -> None:
         _span_ids = itertools.count(1)
 
 
-def hard_disable(off: bool) -> None:
-    """Kill switch for the bench's no-tracing baseline: skips even the
-    sample_ratio flag read on the client hot path."""
-    global _hard_off
-    _hard_off = off
-
-
 def _new_trace_id() -> str:
     return f"{_prefix:08x}{next(_trace_ids):08x}"
 
@@ -110,12 +127,84 @@ def _new_span_id() -> int:
 
 def maybe_sample() -> bool:
     """One head-based sampling draw (client op mint)."""
-    if _hard_off:
-        return False
     ratio = FLAGS.get("pegasus.tracing", "sample_ratio")
     if ratio <= 0.0:
         return False
     return ratio >= 1.0 or _rng.random() < ratio
+
+
+# ---- the profile session as the switch -----------------------------------
+
+_trace_me: Any = None  # jax.profiler.TraceAnnotation once jax is loaded
+
+
+def profiling() -> bool:
+    """True while a jax.profiler session is active in this process. Read
+    once per client call; a process that never imported jax reads False
+    without importing it."""
+    global _trace_me
+    tm = _trace_me
+    if tm is None:
+        if "jax" not in sys.modules:
+            return False
+        try:
+            from jax.profiler import TraceAnnotation as tm
+        except ImportError:
+            tm = False
+        _trace_me = tm
+    return bool(tm) and tm.is_enabled()
+
+
+# ---- layers --------------------------------------------------------------
+
+LAYER_KEYS = ("client", "rpc", "gate", "coord", "overlay", "index",
+              "decode", "dispatch", "repl", "engine", "other")
+
+# span or stage name -> layer key. A name is looked up whole, then by
+# what stands before its first "." (client.<op>, prepare.<dst>,
+# ack.<peer>, 2pc.<app>.<pidx>.d<decree>); a name in neither way falls
+# to "other". (The per-op spans op.<kind>.<pidx> are never framed.)
+LAYER_OF = {
+    # client/cluster_client.py: the root's self time is routing,
+    # grouping, PGT1 encode, reply decode and retry bookkeeping
+    "client": "client",
+    # runtime/sim.py, rpc/transport.py: delivery and dispatch; the
+    # transports' dispatch spans are named by message type and their
+    # self time is the stub handler's unpack, reply build and send
+    "rpc": "rpc",
+    "client_read": "rpc", "client_write": "rpc",
+    "client_read_batch": "rpc", "client_write_batch": "rpc",
+    "client_scan_multi": "rpc", "query_config": "rpc",
+    "negotiate": "rpc",
+    # replica/stub.py, server/tenancy.py: ACL, lease, follower gate,
+    # tenant brownout/admit, read limiter
+    "gate": "gate",
+    # server/*_coordinator.py, partition_server.py
+    "coord": "coord", "plan": "coord", "assemble": "coord",
+    "finish": "coord",
+    # memtable + L0 walked under a read
+    "overlay": "overlay", "overlay_merge": "overlay",
+    # row cache, bloom and perfect-hash probes
+    "row_cache": "index", "bloom": "index", "phash_probe": "index",
+    # storage/sstable.py: block fetch, codec, crc
+    "block_probe": "decode", "block_scan": "decode", "decode": "decode",
+    # device predicate programs: call to mask on the host
+    "dispatch": "dispatch", "pushdown": "dispatch",
+    # replica/: 2PC, plog, group commit
+    "repl": "repl", "2pc": "repl", "prepare": "repl", "ack": "repl",
+    "replica": "repl", "prepare_local": "repl", "append_plog": "repl",
+    "plog_durable": "repl", "prepares_sent": "repl",
+    "committed_applied": "repl", "replied": "repl",
+    # storage/engine.py
+    "engine": "engine",
+}
+
+
+def layer_of(name: str) -> str:
+    key = LAYER_OF.get(name)
+    if key is None:
+        key = LAYER_OF.get(name.partition(".")[0], "other")
+    return key
 
 
 # ---- spans ---------------------------------------------------------------
@@ -123,18 +212,29 @@ def maybe_sample() -> bool:
 
 class Span:
     __slots__ = ("ring", "trace_id", "span_id", "parent_id", "name",
-                 "node", "start", "end", "annotations", "tags")
+                 "node", "start", "end", "annotations", "tags", "flags",
+                 "host_start_ns", "host_end_ns", "host_self_ns",
+                 "stage_ns")
 
     def __init__(self, ring: "SpanRing", trace_id: str, span_id: int,
-                 parent_id: Optional[int], name: str) -> None:
+                 parent_id: Optional[int], name: str,
+                 flags: int = SAMPLED) -> None:
         self.ring = ring
         self.trace_id = trace_id
         self.span_id = span_id
         self.parent_id = parent_id
         self.name = name
         self.node = ring.node
+        self.flags = flags
         self.start = ring.clock()
         self.end: Optional[float] = None
+        # the host's monotonic clock beside the ring's: open, finish,
+        # self time summed over this span's frames, and the stage
+        # intervals closed inside them
+        self.host_start_ns = time.perf_counter_ns()
+        self.host_end_ns: Optional[int] = None
+        self.host_self_ns = 0
+        self.stage_ns: Optional[Dict[str, int]] = None
         self.annotations: List[Tuple[str, float]] = []
         self.tags: Dict[str, Any] = {}
 
@@ -150,7 +250,7 @@ class Span:
         time: a reply stamped while the local request already crossed
         the slow threshold (or its trace was already pinned) carries the
         tail-keep decision upstream."""
-        flags = SAMPLED
+        flags = self.flags
         if (self.ring.is_kept(self.trace_id)
                 or self.elapsed_ms()
                 >= FLAGS.get("pegasus.tracing", "slow_trace_ms")):
@@ -161,15 +261,23 @@ class Span:
         if self.end is not None:
             return  # idempotent (error paths may double-finish)
         self.end = self.ring.clock()
+        self.host_end_ns = time.perf_counter_ns()
         self.ring.record(self)
 
     def to_dict(self) -> Dict[str, Any]:
-        return {"trace": self.trace_id, "span": self.span_id,
-                "parent": self.parent_id, "name": self.name,
-                "node": self.node, "start": self.start,
-                "end": self.end if self.end is not None else self.start,
-                "ann": list(self.annotations),
-                "tags": dict(self.tags)}
+        host_end = (self.host_end_ns if self.host_end_ns is not None
+                    else self.host_start_ns)
+        d = {"trace": self.trace_id, "span": self.span_id,
+             "parent": self.parent_id, "name": self.name,
+             "node": self.node, "start": self.start,
+             "end": self.end if self.end is not None else self.start,
+             "host_ms": (host_end - self.host_start_ns) / 1e6,
+             "host_self_ms": self.host_self_ns / 1e6,
+             "ann": list(self.annotations),
+             "tags": dict(self.tags)}
+        if self.stage_ns:
+            d["stage_us"] = {k: v / 1e3 for k, v in self.stage_ns.items()}
+        return d
 
 
 class SpanRing:
@@ -188,20 +296,44 @@ class SpanRing:
         self.kept_count = ent.counter("kept_trace_count")
         self.drop_count = ent.counter("span_drop_count")
         self.span_count = ent.counter("span_count")
+        # the layer profile: host self time per layer key, whole
+        # microseconds (the nanoseconds left over wait in _ns_left)
+        lay = METRICS.entity("layer", node)
+        self._layer_us = {k: lay.counter(f"{k}_self_us")
+                          for k in LAYER_KEYS}
+        self._layer_us["traced"] = lay.counter("traced_us")
+        self._ns_left: Dict[str, int] = {}
+
+    def add_host_ns(self, key: str, ns: int) -> None:
+        """Host time of one closed frame or stage interval, to its
+        layer key's counter (or to "traced", a root's whole frame)."""
+        us, self._ns_left[key] = divmod(ns + self._ns_left.get(key, 0),
+                                        1000)
+        if us:
+            self._layer_us[key].increment(us)
 
     # -- recording --------------------------------------------------------
 
     def start(self, name: str, parent: Optional[Span] = None,
               parent_ctx: Optional[tuple] = None,
-              trace_id: Optional[str] = None) -> Span:
-        """A new span; the caller already decided it is sampled."""
+              trace_id: Optional[str] = None,
+              profiled: bool = False) -> Span:
+        """A new span; the caller already decided it is sampled. A
+        child inherits the PROFILED bit; a root takes it from the
+        session (`profiled`)."""
+        flags = SAMPLED
         if parent is not None:
             trace_id, parent_id = parent.trace_id, parent.span_id
+            flags |= parent.flags & PROFILED
         elif parent_ctx is not None:
             trace_id, parent_id = parent_ctx[0], parent_ctx[1]
+            flags |= parent_ctx[2] & PROFILED
         else:
             trace_id, parent_id = trace_id or _new_trace_id(), None
-        return Span(self, trace_id, _new_span_id(), parent_id, name)
+            if profiled:
+                flags |= PROFILED
+        return Span(self, trace_id, _new_span_id(), parent_id, name,
+                    flags)
 
     def record(self, span: Span) -> None:
         d = span.to_dict()
@@ -378,8 +510,178 @@ def annotate(stage: str) -> None:
         st[-1].annotate(stage)
 
 
+# ---- host frames: self time per thread ------------------------------------
+
+
+class _Frame:
+    """One entry of a span on this thread's host clock."""
+
+    __slots__ = ("span", "t0", "child_ns", "mark_ns", "mark_child_ns",
+                 "ann", "stage_ann")
+
+    def __init__(self, span: Span, now: int) -> None:
+        self.span = span
+        self.t0 = now
+        self.child_ns = 0          # whole durations of finished children
+        self.mark_ns = now         # the previous stage point (or entry)
+        self.mark_child_ns = 0     # child_ns as it stood at that point
+        self.ann = None            # TraceAnnotation of a PROFILED span
+        self.stage_ann = None      # the open `pegasus.stage` interval
+
+
+def _open_stage_ann(f: _Frame) -> None:
+    f.stage_ann = a = _trace_me("pegasus.stage")
+    a.__enter__()
+
+
+def _close_interval(f: _Frame, name: str, now: int) -> int:
+    """Close the frame's interval since its previous point: what child
+    frames covered is theirs, the rest goes to `name`'s layer."""
+    ns = (now - f.mark_ns) - (f.child_ns - f.mark_child_ns)
+    f.mark_ns = now
+    f.mark_child_ns = f.child_ns
+    f.span.ring.add_host_ns(layer_of(name), ns)
+    return ns
+
+
+def enter(span: Optional[Span]) -> None:
+    """Push a host frame for `span` on this thread (no-op for None).
+    Frames are about time only; `push` is what makes a span ambient."""
+    if span is None:
+        return
+    fr = getattr(_tls, "frames", None)
+    if fr is None:
+        fr = _tls.frames = []
+    f = _Frame(span, time.perf_counter_ns())
+    if span.flags & PROFILED and _trace_me:
+        f.ann = _trace_me("pegasus." + span.name)
+        f.ann.__enter__()
+    fr.append(f)
+
+
+def leave(span: Optional[Span]) -> None:
+    """Pop `span`'s frame (scopes nest, so it is the top one): its self
+    time to its layer, its whole duration to the parent frame, or to
+    `traced_us` when it was a root's outermost."""
+    if span is None:
+        return
+    fr = _tls.frames
+    f = fr.pop()
+    now = time.perf_counter_ns()
+    _close_interval(f, span.name, now)
+    dur = now - f.t0
+    span.host_self_ns += dur - f.child_ns
+    if fr:
+        fr[-1].child_ns += dur
+    elif span.parent_id is None:
+        span.ring.add_host_ns("traced", dur)
+    if f.stage_ann is not None:
+        f.stage_ann.__exit__(None, None, None)
+    if f.ann is not None:
+        f.ann.__exit__(None, None, None)
+
+
+def begin_stages() -> None:
+    """A stage chain starts here (LatencyTracer with a span): what the
+    top frame spent so far stays with its own span, and the chain's
+    first interval opens."""
+    fr = getattr(_tls, "frames", None)
+    if fr:
+        f = fr[-1]
+        _close_interval(f, f.span.name, time.perf_counter_ns())
+        if f.ann is not None and f.stage_ann is None:
+            _open_stage_ann(f)
+
+
+def mark(stage: str) -> None:
+    """A stage point: the top frame's interval since the previous point
+    is `stage`'s."""
+    fr = getattr(_tls, "frames", None)
+    if not fr:
+        return
+    f = fr[-1]
+    ns = _close_interval(f, stage, time.perf_counter_ns())
+    sp = f.span
+    if sp.stage_ns is None:
+        sp.stage_ns = {}
+    sp.stage_ns[stage] = sp.stage_ns.get(stage, 0) + ns
+    if f.ann is not None:
+        if f.stage_ann is not None:
+            f.stage_ann.set_metadata(stage=stage)
+            f.stage_ann.__exit__(None, None, None)
+        _open_stage_ann(f)
+
+
+# the one shared no-op scope of every untraced `layer`/`deliver`
+_NULL = contextlib.nullcontext()
+
+
+class _Scope:
+    """A span opened, framed and finished around one block."""
+
+    __slots__ = ("_span",)
+
+    def __init__(self, span: Span) -> None:
+        self._span = span
+
+    def __enter__(self) -> Span:
+        enter(self._span)
+        return self._span
+
+    def __exit__(self, *exc) -> None:
+        leave(self._span)
+        self._span.finish()
+
+
+def layer(name: str):
+    """Scope of one layer's work at a per-batch boundary: a child of
+    this thread's top frame, with a frame of its own. It is never
+    ambient, so contexts on the wire and LatencyTracer annotations stay
+    with the dispatch span. Untraced: one thread-local read."""
+    fr = getattr(_tls, "frames", None)
+    if not fr:
+        return _NULL
+    parent = fr[-1].span
+    return _Scope(parent.ring.start(name, parent=parent))
+
+
+def deliver(node: str, msg_type: str, payload):
+    """`rpc.deliver`: the scope of one message's delivery on `node`
+    (sim loop or TCP dispatcher), request or reply.
+
+    Inside a frame of this thread (the sim delivers inside the client
+    call that pumps it) the delivery is that frame's child whatever it
+    carries: its time is that call's. On a thread of its own (the TCP
+    dispatcher) it is the child of the context it carries — a batch
+    carrier (`trace: None`, per-item contexts) takes its first item's —
+    except a `*_reply`: a reply's context names the remote hop for
+    tail-keep, not a parent, and that hop's ring (a meta's) may be one
+    no trace dump collects."""
+    fr = getattr(_tls, "frames", None)
+    if fr:
+        top = fr[-1].span
+        return _Scope(ring_for(node).start(
+            "rpc.deliver",
+            parent_ctx=(top.trace_id, top.span_id, top.flags)))
+    if not isinstance(payload, dict):
+        return _NULL
+    ctx = payload.get("trace")
+    if ctx is None:
+        items = payload.get("items")
+        if not items:
+            return _NULL
+        ctx = next((e[2] for e in items if len(e) > 2 and e[2]), None)
+    if not ctx or not (ctx[2] & SAMPLED):
+        return _NULL
+    kind = payload.get("type") if msg_type == "replica" else msg_type
+    if isinstance(kind, str) and kind.endswith("_reply"):
+        return _NULL
+    return _Scope(ring_for(node).start("rpc.deliver", parent_ctx=ctx))
+
+
 class activate:
-    """Context manager: make `span` ambient (no-op for None)."""
+    """Context manager: make `span` ambient, with a host frame (no-op
+    for None)."""
 
     __slots__ = ("_span",)
 
@@ -389,10 +691,12 @@ class activate:
     def __enter__(self):
         if self._span is not None:
             push(self._span)
+            enter(self._span)
         return self._span
 
     def __exit__(self, *exc) -> None:
         if self._span is not None:
+            leave(self._span)
             pop(self._span)
 
 
@@ -438,7 +742,9 @@ def stitch(spans: List[dict]) -> Optional[dict]:
       - ``skew_ms``: half-width of the per-hop offset interval — the
         alignment uncertainty from transport asymmetry;
       - ``rel_ms`` / ``dur_ms`` / ``self_ms``: aligned start relative to
-        the root, duration, and self time (duration minus children);
+        the root, duration, and self time (duration minus children),
+        all on the rings' clock; ``host_ms`` / ``host_self_ms`` /
+        ``stage_us`` ride each span as recorded, on the host's own;
       - ``children``: sorted by aligned start.
 
     Alignment derives from the send/recv pair the transports already
@@ -573,12 +879,10 @@ def walk_dict(tree: dict):
         yield from walk_dict(c)
 
 
-walk = walk_dict
-
-
 def render(tree: Optional[dict], width: int = 48) -> str:
     """Text timeline of a stitched tree: one line per span with an
-    aligned bar, duration, self time, and per-hop skew bound."""
+    aligned bar, duration and self time on the rings' clock, the same
+    two on the host's clock, and the per-hop skew bound."""
     if tree is None:
         return "(no spans)"
     total = max(tree["dur_ms"], 1e-9)
@@ -594,10 +898,20 @@ def render(tree: Optional[dict], width: int = 48) -> str:
         if n["ann"]:
             stages = ",".join(a[0] for a in n["ann"][:8])
             ann = f"  [{stages}]"
+        host = ""
+        if "host_ms" in n:
+            host = (f"  host {n['host_ms']:.3f}ms "
+                    f"(self {n['host_self_ms']:.3f}ms)")
         lines.append(
             f"{'  ' * depth}{n['name']} @{n['node']}  "
             f"{n['dur_ms']:.3f}ms (self {n['self_ms']:.3f}ms){skew}"
-            f"{ann}")
+            f"{host}{ann}")
+        if n.get("stage_us"):
+            # the stage intervals closed in this span's frames, host
+            # clock, children's time taken out
+            lines.append(f"{'  ' * depth}  stages: " + " ".join(
+                f"{k}={v / 1000.0:.3f}ms"
+                for k, v in n["stage_us"].items()))
         pc = (n.get("tags") or {}).get("perf")
         if pc:
             # the op's PerfContext rode the span: counts, not just

@@ -487,7 +487,7 @@ def test_dup_trace_crosses_clusters_as_one_tree(tmp_path, dup_flags):
             assert any(n.startswith("2pc.") for n in names)
             assert any(n == "dup_apply_batch" for n in names)
             tree = tracing.stitch(tree_spans)
-            nodes = list(tracing.walk(tree))
+            nodes = list(tracing.walk_dict(tree))
             # the follower's dispatch span is a DESCENDANT in one tree
             assert any(n["name"] == "dup_apply_batch" for n in nodes)
         finally:

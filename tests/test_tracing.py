@@ -153,7 +153,7 @@ def test_slow_secondary_trace_and_tail_keep(cluster):
     spans = _cluster_spans(cluster, c, tid)
     assert {s["node"] for s in spans} >= {c.name, pc.primary, slow_peer}
     tree = tracing.stitch(spans)
-    nodes = [n for n in tracing.walk(tree) if n is not tree]
+    nodes = [n for n in tracing.walk_dict(tree) if n is not tree]
     slowest = max(nodes, key=lambda n: n["self_ms"])
     assert slowest["name"] == f"prepare.{slow_peer}"
     assert slowest["node"] == pc.primary
