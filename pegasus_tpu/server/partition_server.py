@@ -133,20 +133,28 @@ _SPLIT_FENCE_REJECTS = METRICS.entity(
 
 # what a scan costs against what it gives, as counters a window can
 # difference (the PerfContext holds the same per op): rows the scan
-# paths examined and rows they returned, one add per scan batch; and
-# the memtable + L0 rows _overlay_snapshot walks for every batch
+# paths examined and rows they returned, one add per scan batch; the
+# memtable + L0 rows a batch's request ranges made _overlay_windows
+# evaluate; and the requests whose range held overlay rows, which the
+# Python merge loop of finish_scan_batch serves, not page.serve_batch
 _SCAN_ROWS_EVALUATED = METRICS.entity(
     "storage", "node").counter("scan_rows_evaluated")
 _SCAN_ROWS_RETURNED = METRICS.entity(
     "storage", "node").counter("scan_rows_returned")
 _OVERLAY_ROWS_WALKED = METRICS.entity(
     "storage", "node").counter("overlay_rows_walked")
+_SCAN_MERGE_PATH_REQUESTS = METRICS.entity(
+    "storage", "node").counter("scan_merge_path_requests")
 
 
 
 # point-location-cache miss sentinel (None is a valid cached value:
 # "definitively absent from the L1 runs")
 _POINT_MISS = object()
+
+# _overlay_windows' entry for a key the batch's key filter excludes
+# from every window (None is a valid entry: a hidden shadow)
+_OVERLAY_EXCLUDED = object()
 
 
 def _after(key: bytes) -> bytes:
@@ -2605,12 +2613,6 @@ class PartitionServer:
         validate = validates.pop()
         filter_key = filters.pop()
         vf = vfs.pop()
-        if overlay_count:
-            with tracing.layer("overlay.snapshot"):
-                overlay = self._overlay_snapshot(now, validate, filter_key,
-                                                 value_filter=vf)
-        else:
-            overlay = ([], {})
         # 1 — per request: the block list + boundary bounds, capped a bit
         # beyond batch_size so expiry/hash drops don't starve the page.
         # Plans are CACHED per (range, want-bucket, store generation):
@@ -2681,8 +2683,20 @@ class PartitionServer:
                 cache[pkey] = (plan, uniq_entries, geom, nat, frontier)
             for ckey, run, bm, blk in uniq_entries:
                 unique.setdefault(ckey, (run, bm, blk))
+            # a plan that spent its budget answers up to its frontier
+            # only; one that did not reaches the request's stop_key and
+            # carries no frontier from here on
+            capped = bool(plan) and geom[0] >= want * 2 + 64
             req_plans.append((req, start_key, stop_key, want, plan,
-                              geom, nat, frontier))
+                              geom, nat, frontier if capped else None))
+        # 2 — per request: the overlay rows of ITS range, now that the
+        # plans say where each range ends
+        if overlay_count:
+            with tracing.layer("overlay.snapshot"):
+                overlay = self._overlay_windows(lsm, req_plans, now,
+                                                validate, filter_key, vf)
+        else:
+            overlay = ([()] * len(req_plans), {})
         if lsm.generation != gen:
             # a compaction published while this batch planned: the runs
             # and overlay above may be from different sides of the swap
@@ -2905,17 +2919,15 @@ class PartitionServer:
 
     def prepare_serve(self, state, keep_masks) -> list:
         """Phase 2.5: combine static keep with host TTL per unique
-        block, compute each request's overlay window + plan frontier,
-        and return the batch's fast-path (overlay-free) request windows
+        block and return the batch's fast-path request windows (those
+        whose range the plan found free of overlay rows)
         `(plan, want, no_value, want_ets, live_masks, geom)` for
         native assembly (page.serve_batch's req_windows shape). The
         node-level coordinator concatenates these ACROSS partitions so
         one native call (page.serve_batch) packs every fast request of
         a whole flush. Everything is stashed in `state`; idempotent."""
-        if "precomputed" in state or "windows" in state:
+        if "precomputed" in state or "fast" in state:
             return state.get("fast", [])
-        import bisect as _bisect
-
         unique = state["unique"]
         now = state["now"]
         vf = state.get("vf")
@@ -2973,31 +2985,16 @@ class PartitionServer:
                 cache.pop(next(iter(cache)))
             cache[lkey] = (now, static, alive, exp, live, lptr, prn)
         state["pushdown_pruned"] = pushdown_pruned
-        overlay_keys, _overlay_map = state["overlay"]
-        windows = []
         fast = []
-        for req, start_key, stop_key, want, plan, geom, nat, pfrontier \
-                in state["req_plans"]:
-            capped = bool(plan) and geom[0] >= want * 2 + 64
-            frontier = pfrontier if capped else None
-            ov_lo = (_bisect.bisect_left(overlay_keys, start_key)
-                     if start_key else 0)
-            ov_hi = len(overlay_keys)
-            if stop_key:
-                ov_hi = _bisect.bisect_left(overlay_keys, stop_key,
-                                            ov_lo)
-            if frontier is not None:
-                ov_hi = _bisect.bisect_left(overlay_keys, frontier,
-                                            ov_lo, ov_hi)
-            windows.append((capped, frontier, ov_lo, ov_hi))
-            if ov_lo >= ov_hi:
+        for (req, _start, _stop, want, plan, geom, nat, _frontier), \
+                ov_keys in zip(state["req_plans"], state["overlay"][0]):
+            if not ov_keys:
                 fast.append((plan, want, req.no_value,
                              req.return_expire_ts, live_masks, geom,
                              nat, live_ptrs))
         state["live_masks"] = live_masks
         state["alive_all"] = alive_all
         state["exp_full"] = exp_full
-        state["windows"] = windows
         state["fast"] = fast
         tracer = state.get("tracer")
         if tracer is not None:
@@ -3033,8 +3030,7 @@ class PartitionServer:
         live_masks = state["live_masks"]
         alive_all = state["alive_all"]
         exp_full = state["exp_full"]
-        windows = state["windows"]
-        overlay_keys, overlay_map = state["overlay"]
+        ov_windows, overlay_map = state["overlay"]
         hdr = header_length(self.data_version)
         if served is None and fast:
             served = serve_batch(fast, None, SCAN_BYTES_CAP, hdr)
@@ -3055,11 +3051,11 @@ class PartitionServer:
         total_read_cu = 0
         total_rows = 0
         total_bytes = 0
+        merge_reqs = 0
 
         out = []
-        for (req, start_key, stop_key, want, plan, _geom, _nat, _pf), \
-                (capped, frontier, ov_lo, ov_hi) in zip(req_plans,
-                                                        windows):
+        for (req, start_key, stop_key, want, plan, _geom, _nat,
+             frontier), ov_keys in zip(req_plans, ov_windows):
             kvs: list = []
             size = 0
             exhausted = True
@@ -3089,8 +3085,7 @@ class PartitionServer:
                         req_expired += int(np.count_nonzero(
                             ~alive_all[ckey][lo:hi]))
                 pec[id(plan)] = (plan, req_expired)
-            ov_i = ov_lo
-            if ov_lo >= ov_hi:
+            if not ov_keys:
                 # fast path: no overlay rows shadow this window, so the
                 # kept base rows ARE the answer — already assembled by
                 # the batch native call (page.serve_batch -> packer.cpp
@@ -3161,14 +3156,17 @@ class PartitionServer:
                 if (taken >= want or truncated) and last_key is not None:
                     resume_key = _after(last_key)
                     stop_early = True
-            elif ov_lo < ov_hi:
+            elif ov_keys:
                 # merge path: interleave overlay rows in key order
                 # (overlay rows SHADOW base rows: newest wins,
                 # tombstones hide)
+                merge_reqs += 1
+                ov_i = 0
+                ov_hi = len(ov_keys)
                 base = base_rows()
                 base_item = next(base, None)
                 while len(kvs) < want:
-                    ov_key = overlay_keys[ov_i] if ov_i < ov_hi else None
+                    ov_key = ov_keys[ov_i] if ov_i < ov_hi else None
                     if base_item is None and ov_key is None:
                         break
                     take_overlay = (ov_key is not None
@@ -3203,7 +3201,7 @@ class PartitionServer:
                         break
             if stop_early:
                 exhausted = False
-            elif capped:
+            elif frontier is not None:  # a capped plan
                 resume_key = frontier
                 exhausted = False
             total_expired += req_expired
@@ -3239,6 +3237,8 @@ class PartitionServer:
         rows_eval = sum(b.count for _r, _bm, b in unique.values())
         _SCAN_ROWS_EVALUATED.increment(rows_eval)
         _SCAN_ROWS_RETURNED.increment(total_rows)
+        if merge_reqs:
+            _SCAN_MERGE_PATH_REQUESTS.increment(merge_reqs)
         self.workload.note_scan(len(reqs), rows_eval, total_rows)
         pd_pruned = state.get("pushdown_pruned", 0)
         n_pushdown = sum(1 for pd in state.get("pd_list") or ()
@@ -3277,64 +3277,90 @@ class PartitionServer:
     # back to per-request merged serving
     OVERLAY_MERGE_LIMIT = 4096
 
-    def _overlay_snapshot(self, now: int, validate: bool,
-                          filter_key=None, value_filter=None):
-        """(sorted_keys, key -> None|(user_data, ets)) for the memtable +
-        L0 overlay, newest-wins, with the scan predicates (TTL, stale-
-        split hash, and the batch's shared key filter) evaluated
-        HOST-side — the overlay is tiny by the fast-path qualifier, so a
-        device dispatch would cost more than it filters. A key failing
-        the KEY filter is excluded entirely (its base copies fail the
-        same filter in the device mask, so nothing needs shadowing); a
-        row failing the pushdown VALUE filter must instead stay as a
-        hidden SHADOW (None) — the base may hold an older value for the
-        same key that would pass, and newest-wins must still hide it."""
+    def _overlay_windows(self, lsm, req_plans, now: int, validate: bool,
+                         filter_key=None, value_filter=None):
+        """([sorted keys, one list a request], key -> None|(user_data,
+        ets)): the memtable + L0 rows of each request's OWN range
+        `[start_key, min(stop_key, frontier of a capped plan))`,
+        newest-wins, with the scan predicates (TTL, stale-split hash,
+        and the batch's shared key filter) evaluated HOST-side — the
+        overlay is tiny by the fast-path qualifier, so a device dispatch
+        would cost more than it filters. The memtable's sorted keys are
+        bisected and each L0 table iterated by range, so a short scan
+        pays for the overlay rows beside it and not for the partition's;
+        a key is evaluated once a batch however many ranges hold it
+        (zipfian traffic repeats its hot records inside one window). A
+        key failing the KEY filter is excluded entirely (its base copies
+        fail the same filter in the device mask, so nothing needs
+        shadowing); a row failing the pushdown VALUE filter must instead
+        stay as a hidden SHADOW (None) — the base may hold an older
+        value for the same key that would pass, and newest-wins must
+        still hide it."""
         from pegasus_tpu.base.key_schema import check_key_hash, restore_key
         from pegasus_tpu.ops.predicates import host_match_filter
         from pegasus_tpu.storage.memtable import TOMBSTONE
 
         hft, hfp, sft, sfp = filter_key or (FT_NO_FILTER, b"",
                                             FT_NO_FILTER, b"")
+        key_filtered = hft != FT_NO_FILTER or sft != FT_NO_FILTER
 
-        lsm = self.engine.lsm
-        merged: dict = {}
-        for key, value, ets in lsm.memtable.items_sorted():
-            merged[key] = (None if value is TOMBSTONE
-                           else (value, ets))
-        for table in lsm.l0:  # newest first; first writer wins
-            for key, value, ets in table.iterate():
-                if key not in merged:
-                    merged[key] = (None if value is None
-                                   else (value, ets))
-        _OVERLAY_ROWS_WALKED.increment(
-            len(lsm.memtable) + sum(t.total_count for t in lsm.l0))
-        out: dict = {}
-        for key in sorted(merged):
-            if hft != FT_NO_FILTER or sft != FT_NO_FILTER:
+        def entry_of(key, value, ets):
+            if key_filtered:
                 hk, sk = restore_key(key)
                 if not (host_match_filter(hk, hft, hfp)
                         and host_match_filter(sk, sft, sfp)):
-                    continue  # fails the batch filter everywhere
-            entry = merged[key]
-            if entry is None:
-                out[key] = None  # tombstone: shadows the base
-                continue
-            value, ets = entry
+                    return _OVERLAY_EXCLUDED  # fails the filter everywhere
+            if value is TOMBSTONE:
+                return None  # shadows the base
             if check_if_ts_expired(now, ets):
                 self._abnormal_reads.increment()
-                out[key] = None  # expired: hidden AND shadows the base
-                continue
+                return None  # expired: hidden AND shadows the base
             if validate and not check_key_hash(key, self.pidx,
                                                self.partition_version):
-                out[key] = None
-                continue
+                return None
             data = extract_user_data(self.data_version, value)
             if value_filter is not None and not host_match_filter(
                     data, value_filter[0], value_filter[1]):
-                out[key] = None  # value-rejected: hidden, still shadows
+                return None  # value-rejected: hidden, still shadows
+            return data, ets
+
+        mem = lsm.memtable
+        mem_get = mem.get
+        l0 = lsm.l0
+        entries: dict = {}
+        ranges: dict = {}
+        windows = []
+        walked = 0
+        for _req, start_key, stop_key, _want, _plan, _geom, _nat, \
+                frontier in req_plans:
+            stop = stop_key or None
+            if frontier is not None and (stop is None or frontier < stop):
+                stop = frontier
+            keys = ranges.get((start_key, stop))
+            if keys is not None:
+                windows.append(keys)
                 continue
-            out[key] = (data, ets)
-        return list(out), out  # insertion order is already sorted
+            in_range = mem.keys_in(start_key, stop)
+            l0_rows: dict = {}
+            for table in l0:  # newest first; first writer wins
+                for key, value, ets in table.iterate(start_key, stop):
+                    l0_rows.setdefault(key, (value, ets))
+            if l0_rows:
+                under = [k for k in l0_rows if mem_get(k) is None]
+                if under:
+                    in_range = sorted(in_range + under)
+            keys = []
+            for key in in_range:
+                if key not in entries:
+                    walked += 1
+                    entries[key] = entry_of(
+                        key, *(mem_get(key) or l0_rows[key]))
+                if entries[key] is not _OVERLAY_EXCLUDED:
+                    keys.append(key)
+            ranges[(start_key, stop)] = keys
+            windows.append(keys)
+        _OVERLAY_ROWS_WALKED.increment(walked)
+        return windows, entries
 
     def _eval_blocks_stacked(self, misses, filter_key, validate):
         """Evaluate MANY blocks' static predicates in as few device

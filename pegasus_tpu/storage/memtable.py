@@ -57,17 +57,23 @@ class Memtable:
             self._sorted_keys = sorted(self._data.keys())
             self._dirty = False
 
+    def keys_in(self, start: bytes = b"", stop: Optional[bytes] = None
+                ) -> list[bytes]:
+        """The keys with start <= key < stop, in order (a copy): two
+        bisects over the sorted keys, whatever the memtable holds."""
+        self._ensure_sorted()
+        keys = self._sorted_keys
+        lo = bisect.bisect_left(keys, start) if start else 0
+        hi = (bisect.bisect_left(keys, stop, lo) if stop is not None
+              else len(keys))
+        return keys[lo:hi]
+
     def iterate(self, start: bytes = b"", stop: Optional[bytes] = None,
                 reverse: bool = False
                 ) -> Iterator[Tuple[bytes, Optional[bytes], int]]:
         """Yield (key, value|TOMBSTONE, expire_ts) for start <= key < stop."""
-        self._ensure_sorted()
-        keys = self._sorted_keys
-        lo = bisect.bisect_left(keys, start) if start else 0
-        hi = bisect.bisect_left(keys, stop) if stop is not None else len(keys)
-        rng = range(hi - 1, lo - 1, -1) if reverse else range(lo, hi)
-        for i in rng:
-            k = keys[i]
+        keys = self.keys_in(start, stop)
+        for k in reversed(keys) if reverse else keys:
             v, ets = self._data[k]
             yield k, v, ets
 

@@ -27,6 +27,9 @@ def test_stage_a_tiny_matches_the_plain_model(tmp_path, monkeypatch):
     monkeypatch.setattr(placement, "mesh_compact_pays",
                         lambda *a, **k: True)
     codec = FLAGS.get("pegasus.storage", "block_codec")
+    # the counters are the process's: another test file of this worker
+    # may have driven a fallback on purpose before this one ran
+    fallbacks = chip_smoke._fallback_counters()
     facts = chip_smoke.stage_a(
         str(tmp_path), seed=7, n_records=2000, n_partitions=8, n_nodes=3,
         n_scans=64, n_gets=64, n_sets=30, pallas_interpret=True)
@@ -41,7 +44,7 @@ def test_stage_a_tiny_matches_the_plain_model(tmp_path, monkeypatch):
     assert facts["serve_raw_blocks"]["audited_device_waves"] >= 1
     assert facts["serve_raw_blocks"]["encoded_host_probes"] == 0
     assert facts["serve_default_codec"]["encoded_host_probes"] >= 1
-    assert not any(facts["fallback_counters"].values())
+    assert facts["fallback_counters"] == fallbacks
     assert facts["prefresher"]["errors"] == 0
     assert set(facts["kernel_first_call_s"]) >= {
         "pallas_ft1", "static_predicate_ft2", "multi_flavor_predicate",

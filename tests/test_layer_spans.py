@@ -264,16 +264,26 @@ def test_counts_move_by_hand_computed_amounts(loaded):
     assert dw["group_commit_windows"] == 3
     assert dw["group_commit_mutations"] == 3
 
-    # two scans of 5 rows in p (whose primary's memtable now holds the
-    # 10 new rows) and one of 7 rows in q (no overlay), one flavor: one
-    # overlay walk of 10 rows; every row of each partition's one block
-    # examined once per partition; 5 + 5 + 7 rows returned
+    # a second new record after it in p: the primary's memtable holds
+    # 20 rows, both records past every row of p's one block. In one
+    # flush and one flavor: two scans of 5 rows from the later record
+    # (no block planned, so no frontier: their range is the record's 10
+    # rows, evaluated once for the two of them, and both merge), one of
+    # 5 rows from record 0 (its plan holds the whole block, more than
+    # 2 * 5 + 64 rows, so its range ends at the block's last key, before
+    # either new record: nothing evaluated, no merge) and one of 7 rows
+    # in q (no overlay). Every row of each partition's one block is
+    # examined once per partition; 5 + 5 + 5 + 7 rows returned
+    assert rows_l1[p] >= 2 * 5 + 64
+    later = next(r for r in range(new + 1, 6000) if _pidx(r) == p)
+    assert cl.write_multi(_put_groups([later])) == {p: [0]}
     s0 = _counters("storage")
-    out = cl.scan_multi({p: _scan_groups([0, 0], rows=5)[p],
+    out = cl.scan_multi({p: _scan_groups([later, later, 0], rows=5)[p],
                          q: _scan_groups([rec_q], rows=7)[q]})
-    assert [len(r.kvs) for r in out[p]] == [5, 5]
+    assert [len(r.kvs) for r in out[p]] == [5, 5, 5]
     assert [len(r.kvs) for r in out[q]] == [7]
     ds = _delta("storage", s0)
     assert ds["overlay_rows_walked"] == FIELDS
+    assert ds["scan_merge_path_requests"] == 2
     assert ds["scan_rows_evaluated"] == rows_l1[p] + rows_l1[q]
-    assert ds["scan_rows_returned"] == 17
+    assert ds["scan_rows_returned"] == 22

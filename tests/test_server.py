@@ -447,6 +447,280 @@ def test_batched_scan_overlay_merge_matches_individual(tmp_path):
     srv.close()
 
 
+# ---- the scan overlay by request range: the batched path, the per-
+# request path and a plain sorted-dict model agree, case by case ---------
+
+
+class _OverlayStore:
+    """One partition with `n_base` rows compacted to L1 (blocks of 1,024
+    rows) and whatever a case lays over them, beside a plain dict of
+    what every write meant: key -> (user bytes | None, expire_ts)."""
+
+    PARTS = 8  # so that a stale-split row has somewhere else to belong
+
+    def __init__(self, path, n_base=400):
+        from pegasus_tpu.base.key_schema import partition_index
+
+        self.now = epoch_now()
+        self.rows = {}
+        self.hks = [b"h%03d" % i for i in range(400)
+                    if partition_index(b"h%03d" % i, self.PARTS) == 0][:10]
+        self.foreign = next(b"f%03d" % i for i in range(400)
+                            if partition_index(b"f%03d" % i, self.PARTS))
+        self.srv = PartitionServer(str(path), pidx=0,
+                                   partition_count=self.PARTS)
+        self.write([(self.hks[i % 10], b"s%04d" % i, b"base%d" % i, 0)
+                    for i in range(n_base)])
+        self.srv.manual_compact()
+
+    def key(self, h, sk):
+        return generate_key(self.hks[h] if isinstance(h, int) else h, sk)
+
+    def write(self, rows):
+        """[(hashkey | index into hks, sortkey, user bytes, expire_ts)]
+        as one committed batch."""
+        from pegasus_tpu.base.value_schema import generate_value
+        from pegasus_tpu.storage.engine import WriteBatchItem
+        from pegasus_tpu.storage.wal import OP_PUT
+
+        items = []
+        for h, sk, user, ets in rows:
+            items.append(WriteBatchItem(
+                OP_PUT, self.key(h, sk), generate_value(1, user, ets), ets))
+            self.rows[self.key(h, sk)] = (user, ets)
+        self.srv.engine.write_batch(
+            items, self.srv.engine.last_committed_decree + 1)
+
+    def remove(self, h, sk):
+        assert self.srv.on_remove(self.key(h, sk)) == OK
+        self.rows[self.key(h, sk)] = (None, 0)
+
+    def expected(self, req):
+        from pegasus_tpu.base.key_schema import partition_index
+
+        vf = req.pushdown.value_filter_pattern if req.pushdown else b""
+        out = []
+        for key in sorted(self.rows):
+            user, ets = self.rows[key]
+            hk, sk = restore_key(key)
+            if key < req.start_key:
+                continue
+            if req.stop_key and (key > req.stop_key or (
+                    key == req.stop_key and not req.stop_inclusive)):
+                continue
+            if user is None or 0 < ets <= self.now:
+                continue
+            if (req.validate_partition_hash
+                    and partition_index(hk, self.PARTS) != 0):
+                continue
+            if not sk.startswith(req.sort_key_filter_pattern):
+                continue
+            if vf not in user:
+                continue
+            out.append((key, user))
+        return out
+
+    def drained(self, resp):
+        """Every row of a scan, its first page and the pages after."""
+        rows = []
+        while True:
+            assert resp.error == OK
+            rows += [(kv.key, kv.value) for kv in resp.kvs]
+            if resp.context_id < 0:
+                return rows
+            resp = self.srv.on_scan(resp.context_id)
+
+
+def _req(store, h=0, sk=b"", **kw):
+    kw.setdefault("batch_size", 17)
+    return GetScannerRequest(start_key=store.key(h, sk), **kw)
+
+
+def _case_memtable_only(s):
+    s.write([(0, b"s0000", b"UPDATED", 0), (0, b"s0000x", b"NEW", 0),
+             (3, b"s9999", b"LAST", 0)])
+    return [_req(s, 0), _req(s, 3), _req(s, 5)]
+
+
+def _case_l0_under_a_newer_memtable_copy(s):
+    s.write([(0, b"s0000", b"L0-OLD", 0), (0, b"s0005", b"L0-ONLY", 0),
+             (1, b"s0001", b"L0-GONE", 0)])
+    s.srv.engine.flush()
+    s.remove(2, b"s0002")
+    s.srv.engine.flush()                  # two L0 tables, newest first
+    s.write([(0, b"s0000", b"MEM-NEW", 0), (2, b"s0002", b"BACK", 0)])
+    s.remove(1, b"s0001")
+    assert len(s.srv.engine.lsm.l0) == 2 and len(s.srv.engine.lsm.memtable)
+    return [_req(s, 0), _req(s, 1), _req(s, 2)]
+
+
+def _case_tombstone_over_a_base_row(s):
+    s.remove(0, b"s0000")
+    s.remove(0, b"s0010")
+    return [_req(s, 0)]
+
+
+def _case_expired_overlay_row_over_a_live_base_row(s):
+    s.write([(0, b"s0000", b"EXPIRED", s.now - 50),
+             (0, b"s0010", b"LIVES", s.now + 3600)])
+    return [_req(s, 0, return_expire_ts=True)]
+
+
+def _case_stale_split_row_under_validate(s):
+    s.write([(s.foreign, b"s", b"STALE", 0), (0, b"s0000", b"OWNED", 0)])
+    return [GetScannerRequest(start_key=b"", batch_size=1000,
+                              validate_partition_hash=True),
+            _req(s, 0, validate_partition_hash=True)]
+
+
+def _case_sortkey_prefix_filter(s):
+    s.write([(0, b"s0000", b"IN", 0), (0, b"t0000", b"OUT", 0),
+             (0, b"s001", b"IN-TOO", 0)])
+    s.remove(0, b"s0010")
+    return [_req(s, 0, sort_key_filter_type=FT_MATCH_PREFIX,
+                 sort_key_filter_pattern=b"s00")]
+
+
+def _case_value_filter_leaves_a_hidden_shadow(s):
+    from pegasus_tpu.ops.predicates import FT_MATCH_ANYWHERE
+    from pegasus_tpu.ops.pushdown import PushdownSpec
+
+    # base0 passes the filter and its newer copy does not: the old value
+    # must not come back from under it
+    s.write([(0, b"s0000", b"other", 0), (0, b"s0005", b"base-new", 0)])
+    return [_req(s, 0, pushdown=PushdownSpec(
+        value_filter_type=FT_MATCH_ANYWHERE, value_filter_pattern=b"base"))]
+
+
+def _case_explicit_stop_key(s):
+    s.write([(0, b"s0005", b"BEFORE", 0), (0, b"s0200", b"AT-STOP", 0),
+             (0, b"s0300", b"AFTER", 0)])
+    return [_req(s, 0, stop_key=s.key(0, b"s0200")),
+            _req(s, 0, stop_key=s.key(0, b"s0200"), stop_inclusive=True)]
+
+
+def _case_capped_plan_with_overlay_on_both_sides_of_the_frontier(s):
+    # 3,000 base rows are three blocks, the first of which ends at
+    # (hks[3], s1233); under the prefix filter s123 it answers 4 rows of
+    # the 17 asked, so the page ends at the plan's frontier, with overlay
+    # rows on either side of it
+    run, = s.srv.engine.lsm.l1_runs
+    assert len(run.blocks) == 3
+    assert run.blocks[0].last_key == s.key(3, b"s1233")
+    s.write([(0, b"s1230k", b"LOW", 0), (3, b"s1233k", b"JUST-PAST", 0),
+             (9, b"s1239k", b"HIGH", 0)])
+    s.remove(1, b"s1231")
+    req = GetScannerRequest(start_key=b"", batch_size=17,
+                            sort_key_filter_type=FT_MATCH_PREFIX,
+                            sort_key_filter_pattern=b"s123")
+    state = s.srv.plan_scan_batch([req])
+    frontier = state["req_plans"][0][7]
+    (window,), _entries = state["overlay"]
+    assert window == [s.key(0, b"s1230k"), s.key(1, b"s1231")]
+    assert window[-1] < frontier < s.key(3, b"s1233k")
+    page, = s.srv.finish_scan_batch(state, s.srv.eval_planned_masks(state))
+    assert [kv.value for kv in page.kvs] == [b"base1230", b"LOW",
+                                             b"base1232", b"base1233"]
+    assert page.context_id >= 0       # and goes on from the frontier
+    return [req]
+
+
+def _case_two_overlapping_requests_in_one_batch(s):
+    s.write([(0, b"s0000", b"A", 0), (0, b"s0100", b"B", 0),
+             (0, b"s0200", b"C", 0)])
+    s.remove(0, b"s0110")
+    return [_req(s, 0), _req(s, 0, b"s0050"), _req(s, 0),
+            _req(s, 0, b"s0050", stop_key=s.key(0, b"s0150"))]
+
+
+_OVERLAY_CASES = [
+    _case_memtable_only,
+    _case_l0_under_a_newer_memtable_copy,
+    _case_tombstone_over_a_base_row,
+    _case_expired_overlay_row_over_a_live_base_row,
+    _case_stale_split_row_under_validate,
+    _case_sortkey_prefix_filter,
+    _case_value_filter_leaves_a_hidden_shadow,
+    _case_explicit_stop_key,
+    _case_capped_plan_with_overlay_on_both_sides_of_the_frontier,
+    _case_two_overlapping_requests_in_one_batch,
+]
+
+
+@pytest.mark.parametrize(
+    "case", _OVERLAY_CASES, ids=[c.__name__[6:] for c in _OVERLAY_CASES])
+def test_scan_overlay_by_range_matches_solo_and_model(tmp_path, case):
+    store = _OverlayStore(tmp_path / "p",
+                          n_base=3000 if "capped" in case.__name__ else 400)
+    try:
+        reqs = case(store)
+        srv = store.srv
+        assert srv.plan_scan_batch(list(reqs)) is not None  # the path
+        batch = srv.on_get_scanner_batch(list(reqs))
+        for req, got in zip(reqs, batch):
+            want = store.expected(req)
+            assert want, req                     # no case compares nothing
+            assert len(got.kvs) <= req.batch_size
+            assert store.drained(got) == want, req
+            assert store.drained(srv.on_get_scanner(req)) == want, req
+            if req.return_expire_ts:
+                ets = {kv.key: kv.expire_ts_seconds for kv in got.kvs}
+                assert all(ets[k] == store.rows[k][1] for k in ets)
+    finally:
+        store.srv.close()
+
+
+def test_scan_overlay_work_is_bound_by_the_request_range(tmp_path):
+    """500 rows in the memtable, a capped scan whose range holds k of
+    them: k rows are evaluated, once a batch however often the range is
+    asked for, and only a request with overlay rows in its range takes
+    the merge path."""
+    from pegasus_tpu.utils.metrics import METRICS
+
+    def counts():
+        node = METRICS.entity("storage", "node")
+        return (node.counter("overlay_rows_walked").value(),
+                node.counter("scan_merge_path_requests").value())
+
+    def moved(reqs):
+        before = counts()
+        out = store.srv.on_get_scanner_batch(list(reqs))
+        assert all(r.error == OK and len(r.kvs) == 10 for r in out)
+        return tuple(a - b for a, b in zip(counts(), before))
+
+    store = _OverlayStore(tmp_path / "p", n_base=3000)
+    try:
+        base = sorted(store.rows)
+        # one new row after every 6th base row: 500 of them
+        store.write([(hk, sk + b"k", b"new", 0)
+                     for hk, sk in map(restore_key, base[::6])][:500])
+        assert len(store.srv.engine.lsm.memtable) == 500
+        run, = store.srv.engine.lsm.l1_runs
+        # a scan of 10 rows from the 100th base row plans the first block
+        # alone (924 rows >= 2 * 16 + 64) and ends at its last key
+        req = GetScannerRequest(start_key=base[100], batch_size=10)
+        frontier = run.blocks[0].last_key + b"\x00"
+        k = sum(1 for key in store.rows
+                if key.endswith(b"k") and base[100] <= key < frontier)
+        assert k == len(range(102, 1024, 6)) == 154
+        assert moved([req]) == (k, 1)
+        assert moved([req, req]) == (k, 2)
+        # a stop_key before the first new row of the range: beside the
+        # overlay, nothing evaluated, nothing merged
+        beside = GetScannerRequest(start_key=base[103], stop_key=base[108],
+                                   batch_size=10)
+        assert [len(r.kvs) for r in
+                store.srv.on_get_scanner_batch([beside])] == [5]
+        before = counts()
+        store.srv.on_get_scanner_batch([beside, beside])
+        assert counts() == before
+        # both in one batch: the counts of the one with a window
+        assert moved([req, GetScannerRequest(
+            start_key=base[2000], batch_size=10)])[1] == 2
+    finally:
+        store.srv.close()
+
+
 def test_env_triggered_manual_compact(server):
     """Remote manual compaction rides the `manual_compact.once.
     trigger_time` app env (parity: pegasus_manual_compact_service.cpp

@@ -58,6 +58,56 @@ def test_memtable_range_and_reverse():
 # ---- WAL --------------------------------------------------------------
 
 
+def test_memtable_key_order_across_interleaved_writes_and_reads():
+    # the order is read between writes of new keys, overwrites and
+    # deletes, front, middle and back: every read sees every key once,
+    # sorted, and the ranged accessor agrees with a plain sorted list
+    mt = Memtable()
+    model = {}
+
+    def check():
+        want = sorted(model)
+        assert mt.keys_in() == want
+        assert [(k, v) for k, v, _e in mt.iterate()] == \
+            [(k, model[k]) for k in want]
+        for lo, hi in ((b"k03", b"k07"), (b"k05", None), (b"", b"k05"),
+                       (b"k07", b"k03"), (b"k051", b"k052"), (b"z", None)):
+            ranged = [k for k in want
+                      if k >= lo and (hi is None or k < hi)]
+            assert mt.keys_in(lo, hi) == ranged
+            assert [k for k, _v, _e in mt.iterate(lo, hi)] == ranged
+            assert [k for k, _v, _e in
+                    mt.iterate(lo, hi, reverse=True)] == ranged[::-1]
+
+    check()                                       # empty
+    for i in (5, 3, 8):
+        mt.put(b"k%02d" % i, b"v%d" % i)
+        model[b"k%02d" % i] = b"v%d" % i
+    check()
+    mt.put(b"k05", b"again")                      # overwrite: no new key
+    model[b"k05"] = b"again"
+    check()
+    mt.delete(b"k04")                             # tombstone of a new key
+    model[b"k04"] = None
+    mt.put(b"k01", b"front")
+    model[b"k01"] = b"front"
+    check()
+    mt.delete(b"k08")                             # tombstone over a put
+    model[b"k08"] = None
+    mt.put(b"k09", b"back")
+    model[b"k09"] = b"back"
+    mt.put(b"k051", b"between")
+    model[b"k051"] = b"between"
+    check()
+    mt.put(b"k04", b"back from the dead")
+    model[b"k04"] = b"back from the dead"
+    check()
+    got = mt.keys_in(b"k03", b"k07")
+    got.append(b"mine")                           # a copy, not the order
+    assert b"mine" not in mt.keys_in()
+    assert len(mt) == len(model) == 7
+
+
 def test_wal_roundtrip_and_torn_tail(tmp_path):
     path = str(tmp_path / "wal.log")
     wal = WriteAheadLog(path)
