@@ -27,6 +27,33 @@ import numpy as np
 from pegasus_tpu.ops.device_crc import key_hash_device
 from pegasus_tpu.ops.predicates import ttl_expired
 from pegasus_tpu.ops.record_block import next_bucket
+from pegasus_tpu.utils import tracing
+from pegasus_tpu.utils.metrics import METRICS
+
+# every compaction filter program handed to a device (always on), and
+# the part of them dispatched under a traced root or inside a profiler
+# session: what a traced slice's device busy time is made of. The read
+# path's static mask programs (scan_coordinator.stacked_block_submit)
+# count beside them under `mask_*`: a scan batch whose plan a publish
+# tore dispatches them in the same slice.
+_FILTER_ENT = METRICS.entity("engine", "filter_programs")
+_PROGRAM_COUNTERS = {
+    kind: tuple(_FILTER_ENT.counter(f"{kind}_{what}") for what in (
+        "programs", "rows", "bytes", "programs_traced", "bytes_traced"))
+    for kind in ("filter", "mask")}
+
+
+def note_filter_program(rows: int, nbytes: int,
+                        kind: str = "filter") -> None:
+    """One dispatched program: its padded rows, and the bytes of the
+    columns it was handed plus the masks / expire_ts it gives back."""
+    programs, c_rows, c_bytes, traced, bytes_traced = _PROGRAM_COUNTERS[kind]
+    programs.increment()
+    c_rows.increment(rows)
+    c_bytes.increment(nbytes)
+    if tracing.frame_span() is not None or tracing.profiling():
+        traced.increment()
+        bytes_traced.increment(nbytes)
 
 
 @functools.partial(jax.jit, static_argnames=("validate_hash",))
@@ -38,22 +65,23 @@ def compaction_filter_block(keys, key_len, hashkey_len, expire_ts, valid,
     `partition_version` must be >= 0 when validate_hash is set (callers gate
     the pv<0 / pidx>pv cases to keep, mirroring check_if_stale_split_data).
     """
-    now = jnp.asarray(now, jnp.uint32)
-    default_ttl = jnp.asarray(default_ttl, jnp.uint32)
+    with jax.named_scope("pegasus_compact_ttl_filter"):
+        now = jnp.asarray(now, jnp.uint32)
+        default_ttl = jnp.asarray(default_ttl, jnp.uint32)
 
-    new_ets = jnp.where((default_ttl != 0) & (expire_ts == 0),
-                        now + default_ttl, expire_ts)
-    expired = ttl_expired(new_ets, now)
+        new_ets = jnp.where((default_ttl != 0) & (expire_ts == 0),
+                            now + default_ttl, expire_ts)
+        expired = ttl_expired(new_ets, now)
 
-    if validate_hash:
-        _, lo = key_hash_device(keys, key_len, hashkey_len)
-        pv = jnp.asarray(partition_version, jnp.uint32)
-        stale = (lo & pv) != jnp.asarray(pidx, jnp.uint32)
-    else:
-        stale = jnp.zeros_like(valid)
+        if validate_hash:
+            _, lo = key_hash_device(keys, key_len, hashkey_len)
+            pv = jnp.asarray(partition_version, jnp.uint32)
+            stale = (lo & pv) != jnp.asarray(pidx, jnp.uint32)
+        else:
+            stale = jnp.zeros_like(valid)
 
-    drop = (expired | stale) & valid
-    return drop, new_ets
+        drop = (expired | stale) & valid
+        return drop, new_ets
 
 
 # ---- bulk block-level compaction (the GB/s path) -----------------------
@@ -113,34 +141,35 @@ def make_compaction_eval(operations=None):
                    want_ets: bool = True, pack: bool = False):
         from pegasus_tpu.ops.compaction_rules import apply_rules_ops
 
-        now = jnp.asarray(now, jnp.uint32)
-        default_ttl = jnp.asarray(default_ttl, jnp.uint32)
-        ets1 = jnp.where((default_ttl != 0) & (expire_ts == 0),
-                         now + default_ttl, expire_ts)
-        if operations:
-            rule_drop, ets2 = apply_rules_ops(
-                operations, keys, key_len, hashkey_len, ets1, valid, now)
-        else:
-            rule_drop = jnp.zeros_like(valid)
-            ets2 = ets1
-        expired = ttl_expired(ets2, now)
-        if validate_hash:
-            if use_hash_lo:
-                lo = hash_lo  # precomputed at SST write time
+        with jax.named_scope("pegasus_compact_bulk_filter"):
+            now = jnp.asarray(now, jnp.uint32)
+            default_ttl = jnp.asarray(default_ttl, jnp.uint32)
+            ets1 = jnp.where((default_ttl != 0) & (expire_ts == 0),
+                             now + default_ttl, expire_ts)
+            if operations:
+                rule_drop, ets2 = apply_rules_ops(
+                    operations, keys, key_len, hashkey_len, ets1, valid, now)
             else:
-                _, lo = key_hash_device(keys, key_len, hashkey_len)
-            pv = jnp.asarray(partition_version, jnp.uint32)
-            stale = (lo & pv) != jnp.asarray(pidx, jnp.uint32)
-        else:
-            stale = jnp.zeros_like(valid)
-        drop = ((expired | stale) & valid) | rule_drop
-        # pack: bit-pack the drop mask on device (8x fewer bytes to
-        # fetch); want_ets=False skips
-        # returning the rewritten-TTL column entirely when no rule or
-        # default-TTL can change it (the caller never reads it)
-        if pack:
-            drop = jnp.packbits(drop)
-        return (drop, ets2) if want_ets else (drop,)
+                rule_drop = jnp.zeros_like(valid)
+                ets2 = ets1
+            expired = ttl_expired(ets2, now)
+            if validate_hash:
+                if use_hash_lo:
+                    lo = hash_lo  # precomputed at SST write time
+                else:
+                    _, lo = key_hash_device(keys, key_len, hashkey_len)
+                pv = jnp.asarray(partition_version, jnp.uint32)
+                stale = (lo & pv) != jnp.asarray(pidx, jnp.uint32)
+            else:
+                stale = jnp.zeros_like(valid)
+            drop = ((expired | stale) & valid) | rule_drop
+            # pack: bit-pack the drop mask on device (8x fewer bytes to
+            # fetch); want_ets=False skips
+            # returning the rewritten-TTL column entirely when no rule or
+            # default-TTL can change it (the caller never reads it)
+            if pack:
+                drop = jnp.packbits(drop)
+            return (drop, ets2) if want_ets else (drop,)
 
     _EVAL_CACHE[key] = eval_block
     while len(_EVAL_CACHE) > _EVAL_CACHE_CAP:
@@ -347,6 +376,12 @@ def compaction_eval_submit(blocks, now, default_ttl, partition_version,
                     validate_hash, use_lo, want_ets=want_ets, pack=True)
                 drop = out[0]
                 new_ets = out[1] if want_ets else None
+                # key matrix + key_len, hashkey_len, expire_ts, pidx (4
+                # B each) + valid, and hash_lo where it is used; the
+                # packed mask and the rewritten expire_ts back
+                note_filter_program(
+                    cap, cap * (_w + 17 + (4 if use_lo else 0))
+                    + cap // 8 + (4 * cap if want_ets else 0))
                 submitted.append((spans, cap, drop, new_ets))
     return submitted
 

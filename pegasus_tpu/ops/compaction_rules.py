@@ -24,6 +24,7 @@ reference stores in the `user_specified_compaction` table env.
 
 from __future__ import annotations
 
+import functools
 import json
 from typing import Callable, List, Sequence, Tuple
 
@@ -32,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from pegasus_tpu.base.value_schema import PEGASUS_EPOCH_BEGIN
+from pegasus_tpu.ops.compaction import note_filter_program
 from pegasus_tpu.ops.predicates import (
     FT_MATCH_ANYWHERE,
     FT_MATCH_POSTFIX,
@@ -168,13 +170,25 @@ def compile_rules(spec) -> Callable:
     matching StorageEngine.manual_compact's hook signature; the predicate
     pipeline for the whole ruleset is one jitted device program. The
     parsed ruleset is exposed as `rules_filter.operations` so the bulk
-    block-level compactor can fuse it into its own program."""
+    block-level compactor can fuse it into its own program.
+
+    Compiled once per content: every replica of a table gets the same
+    env, again at every config sync, and a jitted program of its own
+    for each would be traced and lowered anew by each compaction."""
+    if not isinstance(spec, (str, bytes)):
+        spec = json.dumps(spec, sort_keys=True)
+    return _compile_rules_text(spec)
+
+
+@functools.lru_cache(maxsize=32)
+def _compile_rules_text(spec) -> Callable:
     operations = parse_rules(spec)
 
     @jax.jit
     def _eval(keys, key_len, hashkey_len, expire_ts, valid, now):
-        return apply_rules_ops(operations, keys, key_len, hashkey_len,
-                               expire_ts, valid, now)
+        with jax.named_scope("pegasus_compact_rules_filter"):
+            return apply_rules_ops(operations, keys, key_len, hashkey_len,
+                                   expire_ts, valid, now)
 
     def rules_filter(keys: Sequence[bytes], expire_ts, now: int
                      ) -> Tuple[np.ndarray, np.ndarray]:
@@ -192,6 +206,9 @@ def compile_rules(spec) -> Callable:
                           jnp.asarray(block.hashkey_len),
                           jnp.asarray(block.expire_ts),
                           jnp.asarray(block.valid), jnp.uint32(now))
+        # key matrix + key_len, hashkey_len, expire_ts (4 B each) +
+        # valid in; the mask and the rewritten expire_ts back
+        note_filter_program(cap, cap * (block.keys.shape[1] + 13 + 5))
         return np.asarray(drop)[:n], np.asarray(ets)[:n]
 
     rules_filter.operations = tuple(operations)

@@ -698,6 +698,7 @@ class ReplicaStub:
                 # scrub_tick/health_tick
                 sc = self.sim_clock
                 r.server.clock_ns = lambda: int(sc() * 1e9)
+            r.server.trace_node = self.name
             r.on_learn_completed = (
                 lambda learner, g=gpid: self._notify_learn_completed(g, learner))
             r.on_replication_error = (

@@ -81,6 +81,8 @@ from pegasus_tpu.server.types import (
 from pegasus_tpu.server.write_service import WriteService
 
 from pegasus_tpu.storage.bloom import bloom_probe_enabled
+from pegasus_tpu.storage import compact_governor
+from pegasus_tpu.storage.compact_governor import MANUAL_COMPACT_MAX_RUNNING
 from pegasus_tpu.storage.phash import phash_probe_enabled
 from pegasus_tpu.storage.engine import StorageEngine
 from pegasus_tpu.utils.errors import (
@@ -102,6 +104,8 @@ define_flag("pegasus.server", "scan_pushdown_enabled", True,
 # the no-filter flavor's mask key component (and the normal form of any
 # empty-pattern filter, which matches everything)
 _NO_FILTER_KEY = (FT_NO_FILTER, b"", FT_NO_FILTER, b"")
+# the sets of read-path mask shapes _warm_manual_compact has compiled
+_WARMED_MASKS: set = set()
 
 
 def _normalize_filter_key(r) -> tuple:
@@ -299,6 +303,10 @@ class PartitionServer:
         # env-driven remote manual compaction (one-shot trigger times)
         self._mc_trigger_seen = 0
         self._mc_running = False
+        self._mc_max_running = MANUAL_COMPACT_MAX_RUNNING
+        # whose ring and layer counters a traced run's spans land on
+        # (the stub names its node, as it threads in clock_ns)
+        self.trace_node = "local"
         # on-demand hotkey detection (parity: hotkey_collector.h:93 —
         # started via on_detect_hotkey; the request stream feeds capture
         # while a detection runs, else a None-check costs nothing)
@@ -443,6 +451,8 @@ class PartitionServer:
         "replica.slow_query_threshold_ms": ("_slow_threshold_ms", 20.0),
         "rocksdb.usage_scenario": ("_usage_scenario", "normal"),
         "user_specified_compaction": ("_compaction_rules", None),
+        "manual_compact.max_concurrent_running_count": (
+            "_mc_max_running", MANUAL_COMPACT_MAX_RUNNING),
     }
 
     def update_app_envs(self, envs: dict, full_set: bool = False) -> None:
@@ -488,6 +498,10 @@ class PartitionServer:
                 elif key == "user_specified_compaction":
                     staged.append(("_compaction_rules",
                                    compile_rules(value) if value else None))
+                elif key == "manual_compact.max_concurrent_running_count":
+                    staged.append(("_mc_max_running",
+                                   int(value) if value != ""
+                                   else MANUAL_COMPACT_MAX_RUNNING))
                 elif key == "manual_compact.once.trigger_time":
                     # accepts unix seconds (the reference's `date +%s`
                     # convention) or pegasus-epoch seconds; normalized
@@ -500,19 +514,24 @@ class PartitionServer:
             except Exception as exc:
                 raise ValueError(f"invalid app-env {key}={value!r}: {exc}") \
                     from exc
+        trigger = None
         for attr, parsed in staged:
             if attr == "_slow_threshold_ms":
                 self.slow_log.threshold_ms = parsed
             elif attr == "_usage_scenario":
                 self._apply_usage_scenario(parsed)
             elif attr == "_mc_once_trigger":
-                self._maybe_start_manual_compact(parsed)
+                trigger = parsed
             else:
                 setattr(self, attr, parsed)
         if full_set:
             self.app_envs = dict(envs)
         else:
             self.app_envs.update(envs)
+        if trigger is not None:
+            # last: a trigger compacts under the ruleset and the bound
+            # that came with it, whatever the map's order
+            self._maybe_start_manual_compact(trigger)
 
     def _maybe_start_manual_compact(self, trigger_ts: int) -> None:
         """Env-driven remote manual compaction (parity:
@@ -543,7 +562,18 @@ class PartitionServer:
         (_on_store_publish). Running it synchronously instead would
         hold the node lock (timers + dispatch share it) for the whole
         compaction — stalling FD beacons long enough to get the node
-        declared dead."""
+        declared dead.
+
+        How many run at once in this process (a node; every node of an
+        in-process SimCluster together) is bounded by its
+        ManualCompactPool (`manual_compact.max_concurrent_running_count`,
+        8 unless the table's env says otherwise): past the bound an
+        accepted run waits for a slot, counted as running here, so the
+        triggers that arrive meanwhile are absorbed all the same. An
+        accepted trigger first compiles what the run will dispatch
+        (_warm_manual_compact): the first one a process hears costs
+        the delivering thread that compile, and no later moment beside
+        traffic does."""
         from pegasus_tpu.storage.compact_governor import GOVERNOR
 
         # <=: a re-delivered trigger that already STARTED a run is
@@ -588,14 +618,84 @@ class PartitionServer:
                 shared_now = (trigger_ts
                               if abs(epoch_now() - trigger_ts) <= 600
                               else None)
-                self.manual_compact(now=shared_now)
+                # the thread's own root: its self time lands on the
+                # layer counters while a profiler session (or
+                # sampling) is on, as a client op's does
+                with tracing.background_root(self.trace_node, "compact.run"):
+                    self.manual_compact(now=shared_now)
             finally:
                 self._mc_running = False
                 GOVERNOR.end_heavy()
 
-        threading.Thread(
-            target=run, daemon=True,
-            name=f"manual-compact-{self.app_id}.{self.pidx}").start()
+        self._warm_manual_compact()
+        # bounded per process (manual_compact.max_concurrent_running_count):
+        # past the bound the run waits for a slot, _mc_running already
+        # set, so triggers that arrive meanwhile are absorbed
+        compact_governor.MANUAL_COMPACT_POOL.submit(
+            self, run, f"manual-compact-{self.app_id}.{self.pidx}",
+            limit=self._mc_max_running)
+
+    def _warm_manual_compact(self) -> None:
+        """On the thread that delivers an accepted trigger, before the
+        run is handed to the pool: compile what the run, and the reads
+        beside it, would otherwise compile where they first dispatch it
+        beside traffic. That is the engine's filter programs
+        (StorageEngine.warm_manual_compact) and, for every scan flavor
+        serving has used, what a scan batch falls back to when a
+        publish tears its plan: the static mask program over one block
+        and over a stack (_encoded_static_mask declines a replaced
+        run), and the per-request scan's predicate over a store with an
+        overlay, at each of _validate_batch's buckets. Once a process
+        for each set of shapes; a failure here is the compaction's to
+        meet again, not the env's."""
+        from pegasus_tpu.server.scan_coordinator import (
+            _fetch_wave,
+            stacked_block_submit,
+        )
+
+        try:
+            self.engine.warm_manual_compact(
+                default_ttl=self._default_ttl, pidx=self.pidx,
+                partition_version=self.partition_version,
+                validate_hash=self.validate_partition_hash,
+                rules_filter=self._compaction_rules)
+            run = next((r for r in list(self.engine.lsm.l1_runs)
+                        if r.blocks), None)
+            with self._mask_lock:
+                flavors = list(self._warm_flavors)
+            if run is None or not flavors:
+                return
+            bm = run.blocks[0]
+            shapes = (bm.key_width, bm.count,
+                      getattr(run, "codec", None) is not None,
+                      self.partition_version >= 0,
+                      tuple((v, fk[0], fk[2]) for v, fk in flavors))
+            if shapes in _WARMED_MASKS:
+                return
+            blk = run.read_block(0)
+            dev = self._device_cached_block((run.path, bm.offset), blk)
+            row = (blk.key_at(0), b"", 0)
+            now = epoch_now()
+            for validate, filter_key in flavors:
+                for height in (1, 2):   # one block; a padded stack
+                    _fetch_wave([keep for _g, _c, keep in
+                                 stacked_block_submit(
+                                     [(i, dev, self.pidx)
+                                      for i in range(height)],
+                                     validate, self.partition_version,
+                                     filter_key=filter_key)])
+                hft, hfp, sft, sfp = filter_key
+                cap = 256       # _validate_batch's smallest bucket
+                while cap <= PREDICATE_BATCH:
+                    self._validate_batch(
+                        [row] * cap, now, FilterSpec.make(hft, hfp),
+                        FilterSpec.make(sft, sfp), validate)
+                    cap <<= 1
+            _WARMED_MASKS.add(shapes)
+        except Exception:  # noqa: BLE001 - see the docstring
+            import traceback
+
+            traceback.print_exc()
 
     def _apply_usage_scenario(self, scenario: str) -> None:
         """Parity: the usage-scenario dynamic tuning
@@ -642,6 +742,11 @@ class PartitionServer:
                           self._deny_client in ("all", "read"))
 
     def close(self) -> None:
+        # an env-triggered compaction of this replica ends, or never
+        # starts, before its engine closes under it
+        if compact_governor.MANUAL_COMPACT_POOL.drain(self):
+            self._mc_running = False
+            compact_governor.GOVERNOR.end_heavy()
         self.engine.close()
 
     # ---- decree management (standalone mode) --------------------------

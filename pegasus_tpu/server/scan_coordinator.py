@@ -38,6 +38,7 @@ from typing import Dict, List, Tuple
 
 import numpy as np
 
+from pegasus_tpu.ops.compaction import note_filter_program
 from pegasus_tpu.ops.predicates import (
     FT_NO_FILTER,
     FilterSpec,
@@ -292,6 +293,17 @@ def stacked_block_submit(blocks, validate: bool, pv: int,
             stacked, hash_filter=hash_f, sort_filter=sort_f,
             validate_hash=validate, pidx=pidx, partition_version=pv,
             pack=True)
+        # key matrix + key_len, hashkey_len (4 B each) + valid, the
+        # hash column where it is used, the packed mask back; a stack
+        # also reads a pidx column, after its six columns were
+        # gathered on the device (read and written once)
+        rows, width = stacked.keys.shape
+        columns = width + 9 + (
+            4 if validate and stacked.hash_lo is not None else 0)
+        note_filter_program(
+            rows, rows * columns + rows // 8
+            + (rows * (4 + 2 * (width + 17)) if len(group) > 1 else 0),
+            kind="mask")
         yield group, cap, keep
 
 

@@ -35,6 +35,7 @@ read rate), `compact_throttle_mbps` (gauge, 0 = uncapped),
 
 from __future__ import annotations
 
+import collections
 import threading
 import time
 from typing import Callable, Optional
@@ -266,3 +267,103 @@ class CompactionGovernor:
 
 
 GOVERNOR = CompactionGovernor()
+
+# manual_compact.max_concurrent_running_count when the table env does
+# not set it (client/table.compact_partitions_parallel's pool size)
+MANUAL_COMPACT_MAX_RUNNING = 8
+
+
+class ManualCompactPool:
+    """Bounds the env-triggered manual compactions that run at once in
+    this PROCESS (parity: pegasus_manual_compact_service's
+    `manual_compact.max_concurrent_running_count`, which upstream
+    counts per replica server: one process a node there, so the same
+    thing; the nodes of an in-process SimCluster share this one pool as
+    they share GOVERNOR, and the env's count then bounds the whole
+    cluster). A trigger that finds every slot taken waits in FIFO order
+    and starts when a slot frees, on the worker that freed it, not at
+    the next config sync.
+
+    Counters (entity `engine`, id `manual_compact_pool`):
+    `compact_started`, `compact_finished`, `compact_deferred`; gauge
+    `compact_running_peak`. `history` keeps when each of the newest
+    runs finished and how long it took (time.perf_counter seconds),
+    as upstream's query_compact_state tells an operator."""
+
+    def __init__(self, name: str = "manual_compact_pool") -> None:
+        self._cv = threading.Condition()
+        self._waiting: collections.deque = collections.deque()
+        self._owners: list = []     # of the runs on a worker now
+        self.running_peak = 0
+        self.history: collections.deque = collections.deque(maxlen=4096)
+        ent = METRICS.entity("engine", name)
+        self._c_started = ent.counter("compact_started")
+        self._c_finished = ent.counter("compact_finished")
+        self._c_deferred = ent.counter("compact_deferred")
+        self._g_peak = ent.gauge("compact_running_peak")
+
+    @property
+    def running(self) -> int:
+        return len(self._owners)
+
+    def submit(self, owner, fn: Callable[[], None], name: str,
+               limit: int = MANUAL_COMPACT_MAX_RUNNING) -> None:
+        """Run `fn` for `owner` on a worker now, or when one of the
+        `limit` slots frees (`limit` <= 0: no bound, as upstream)."""
+        with self._cv:
+            if 0 < limit <= self.running:
+                self._waiting.append((owner, fn))
+                self._c_deferred.increment()
+                return
+            self._owners.append(owner)
+            if self.running > self.running_peak:
+                self.running_peak = self.running
+                self._g_peak.set(self.running)
+        threading.Thread(target=self._work, args=(owner, fn),
+                         daemon=True, name=name).start()
+
+    def _work(self, owner, fn: Callable[[], None]) -> None:
+        while True:
+            self._c_started.increment()
+            t0 = time.perf_counter()
+            try:
+                fn()
+            except Exception:  # noqa: BLE001 - one failed compaction
+                # must not take the slot's queue down with it; the
+                # trigger stays unsatisfied (no finish time recorded)
+                # and the next one compacts again
+                import traceback
+
+                traceback.print_exc()
+            self._c_finished.increment()
+            now = time.perf_counter()
+            self.history.append((now, now - t0))
+            with self._cv:
+                self._owners.remove(owner)
+                self._cv.notify_all()
+                if not self._waiting:
+                    return
+                owner, fn = self._waiting.popleft()
+                self._owners.append(owner)
+
+    def wait_idle(self, timeout: float) -> bool:
+        """Wait until nothing runs and nothing waits; False when
+        `timeout` seconds did not see that."""
+        with self._cv:
+            return self._cv.wait_for(
+                lambda: not self._owners and not self._waiting, timeout)
+
+    def drain(self, owner) -> bool:
+        """Forget what waits for `owner` and wait for what runs for it
+        (the owner's engine is about to close). True when a waiting
+        run was dropped."""
+        with self._cv:
+            kept = [e for e in self._waiting if e[0] is not owner]
+            dropped = len(kept) != len(self._waiting)
+            self._waiting = collections.deque(kept)
+            while any(o is owner for o in self._owners):
+                self._cv.wait()
+        return dropped
+
+
+MANUAL_COMPACT_POOL = ManualCompactPool()

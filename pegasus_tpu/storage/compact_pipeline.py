@@ -58,6 +58,7 @@ import time
 from typing import Callable, Iterator, List, Optional, Sequence
 
 from pegasus_tpu.utils.flags import FLAGS, define_flag
+from pegasus_tpu.utils import tracing
 from pegasus_tpu.utils.metrics import METRICS
 
 define_flag("pegasus.storage", "compact_pipeline", True,
@@ -155,8 +156,8 @@ class CompactPipeline:
                  submit: Callable[[List], object],
                  drain: Callable[[object], List],
                  window: int, depth: int = 2,
-                 eager: Optional[Callable[[object], bool]] = None
-                 ) -> None:
+                 eager: Optional[Callable[[object], bool]] = None,
+                 span=None) -> None:
         self._entries = entries
         self._load = load
         self._submit = submit
@@ -167,6 +168,9 @@ class CompactPipeline:
         # starve the write stage — drain and forward it immediately
         self._eager = eager or (lambda _t: False)
         self._window = max(1, window)
+        # the compaction's span where it is traced: the stage threads
+        # frame their work as its children (tracing.adopt)
+        self._span = span
         self._stop = threading.Event()
         self._q_read: "queue.Queue" = queue.Queue(maxsize=max(1, depth))
         self._q_filt: "queue.Queue" = queue.Queue(maxsize=max(1, depth))
@@ -202,6 +206,14 @@ class CompactPipeline:
     # ---- stages ---------------------------------------------------------
 
     def _read_stage(self) -> None:
+        with tracing.adopt(self._span, "compact.read"):
+            self._read_stage_body()
+
+    def _filter_stage(self) -> None:
+        with tracing.adopt(self._span, "compact.filter"):
+            self._filter_stage_body()
+
+    def _read_stage_body(self) -> None:
         try:
             w = self._window
             for off in range(0, len(self._entries), w):
@@ -216,7 +228,7 @@ class CompactPipeline:
         except BaseException as e:  # noqa: BLE001 - travels to consumer
             self._put(self._q_read, _StageError(e), _READ_STALL_MS)
 
-    def _filter_stage(self) -> None:
+    def _filter_stage_body(self) -> None:
         pending = None
         try:
             while not self._stop.is_set():

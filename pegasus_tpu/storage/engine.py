@@ -27,7 +27,10 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 from pegasus_tpu.base.value_schema import epoch_now
-from pegasus_tpu.ops.compaction import compaction_filter_block
+from pegasus_tpu.ops.compaction import (
+    compaction_filter_block,
+    note_filter_program,
+)
 from pegasus_tpu.ops.record_block import build_record_block
 # imported for their flag definitions (compact_pipeline /
 # compact_max_mbps etc. must exist before any config file applies)
@@ -35,7 +38,11 @@ from pegasus_tpu.storage import compact_governor  # noqa: F401
 from pegasus_tpu.storage import compact_pipeline  # noqa: F401
 from pegasus_tpu.storage.lsm import LSMStore
 from pegasus_tpu.storage.wal import OP_DEL, OP_PUT, WalRecord, WriteAheadLog
-from pegasus_tpu.utils.tracing import layer
+from pegasus_tpu.utils.tracing import frame_span, layer, mark
+
+
+# the sets of shapes warm_manual_compact has compiled in this process
+_WARMED: set = set()
 
 
 @dataclass
@@ -103,6 +110,16 @@ class StorageEngine:
         self._ev_compact_count = ev.counter("compaction_count")
         self._ev_compact_bytes = ev.counter("compaction_bytes")
         self._ev_compact_ms = ev.percentile("compaction_duration_ms")
+        # what the compaction filter saw and did, both paths (a dropped
+        # row counts under `ttl` when its rewritten expire_ts has run
+        # out or it is stale split data, else under `rules`)
+        self._ev_compact_bytes_in = ev.counter("compact_bytes_in")
+        self._ev_compact_rows_in = ev.counter("compact_rows_in")
+        self._ev_dropped_rules = ev.counter("compact_rows_dropped_rules")
+        self._ev_dropped_ttl = ev.counter("compact_rows_dropped_ttl")
+        self._ev_ttl_rewritten = ev.counter("compact_rows_ttl_rewritten")
+        self._ev_path_bulk = ev.counter("compact_path_bulk")
+        self._ev_path_merge = ev.counter("compact_path_merge")
 
         # replay WAL beyond the flushed watermark
         self._wal_path = os.path.join(data_dir, "wal.log")
@@ -280,6 +297,25 @@ class StorageEngine:
 
     # ---- compaction ---------------------------------------------------
 
+    def _count_filtered(self, nbytes: int, ets_orig, drop, new_ets,
+                        now_s: int, not_rules=None) -> None:
+        """One filtered block of the block path into the engine's
+        counters. `not_rules`: rows known dropped for another reason
+        than the rules (stale split data: the path has the hash
+        column)."""
+        ets_orig = np.asarray(ets_orig, dtype=np.uint32)
+        new_ets = ets_orig if new_ets is None else np.asarray(new_ets)
+        by_ttl = (new_ets > 0) & (new_ets <= np.uint32(now_s))
+        if not_rules is not None:
+            by_ttl = by_ttl | not_rules
+        n_rules = int(np.count_nonzero(drop & ~by_ttl))
+        self._ev_compact_bytes_in.increment(int(nbytes))
+        self._ev_compact_rows_in.increment(len(ets_orig))
+        self._ev_dropped_rules.increment(n_rules)
+        self._ev_dropped_ttl.increment(int(np.count_nonzero(drop)) - n_rules)
+        self._ev_ttl_rewritten.increment(
+            int(np.count_nonzero(~drop & (new_ets != ets_orig))))
+
     def _manual_compact_bulk(self, now_s: int, default_ttl: int,
                              pidx: int, partition_version: int,
                              do_validate: bool, operations,
@@ -322,6 +358,7 @@ class StorageEngine:
             window_count,
         )
 
+        self._ev_path_bulk.increment()
         ttl_may_change = bool(default_ttl) or bool(
             operations and any(op.op == "update_ttl" for op in operations))
         eval_device = choose_eval_device(workload=rules_workload(operations))
@@ -370,8 +407,11 @@ class StorageEngine:
             run, i, bm = entry
             GOVERNOR.acquire(bm.size)
             if direct(run):
-                return (run, i, run.read_block_encoded(i), True)
-            return (run, i, run.read_block(i), False)
+                out = (run, i, run.read_block_encoded(i), True)
+            else:
+                out = (run, i, run.read_block(i), False)
+            mark("compact_read")
+            return out
 
         def submit_window(items):
             """FILTER stage phase 1: dispatch without waiting."""
@@ -401,6 +441,7 @@ class StorageEngine:
                 do_validate, operations=operations,
                 eval_device=eval_device,
                 want_ets=ttl_may_change) if blocks else []
+            mark("compact_filter_submit")
             return items, pend, host_done
 
         def drain_window(token):
@@ -418,13 +459,23 @@ class StorageEngine:
                 if m is None:
                     m = got[(run, i)]
                 drop, new_ets = m
+                self._count_filtered(
+                    run.blocks[i].size, blk.expire_ts, drop, new_ets,
+                    now_s, not_rules=(
+                        (np.asarray(blk.hash_lo)
+                         & np.uint32(max(partition_version, 0)))
+                        != np.uint32(pidx)
+                        if do_validate and blk.hash_lo is not None
+                        else None))
                 out.append((run, i, blk, drop, new_ets))
+            mark("compact_filter_drain")
             return out
 
         if pipeline_enabled() and stage_threads_enabled():
             pipe = CompactPipeline(
                 entries, load, submit_window, drain_window,
                 window=pipeline_window(), depth=pipeline_depth(),
+                span=frame_span(),
                 # a window whose masks all computed host-direct at
                 # submit has no in-flight device program to hide:
                 # forward it immediately instead of holding the
@@ -503,20 +554,65 @@ class StorageEngine:
                 advance_watermark=publish_lock is None)
             return
 
+        self._ev_path_merge.increment()
+        record_filter = self._merge_record_filter(
+            now_s, default_ttl, pidx, partition_version, do_validate,
+            rules_filter)
+
+        self._compact_with_epilogue(
+            lambda: self._ev_compact_bytes_in.increment(self.lsm.compact(
+                record_filter=record_filter,
+                patch_headers=self.values_carry_expire_header,
+                publish_lock=publish_lock,
+                meta={
+                    # see _manual_compact_bulk: snapshot mode covers
+                    # only the freeze-time watermark
+                    "last_flushed_decree": (
+                        self.last_flushed_decree
+                        if publish_lock is not None
+                        else self.last_committed_decree),
+                    "data_version": self.data_version,
+                    "manual_compact_finish_time": epoch_now(),
+                })),
+            advance_watermark=publish_lock is None)
+
+    def _merge_record_filter(self, now_s: int, default_ttl: int, pidx: int,
+                             partition_version: int, do_validate: bool,
+                             rules_filter, count: bool = True):
+        """The per-record path's filter for lsm.compact: `(keys, ets) ->
+        (drop, new_ets)`, both lazy device values at the batch's
+        power-of-two bucket. With `count` every batch lands on the
+        engine's counters from what is on the host already (the rules'
+        mask and the rewritten expire_ts; expiry is one compare; the
+        stale split rows the device drops besides stay uncounted
+        here)."""
+        import jax.numpy as jnp
+
         def record_filter(keys: List[bytes], ets: List[int]):
             n = len(keys)
             # Stage 1 — default-TTL rewrite (reference does this FIRST and
             # hands the rewritten value to the user rules, Filter():72-79).
-            ets_arr = np.asarray(ets, dtype=np.uint32)
+            ets_in = np.asarray(ets, dtype=np.uint32)
+            ets_arr = ets_in
             if default_ttl:
                 ets_arr = np.where(ets_arr == 0,
                                    np.uint32(now_s + default_ttl), ets_arr)
             # Stage 2 — user-specified rules see the rewritten TTLs.
             if rules_filter is not None:
                 rule_drop, ets_arr = rules_filter(keys, ets_arr, now_s)
+                rule_drop = np.asarray(rule_drop)
                 ets_arr = np.asarray(ets_arr, dtype=np.uint32)
             else:
                 rule_drop = np.zeros(n, dtype=bool)
+            if count:
+                expired = (ets_arr > 0) & (ets_arr <= np.uint32(now_s))
+                self._ev_compact_rows_in.increment(n)
+                self._ev_dropped_rules.increment(
+                    int(np.count_nonzero(rule_drop & ~expired)))
+                self._ev_dropped_ttl.increment(
+                    int(np.count_nonzero(expired)))
+                self._ev_ttl_rewritten.increment(int(np.count_nonzero(
+                    ~rule_drop & ~expired & (ets_arr != ets_in))))
             # Stage 3 — expiry + stale-split drop on device (default_ttl=0:
             # the rewrite already happened; a rule that cleared a TTL must
             # not be re-stamped).
@@ -534,31 +630,86 @@ class StorageEngine:
                 np.uint32(pidx),
                 np.uint32(max(partition_version, 0)),
                 do_validate)
+            # as compile_rules' program, plus the rules' mask up
+            note_filter_program(cap, cap * (block.keys.shape[1] + 14 + 5))
             # stay LAZY: combining on device keeps the result an async
             # jax value, so the LSM's double-buffered compaction really
             # overlaps this batch's device work with the next batch's
-            # host gathering (materialization happens at drain)
-            import jax.numpy as jnp
+            # host gathering (materialization happens at drain). At the
+            # bucket's width: a slice to n would be a program of its
+            # own for every n a partition ever has (the store reads the
+            # first n of both)
+            padded = np.zeros(cap, dtype=bool)
+            padded[:n] = rule_drop
+            return jnp.logical_or(drop, jnp.asarray(padded)), new_ets
 
-            drop = jnp.logical_or(drop[:n], jnp.asarray(rule_drop))
-            return drop, new_ets[:n]
+        return record_filter
 
-        self._compact_with_epilogue(
-            lambda: self.lsm.compact(
-                record_filter=record_filter,
-                patch_headers=self.values_carry_expire_header,
-                publish_lock=publish_lock,
-                meta={
-                    # see _manual_compact_bulk: snapshot mode covers
-                    # only the freeze-time watermark
-                    "last_flushed_decree": (
-                        self.last_flushed_decree
-                        if publish_lock is not None
-                        else self.last_committed_decree),
-                    "data_version": self.data_version,
-                    "manual_compact_finish_time": epoch_now(),
-                }),
-            advance_watermark=publish_lock is None)
+    def warm_manual_compact(self, default_ttl: int = 0, pidx: int = 0,
+                            partition_version: int = -1,
+                            validate_hash: bool = False,
+                            rules_filter=None) -> None:
+        """Compile now what a manual compaction of this store, as it
+        stands, would compile where it first dispatches it: the
+        per-record path's filter programs at the buckets of its
+        batches, the block path's fused program at the buckets of its
+        windows, the placement probe. Rows of the store's own first
+        block ride through the code a compaction runs; nothing is
+        written, and of the counters only the dispatched filter
+        programs' move. Once a process for each set of shapes: the
+        programs are jit-cached process-wide, so the first replica of a
+        table pays for its siblings."""
+        from pegasus_tpu.ops.compaction import (
+            _row_bucket,
+            choose_eval_device,
+            compaction_eval_drain,
+            compaction_eval_submit,
+            rules_workload,
+        )
+        from pegasus_tpu.storage.compact_pipeline import pipeline_window
+
+        runs = list(self.lsm.l1_runs)
+        counts = [bm.count for run in runs for bm in run.blocks]
+        if not counts:
+            return
+        operations = getattr(rules_filter, "operations", None)
+        do_validate = bool(validate_hash and partition_version >= 0
+                           and pidx <= partition_version)
+        rows, batch = sum(counts), self.lsm.filter_batch_rows
+        batches = {min(rows, batch), rows % batch or batch}
+        win = pipeline_window()
+        windows = {_row_bucket(sum(counts[off:off + win])): sum(
+            counts[off:off + win]) for off in range(0, len(counts), win)}
+        first = next(run for run in runs if run.blocks)
+        shapes = (rules_filter, first.blocks[0].key_width,
+                  frozenset(_row_bucket(n) for n in batches),
+                  frozenset(windows), do_validate, bool(default_ttl),
+                  getattr(first, "codec", None) is not None)
+        if shapes in _WARMED:
+            return
+        blk = first.read_block(0)
+        now_s = epoch_now()
+        record_filter = self._merge_record_filter(
+            now_s, default_ttl, pidx, partition_version, do_validate,
+            rules_filter, count=False)
+        for n in batches:
+            for lazy in record_filter([blk.key_at(0)] * n, [0] * n):
+                np.asarray(lazy)
+        # the block path is taken, and evaluates on the device, with a
+        # parsed ruleset, or with none over an uncompressed store
+        if operations is not None or (rules_filter is None
+                                      and not shapes[-1]):
+            ttl_may_change = bool(default_ttl) or any(
+                op.op == "update_ttl" for op in operations or ())
+            eval_device = choose_eval_device(
+                workload=rules_workload(operations))
+            for n in windows.values():
+                list(compaction_eval_drain(compaction_eval_submit(
+                    [(i, blk, pidx) for i in range(-(-n // blk.count))],
+                    now_s, default_ttl, partition_version, do_validate,
+                    operations=operations, eval_device=eval_device,
+                    want_ets=ttl_may_change), want_ets=ttl_may_change))
+        _WARMED.add(shapes)
 
     def _compact_with_epilogue(self, body,
                                advance_watermark: bool = True) -> None:

@@ -47,6 +47,7 @@ from pegasus_tpu.storage.sstable import (
     SSTable,
     SSTableWriter,
 )
+from pegasus_tpu.utils.tracing import mark
 
 # (key, value|None, expire_ts) record triple
 Record = Tuple[bytes, Optional[bytes], int]
@@ -358,8 +359,9 @@ class LSMStore:
         meta: Optional[dict] = None,
         patch_headers: bool = False,
         publish_lock=None,
-    ) -> None:
-        """Full compaction as a sequence of BOUNDED range steps.
+    ) -> int:
+        """Full compaction as a sequence of BOUNDED range steps. Returns
+        the key + value bytes of the rows it merged (its input).
 
         One merged pass over the overlay + L1 runs; output runs are
         size-capped (`l1_run_capacity`), so no monolithic rewrite and a
@@ -375,11 +377,17 @@ class LSMStore:
         flushes) survive untouched and keep shadowing the merged base.
 
         `record_filter(keys: List[bytes], expire_ts: List[int]) ->
-        (drop_mask, new_expire)` is the device TTL/compaction-rule seam
+        (drop_mask, new_expire)` (each at least len(keys) long; the
+        first len(keys) are read) is the device TTL/compaction-rule seam
         (engine.StorageEngine wires it); evaluation is DOUBLE-BUFFERED:
         while the device filters batch N, the host gathers batch N+1
         (jax dispatch is asynchronous — only materialization blocks).
         Tombstones always drop (bottommost).
+
+        Stage points (utils/tracing, layer `compact`): compact_merge
+        (the merged iteration: block reads, decode, heap merge),
+        compact_filter_submit, compact_filter_drain, compact_write,
+        compact_publish.
         """
         runs_snap = list(self.l1_runs)
         if publish_lock is not None:
@@ -432,10 +440,17 @@ class LSMStore:
         # pipeline state: the batch whose filter is in flight on device
         pending: Optional[tuple] = None
 
-        def submit(keys, vals, ets):
+        def submit(keys, vals, ets, nbytes):
+            mark("compact_merge")
+            # the merge path's input pacing: one governor charge per
+            # filter batch (the bulk path pays per block) — background
+            # bandwidth answers foreground pressure on BOTH compaction
+            # shapes
+            GOVERNOR.acquire(nbytes)
             if record_filter is None:
                 return (keys, vals, ets, None, ets)
             drop, new_ets = record_filter(keys, ets)
+            mark("compact_filter_submit")
             # jax returns asynchronously-evaluated arrays; conversion to
             # numpy in drain() is the synchronization point
             return (keys, vals, ets, drop, new_ets)
@@ -446,18 +461,17 @@ class LSMStore:
                 # materialize = the device synchronization point
                 drop = np.asarray(drop)
                 new_ets = np.asarray(new_ets)
+                mark("compact_filter_drain")
             write_records(keys, vals, ets_orig, drop, new_ets)
+            mark("compact_write")
 
         from pegasus_tpu.storage.compact_governor import GOVERNOR
 
         batch_keys: List[bytes] = []
         batch_vals: List[bytes] = []
         batch_ets: List[int] = []
-        batch_bytes = 0
-        # the FILTER batch is much larger than the write-block size: a
-        # high-RTT device pays per dispatch, so the compactor amortizes
-        # 16 blocks of records into each filter evaluation
-        filter_batch = self._block_capacity * 16
+        batch_bytes = bytes_in = 0
+        filter_batch = self.filter_batch_rows
         ok = False
         try:
             for key, value, ets in merged:
@@ -468,20 +482,18 @@ class LSMStore:
                 batch_ets.append(ets)
                 batch_bytes += len(key) + len(value)
                 if len(batch_keys) >= filter_batch:
-                    # the merge path's input pacing: one governor
-                    # charge per filter batch (the bulk path pays per
-                    # block) — background bandwidth answers foreground
-                    # pressure on BOTH compaction shapes
-                    GOVERNOR.acquire(batch_bytes)
-                    entry = submit(batch_keys, batch_vals, batch_ets)
+                    entry = submit(batch_keys, batch_vals, batch_ets,
+                                   batch_bytes)
                     if pending is not None:
                         drain(pending)
                     pending = entry
                     batch_keys, batch_vals, batch_ets = [], [], []
+                    bytes_in += batch_bytes
                     batch_bytes = 0
             if batch_keys:
-                GOVERNOR.acquire(batch_bytes)
-                entry = submit(batch_keys, batch_vals, batch_ets)
+                bytes_in += batch_bytes
+                entry = submit(batch_keys, batch_vals, batch_ets,
+                               batch_bytes)
                 if pending is not None:
                     drain(pending)
                 pending = entry
@@ -494,11 +506,14 @@ class LSMStore:
             ok = True
         finally:
             finish_pool.shutdown(ok, open_writer=writer)
+        mark("compact_write")
 
         self._publish_l1(new_runs, consumed_l0=l0_snap,
                          old_runs=runs_snap, publish_lock=publish_lock,
                          mcft=(meta or {}).get(
                              "manual_compact_finish_time", 0))
+        mark("compact_publish")
+        return bytes_in
 
     def _publish_l1(self, new_runs: List[SSTable],
                     consumed_l0: Optional[List[SSTable]] = None,
@@ -585,6 +600,14 @@ class LSMStore:
                 and bool(self.l1_runs)
                 and all(getattr(r, "_has_hash_lo", False)
                         for r in self.l1_runs))
+
+    @property
+    def filter_batch_rows(self) -> int:
+        """Rows a filter batch of compact() holds: much larger than the
+        write-block size, because a high-RTT device pays per dispatch,
+        so the compactor amortizes 16 blocks of records into each
+        filter evaluation."""
+        return self._block_capacity * 16
 
     def bulk_compact_entries(self):
         """Every L1 block in global key order: [(run, idx, BlockMeta)]."""
@@ -838,6 +861,7 @@ class LSMStore:
             ok = True
         finally:
             finish_pool.shutdown(ok, open_writer=writer)
+        mark("compact_write")
         # memtable/L0 are untouched by construction
         # (bulk_compact_eligible requires them empty at snapshot time;
         # writes that arrived since stay in the live overlay)
@@ -845,6 +869,7 @@ class LSMStore:
                          publish_lock=publish_lock,
                          mcft=(meta or {}).get(
                              "manual_compact_finish_time", 0))
+        mark("compact_publish")
 
 
 class _FinishPool:

@@ -158,7 +158,7 @@ def profiling() -> bool:
 # ---- layers --------------------------------------------------------------
 
 LAYER_KEYS = ("client", "rpc", "gate", "coord", "overlay", "index",
-              "decode", "dispatch", "repl", "engine", "other")
+              "decode", "dispatch", "repl", "engine", "compact", "other")
 
 # span or stage name -> layer key. A name is looked up whole, then by
 # what stands before its first "." (client.<op>, prepare.<dst>,
@@ -197,6 +197,14 @@ LAYER_OF = {
     "committed_applied": "repl", "replied": "repl",
     # storage/engine.py
     "engine": "engine",
+    # an env-triggered manual compaction on its own thread: the root
+    # compact.run (partition_server), the bulk path's stage threads
+    # compact.read / compact.filter (compact_pipeline), and the stages
+    # of both paths (storage/lsm.py, storage/engine.py)
+    "compact": "compact", "compact_read": "compact",
+    "compact_merge": "compact", "compact_filter_submit": "compact",
+    "compact_filter_drain": "compact", "compact_write": "compact",
+    "compact_publish": "compact",
 }
 
 
@@ -638,10 +646,36 @@ def layer(name: str):
     this thread's top frame, with a frame of its own. It is never
     ambient, so contexts on the wire and LatencyTracer annotations stay
     with the dispatch span. Untraced: one thread-local read."""
-    fr = getattr(_tls, "frames", None)
-    if not fr:
+    return adopt(frame_span(), name)
+
+
+def background_root(node: str, name: str):
+    """Scope of a background thread's own work (a manual compaction):
+    a root span with a frame on this thread, under the switch a client
+    op's root has (sampling, or a jax.profiler session). Its frames'
+    self time lands on the node's layer counters and its duration on
+    `traced_us`, beside the client roots'."""
+    profiled = profiling()
+    if not profiled and not maybe_sample():
         return _NULL
-    parent = fr[-1].span
+    return _Scope(ring_for(node).start(name, profiled=profiled))
+
+
+def frame_span() -> Optional[Span]:
+    """The span of this thread's top frame (None when untraced): what
+    a helper thread `adopt`s."""
+    fr = getattr(_tls, "frames", None)
+    return fr[-1].span if fr else None
+
+
+def adopt(parent: Optional[Span], name: str):
+    """A child span of `parent` with a frame on THIS thread (`_NULL`
+    for None). On the parent's own thread that is `layer`; on a helper
+    thread (a compaction's stage threads) its self time lands on the
+    layer counters and its duration joins no parent frame and no
+    `traced_us`: the parent's thread is covering the same wall time."""
+    if parent is None:
+        return _NULL
     return _Scope(parent.ring.start(name, parent=parent))
 
 
