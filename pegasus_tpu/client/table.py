@@ -32,13 +32,30 @@ def compact_partitions_parallel(servers, parallel: Optional[int] = None,
     3-5x slower than 8-way on two independent environments — the
     round-3 serial heuristic was the single largest bench regression).
 
+    The pool is for stores of more than one pipeline window of blocks
+    (`compact_pipeline_window`): their native kernels, device waits
+    and fsyncs are long enough to overlap. A smaller store's compaction
+    is a few hundred short calls under the interpreter lock, and beside
+    other threads each of them waits for that lock (my chip run, PR 28:
+    192 replicas of 8 blocks took 11.1 s one after another, 15.3 s on 3
+    threads, 15.8 s on 8): such stores are compacted on the calling
+    thread, one after another, before the pool starts.
+
     `device` pins workers' jax dispatch: jax.default_device is
     thread-local, so the caller's context does not reach the pool."""
     import contextlib
     from concurrent.futures import ThreadPoolExecutor
 
+    from pegasus_tpu.storage.compact_pipeline import pipeline_window
+
     if parallel is None:
         parallel = 8
+
+    def one_window(srv) -> bool:
+        lsm = srv.engine.lsm
+        rows = len(lsm.memtable) + sum(
+            t.total_count for t in list(lsm.l0) + list(lsm.l1_runs))
+        return rows <= pipeline_window() * lsm._block_capacity
 
     def one(srv):
         ctx = contextlib.nullcontext()
@@ -49,9 +66,16 @@ def compact_partitions_parallel(servers, parallel: Optional[int] = None,
         with ctx:
             srv.manual_compact(**compact_kwargs)
 
-    with ThreadPoolExecutor(max_workers=max(1, parallel)) as ex:
-        for f in [ex.submit(one, s) for s in servers]:
-            f.result()
+    pooled = []
+    for srv in servers:
+        if parallel > 1 and not one_window(srv):
+            pooled.append(srv)
+        else:
+            one(srv)
+    if pooled:
+        with ThreadPoolExecutor(max_workers=max(1, parallel)) as ex:
+            for f in [ex.submit(one, s) for s in pooled]:
+                f.result()
 
 
 class Table:

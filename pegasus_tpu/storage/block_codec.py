@@ -258,7 +258,7 @@ def _ragged_gather(flat: np.ndarray, starts: np.ndarray,
     return flat[pos]
 
 
-def _ragged_scatter(dst: np.ndarray, dst_starts: np.ndarray,
+def ragged_scatter(dst: np.ndarray, dst_starts: np.ndarray,
                     src: np.ndarray, src_starts: np.ndarray,
                     lens: np.ndarray) -> None:
     """dst[dst_starts[i]:+lens[i]] = src[src_starts[i]:+lens[i]]."""
@@ -451,11 +451,23 @@ class EncodedBlock:
                  "hash_lo", "flags", "hk_idx", "dict_offs", "dict_heap",
                  "sk_heap", "sk_offs", "hk_len", "value_offs",
                  "_heap_comp", "heap_mode", "raw_heap_len",
-                 "has_malformed", "_sentinel", "version")
+                 "has_malformed", "_sentinel", "version", "_keys")
 
     @property
     def count(self) -> int:
         return self.n
+
+    @property
+    def keys(self) -> np.ndarray:
+        """The key matrix, rebuilt on first use and kept: what a filter
+        program with rules reads of a block whose bytes the compaction
+        then copies or subsets as they are (the value heap stays
+        deflated)."""
+        try:
+            return self._keys
+        except AttributeError:
+            self._keys = self.key_matrix()
+            return self._keys
 
     @staticmethod
     def parse(raw) -> "EncodedBlock":
@@ -603,12 +615,12 @@ class EncodedBlock:
             hl = hk_len[nrm]
             out[nrm, 0] = (hl >> 8).astype(np.uint8)
             out[nrm, 1] = (hl & 0xFF).astype(np.uint8)
-            _ragged_scatter(flat, nrm * width + 2, self.dict_heap,
+            ragged_scatter(flat, nrm * width + 2, self.dict_heap,
                             self.dict_offs.astype(np.int64)[
                                 self.hk_idx[nrm]], hl)
         sk_start = np.where(normal, 2 + hk_len, np.int64(0))
         sk_len = self.sk_offs[1:] - self.sk_offs[:-1]
-        _ragged_scatter(flat, rows * width + sk_start, self.sk_heap,
+        ragged_scatter(flat, rows * width + sk_start, self.sk_heap,
                         self.sk_offs[:-1], sk_len)
         return out
 

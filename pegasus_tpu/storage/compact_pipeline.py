@@ -12,7 +12,9 @@ they hide behind the slowest one.
 Topology (one compaction = one pipeline; stages are threads, the
 inter-stage queues are bounded so memory stays a few windows deep):
 
-    READ thread    walks the L1 block entries in key order, reads the
+    READ thread    walks the snapshot's entries in key order (a block
+                   that flows unchanged, or a splice of chain blocks
+                   and overlay rows), reads the
                    raw/encoded block bytes (paced through the
                    CompactionGovernor token bucket — this is where
                    background IO meets the foreground-pressure
@@ -144,7 +146,8 @@ class _StageError:
 class CompactPipeline:
     """One pipelined bulk compaction.
 
-    `load(entry)` runs on the READ thread per block entry;
+    `load(entry)` runs on the READ thread per entry and gives a list
+    of items (one block, or the blocks a splice merges);
     `submit(items)` / `drain(token)` run on the FILTER thread per
     window (submit dispatches without waiting, drain materializes —
     the pipeline keeps one window submitted ahead). The `results()`
@@ -219,8 +222,8 @@ class CompactPipeline:
             for off in range(0, len(self._entries), w):
                 if self._stop.is_set():
                     return
-                items = [self._load(e)
-                         for e in self._entries[off:off + w]]
+                items = [item for e in self._entries[off:off + w]
+                         for item in self._load(e)]
                 _READQ_DEPTH.set(self._q_read.qsize())
                 if not self._put(self._q_read, items, _READ_STALL_MS):
                     return

@@ -36,7 +36,11 @@ from pegasus_tpu.ops.record_block import build_record_block
 # compact_max_mbps etc. must exist before any config file applies)
 from pegasus_tpu.storage import compact_governor  # noqa: F401
 from pegasus_tpu.storage import compact_pipeline  # noqa: F401
-from pegasus_tpu.storage.lsm import LSMStore
+from pegasus_tpu.storage.lsm import (
+    TRANSFORM_CHUNK_BLOCKS,
+    LSMStore,
+    Splice,
+)
 from pegasus_tpu.storage.wal import OP_DEL, OP_PUT, WalRecord, WriteAheadLog
 from pegasus_tpu.utils.tracing import frame_span, layer, mark
 
@@ -120,6 +124,12 @@ class StorageEngine:
         self._ev_ttl_rewritten = ev.counter("compact_rows_ttl_rewritten")
         self._ev_path_bulk = ev.counter("compact_path_bulk")
         self._ev_path_merge = ev.counter("compact_path_merge")
+        # the block path's sequence: blocks that flowed unchanged into
+        # the filter, chain blocks decoded and merged with overlay rows,
+        # and those rows
+        self._ev_blocks_chained = ev.counter("compact_blocks_chained")
+        self._ev_blocks_spliced = ev.counter("compact_blocks_spliced")
+        self._ev_overlay_rows = ev.counter("compact_overlay_rows")
 
         # replay WAL beyond the flushed watermark
         self._wal_path = os.path.join(data_dir, "wal.log")
@@ -298,29 +308,39 @@ class StorageEngine:
     # ---- compaction ---------------------------------------------------
 
     def _count_filtered(self, nbytes: int, ets_orig, drop, new_ets,
-                        now_s: int, not_rules=None) -> None:
+                        now_s: int, not_rules=None, flags=None) -> None:
         """One filtered block of the block path into the engine's
         counters. `not_rules`: rows known dropped for another reason
         than the rules (stale split data: the path has the hash
-        column)."""
+        column). `flags`: a chained L0 block's tombstones are no rows
+        (the per-record path's merge has dropped them before it
+        counts)."""
         ets_orig = np.asarray(ets_orig, dtype=np.uint32)
         new_ets = ets_orig if new_ets is None else np.asarray(new_ets)
         by_ttl = (new_ets > 0) & (new_ets <= np.uint32(now_s))
         if not_rules is not None:
             by_ttl = by_ttl | not_rules
+        n_rows = len(ets_orig)
+        if flags is not None and np.any(flags):
+            live = np.asarray(flags) == 0
+            drop = drop & live
+            new_ets = np.where(live, new_ets, ets_orig)
+            n_rows = int(np.count_nonzero(live))
         n_rules = int(np.count_nonzero(drop & ~by_ttl))
         self._ev_compact_bytes_in.increment(int(nbytes))
-        self._ev_compact_rows_in.increment(len(ets_orig))
+        self._ev_compact_rows_in.increment(n_rows)
         self._ev_dropped_rules.increment(n_rules)
         self._ev_dropped_ttl.increment(int(np.count_nonzero(drop)) - n_rules)
         self._ev_ttl_rewritten.increment(
             int(np.count_nonzero(~drop & (new_ets != ets_orig))))
 
-    def _manual_compact_bulk(self, now_s: int, default_ttl: int,
+    def _manual_compact_bulk(self, snap, now_s: int, default_ttl: int,
                              pidx: int, partition_version: int,
                              do_validate: bool, operations,
                              publish_lock=None) -> None:
-        """Block-level compaction over a pure-L1 store.
+        """Block-level compaction over `snap` (lsm.bulk_compact_snapshot:
+        pure L1, L0 tables chained by key range, an overlay spliced
+        into the blocks it overlaps).
 
         Pipelined (default): the block-read, filter-eval, and
         compressed-write stages run on dedicated threads connected by
@@ -361,14 +381,18 @@ class StorageEngine:
         self._ev_path_bulk.increment()
         ttl_may_change = bool(default_ttl) or bool(
             operations and any(op.op == "update_ttl" for op in operations))
-        eval_device = choose_eval_device(workload=rules_workload(operations))
-        entries = self.lsm.bulk_compact_entries()
+        entries = self.lsm.bulk_compact_entries(snap)
+        if snap.overlay:
+            # the overlay's tables were read whole to order their rows
+            GOVERNOR.acquire(sum(
+                bm.size for t in snap.overlay for bm in t.blocks))
         # mesh FILTER pre-pass: one whole-table dispatch (or a sibling's
         # cached one) hands back every block's drop mask up front; the
         # READ stage below still pays the governor, the WRITE stage is
-        # untouched
+        # untouched. The resident image holds a store without an
+        # overlay: pure L1 alone.
         mesh_masks = None
-        if entries:
+        if entries and not snap.l0:
             from pegasus_tpu.parallel.mesh_resident import MESH_SERVING
             try:
                 mesh_masks = MESH_SERVING.try_compact_masks(
@@ -401,15 +425,31 @@ class StorageEngine:
                     and getattr(run, "codec", None) is not None)
 
         def load(entry):
-            """READ stage: one block off disk, paced by the governor
-            (this is the only place background compaction touches the
-            disk for input)."""
-            run, i, bm = entry
-            GOVERNOR.acquire(bm.size)
-            if direct(run):
-                out = (run, i, run.read_block_encoded(i), True)
+            """READ stage: one entry's blocks off disk, paced by the
+            governor (this is the only place background compaction
+            touches the disk for input): a chain block as it is, or
+            the blocks a splice merges from the chain blocks and the
+            overlay rows in their spans. A compressed block stays
+            ENCODED whatever evaluates it: rules read its key matrix
+            (EncodedBlock.keys, no heap inflate) and the write stage
+            copies or subsets its bytes as they are. -> [(source, idx,
+            blk, host-direct?, bytes read)]"""
+            if isinstance(entry, Splice):
+                GOVERNOR.acquire(entry.base_bytes)
+                self._ev_blocks_spliced.increment(len(entry.base))
+                self._ev_overlay_rows.increment(entry.hi - entry.lo)
+                # its bytes in: on the first of its blocks
+                out = [(entry, j, blk, direct(entry),
+                        0 if j else entry.base_bytes + entry.overlay_bytes)
+                       for j, blk in enumerate(entry.blocks())]
             else:
-                out = (run, i, run.read_block(i), False)
+                run, i, bm = entry
+                GOVERNOR.acquire(bm.size)
+                self._ev_blocks_chained.increment()
+                blk = (run.read_block_encoded(i)
+                       if getattr(run, "codec", None) is not None
+                       else run.read_block(i))
+                out = [(run, i, blk, direct(run), bm.size)]
             mark("compact_read")
             return out
 
@@ -417,7 +457,7 @@ class StorageEngine:
             """FILTER stage phase 1: dispatch without waiting."""
             if mesh_masks is not None:
                 served = {}
-                for run, i, _blk, _d in items:
+                for run, i, _blk, _d, _n in items:
                     m = mesh_masks.get((run, i))
                     if m is None:
                         break
@@ -427,19 +467,23 @@ class StorageEngine:
                     # in flight, eager-forward straight to WRITE
                     return items, [], served
             blocks = [((run, i), blk, pidx)
-                      for run, i, blk, is_direct in items
+                      for run, i, blk, is_direct, _n in items
                       if not is_direct]
             host_done = {}
-            for run, i, blk, is_direct in items:
+            for run, i, blk, is_direct, _n in items:
                 if is_direct:
                     host_done[(run, i)] = encoded_drop_mask(
                         blk, now_s, default_ttl, pidx,
                         partition_version, do_validate,
                         want_ets=ttl_may_change)
+            # placed only where a program is dispatched: the link probe
+            # behind it (once a process: 16 MiB each way) is not a
+            # host-direct compaction's to pay
             pend = compaction_eval_submit(
                 blocks, now_s, default_ttl, partition_version,
                 do_validate, operations=operations,
-                eval_device=eval_device,
+                eval_device=choose_eval_device(
+                    workload=rules_workload(operations)),
                 want_ets=ttl_may_change) if blocks else []
             mark("compact_filter_submit")
             return items, pend, host_done
@@ -452,7 +496,7 @@ class StorageEngine:
                     pend, want_ets=ttl_may_change):
                 got[tag] = (drop, new_ets)
             out = []
-            for run, i, blk, is_direct in items:
+            for run, i, blk, is_direct, nbytes in items:
                 # host_done holds both direct-on-encoded masks and
                 # mesh-served ones; device programs land in got
                 m = host_done.get((run, i))
@@ -460,18 +504,26 @@ class StorageEngine:
                     m = got[(run, i)]
                 drop, new_ets = m
                 self._count_filtered(
-                    run.blocks[i].size, blk.expire_ts, drop, new_ets,
+                    nbytes, blk.expire_ts, drop, new_ets,
                     now_s, not_rules=(
                         (np.asarray(blk.hash_lo)
                          & np.uint32(max(partition_version, 0)))
                         != np.uint32(pidx)
                         if do_validate and blk.hash_lo is not None
-                        else None))
+                        else None), flags=blk.flags)
                 out.append((run, i, blk, drop, new_ets))
             mark("compact_filter_drain")
             return out
 
-        if pipeline_enabled() and stage_threads_enabled():
+        # A snapshot of one window gives the stage threads nothing to
+        # overlap (the window is read, then filtered, then written),
+        # and one of a single transform chunk gives the transform
+        # workers nothing: such a compaction (a replica of a few
+        # thousand rows) runs its stages inline on the calling thread.
+        # Beside other compactions of a pool its helper threads only
+        # queue for the interpreter lock.
+        if (pipeline_enabled() and stage_threads_enabled()
+                and len(entries) > pipeline_window()):
             pipe = CompactPipeline(
                 entries, load, submit_window, drain_window,
                 window=pipeline_window(), depth=pipeline_depth(),
@@ -495,7 +547,8 @@ class StorageEngine:
                 pending = None
                 for off in range(0, len(entries), W):
                     token = submit_window(
-                        [load(e) for e in entries[off:off + W]])
+                        [item for e in entries[off:off + W]
+                         for item in load(e)])
                     if pending is not None:
                         yield from drain_window(pending)
                         pending = None
@@ -512,8 +565,10 @@ class StorageEngine:
             results, meta, ttl_may_change=ttl_may_change,
             patch_headers=self.values_carry_expire_header,
             publish_lock=publish_lock,
-            transform_workers=(transform_workers()
-                               if pipeline_enabled() else 0))
+            transform_workers=(
+                transform_workers() if pipeline_enabled()
+                and len(entries) > TRANSFORM_CHUNK_BLOCKS else 0),
+            snap=snap)
 
     def manual_compact(self, default_ttl: int = 0, pidx: int = 0,
                        partition_version: int = -1,
@@ -539,17 +594,24 @@ class StorageEngine:
         do_validate = bool(validate_hash and partition_version >= 0
                            and pidx <= partition_version)
 
-        # bulk block-level path (the GB/s shape): a pure-L1 store needs
-        # no merge, so whole columnar blocks are evaluated in a handful
-        # of stacked programs and surviving rows rewritten with numpy
-        # gathers — no per-record Python. Custom rules callables without
-        # a parsed ruleset fall back to the merge path.
+        # The block path (the GB/s shape) takes every store whose
+        # memtable is frozen (or empty) and whose files carry hash_lo:
+        # pure L1, the lone L0 of a table's first compaction, L0 tables
+        # that chain by key range, an L0 that overlaps L1 (spliced into
+        # the blocks it meets). Whole columnar blocks are evaluated in a
+        # handful of stacked programs and surviving rows rewritten with
+        # numpy gathers — no per-record Python but over the overlay's
+        # own rows. The per-record merge path below keeps what that
+        # cannot read: a live memtable under the caller's lock (legacy
+        # mode), v1 files, a rules callable without a parsed ruleset.
         operations = getattr(rules_filter, "operations", None)
-        if (self.lsm.bulk_compact_eligible()
-                and (rules_filter is None or operations is not None)):
+        snap = (self.lsm.bulk_compact_snapshot(
+            frozen=publish_lock is not None)
+            if rules_filter is None or operations is not None else None)
+        if snap is not None:
             self._compact_with_epilogue(
                 lambda: self._manual_compact_bulk(
-                    now_s, default_ttl, pidx, partition_version,
+                    snap, now_s, default_ttl, pidx, partition_version,
                     do_validate, operations, publish_lock=publish_lock),
                 advance_watermark=publish_lock is None)
             return
@@ -650,15 +712,19 @@ class StorageEngine:
                             validate_hash: bool = False,
                             rules_filter=None) -> None:
         """Compile now what a manual compaction of this store, as it
-        stands, would compile where it first dispatches it: the
-        per-record path's filter programs at the buckets of its
-        batches, the block path's fused program at the buckets of its
-        windows, the placement probe. Rows of the store's own first
-        block ride through the code a compaction runs; nothing is
-        written, and of the counters only the dispatched filter
-        programs' move. Once a process for each set of shapes: the
-        programs are jit-cached process-wide, so the first replica of a
-        table pays for its siblings."""
+        stands, would compile where it first dispatches it: on the
+        block path (the path `manual_compact` takes once the run's
+        flush has frozen the memtable) the fused program at the
+        buckets of its windows, on the per-record path the filter
+        programs at the buckets of its batches; the placement probe.
+        A window's rows are known to within the overlay's (an overlay
+        row adds a row to a spliced block, replaces one, or takes one):
+        every bucket in that reach is compiled. Rows of the store's
+        own first block ride through the code a compaction runs;
+        nothing is written, and of the counters only the dispatched
+        filter programs' move. Once a process for each set of shapes:
+        the programs are jit-cached process-wide, so the first replica
+        of a table pays for its siblings."""
         from pegasus_tpu.ops.compaction import (
             _row_bucket,
             choose_eval_device,
@@ -668,19 +734,36 @@ class StorageEngine:
         )
         from pegasus_tpu.storage.compact_pipeline import pipeline_window
 
-        runs = list(self.lsm.l1_runs)
-        counts = [bm.count for run in runs for bm in run.blocks]
-        if not counts:
+        lsm = self.lsm
+        first = next((t for t in list(lsm.l1_runs) + list(lsm.l0)
+                      if t.blocks), None)
+        if first is None:
             return
         operations = getattr(rules_filter, "operations", None)
         do_validate = bool(validate_hash and partition_version >= 0
                            and pidx <= partition_version)
-        rows, batch = sum(counts), self.lsm.filter_batch_rows
-        batches = {min(rows, batch), rows % batch or batch}
-        win = pipeline_window()
-        windows = {_row_bucket(sum(counts[off:off + win])): sum(
-            counts[off:off + win]) for off in range(0, len(counts), win)}
-        first = next(run for run in runs if run.blocks)
+        snap = (lsm.bulk_compact_snapshot(frozen=True)
+                if rules_filter is None or operations is not None else None)
+        n_mem = len(lsm.memtable)   # the run's flush makes it an L0
+        batches, windows = set(), set()
+        if snap is None:
+            rows = n_mem + sum(t.total_count
+                               for t in lsm.l0 + lsm.l1_runs)
+            batch = lsm.filter_batch_rows
+            batches = {min(rows, batch), rows % batch or batch}
+        # the block path evaluates on the device with a parsed ruleset,
+        # or with none over an uncompressed store
+        elif operations is not None or (
+                rules_filter is None and getattr(first, "codec", None) is None):
+            counts = [bm.count for t in snap.chain for bm in t.blocks]
+            k = n_mem + sum(t.total_count for t in snap.overlay)
+            win = pipeline_window()
+            for off in range(0, max(len(counts), 1), win):
+                n = sum(counts[off:off + win])
+                bucket = _row_bucket(max(n - k, 1))
+                while bucket <= _row_bucket(n + k):
+                    windows.add(bucket)
+                    bucket <<= 1
         shapes = (rules_filter, first.blocks[0].key_width,
                   frozenset(_row_bucket(n) for n in batches),
                   frozenset(windows), do_validate, bool(default_ttl),
@@ -689,23 +772,24 @@ class StorageEngine:
             return
         blk = first.read_block(0)
         now_s = epoch_now()
-        record_filter = self._merge_record_filter(
-            now_s, default_ttl, pidx, partition_version, do_validate,
-            rules_filter, count=False)
-        for n in batches:
-            for lazy in record_filter([blk.key_at(0)] * n, [0] * n):
-                np.asarray(lazy)
-        # the block path is taken, and evaluates on the device, with a
-        # parsed ruleset, or with none over an uncompressed store
-        if operations is not None or (rules_filter is None
-                                      and not shapes[-1]):
+        if batches:
+            record_filter = self._merge_record_filter(
+                now_s, default_ttl, pidx, partition_version, do_validate,
+                rules_filter, count=False)
+            for n in batches:
+                for lazy in record_filter([blk.key_at(0)] * n, [0] * n):
+                    np.asarray(lazy)
+        if windows:
             ttl_may_change = bool(default_ttl) or any(
                 op.op == "update_ttl" for op in operations or ())
             eval_device = choose_eval_device(
                 workload=rules_workload(operations))
-            for n in windows.values():
+            for bucket in windows:
+                # as many of the block as the bucket holds: its rows
+                # are over half of it (a block is 1,024 of >= 4,096)
                 list(compaction_eval_drain(compaction_eval_submit(
-                    [(i, blk, pidx) for i in range(-(-n // blk.count))],
+                    [(i, blk, pidx)
+                     for i in range(max(1, bucket // blk.count))],
                     now_s, default_ttl, partition_version, do_validate,
                     operations=operations, eval_device=eval_device,
                     want_ets=ttl_may_change), want_ets=ttl_may_change))

@@ -10,7 +10,11 @@ compaction processes one output range at a time (merge memtable + L0
 sub-range + that L1 run) and caps each output run, so a big table is
 never rewritten as one monolithic file and each step's memory/latency
 stays bounded (the leveled-compaction property manual CompactRange
-relies on). The filter seam drops tombstones, expired records
+relies on). Two paths produce that output: the block path (whole
+columnar blocks; L0 tables that chain by key range flow through it
+unchanged, one that overlaps is spliced into the blocks it meets) and
+the per-record merge (`compact`), kept for what the first cannot read.
+The filter seam drops tombstones, expired records
 (device-evaluated TTL predicate), stale post-split keys, and applies
 user-specified rules — the bottommost-level semantics of
 src/server/key_ttl_compaction_filter.h:55,91.
@@ -27,10 +31,11 @@ Scan merge order: memtable > newest L0 > ... > oldest L0 > L1 runs.
 
 from __future__ import annotations
 
+import bisect
 import heapq
 import itertools
 import os
-from typing import Callable, Iterator, List, Optional, Tuple
+from typing import Callable, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -38,12 +43,14 @@ from pegasus_tpu.base.crc import crc64
 from pegasus_tpu.storage.block_codec import (
     CODEC_NONE,
     EncodedBlock,
+    ragged_scatter,
     codec_accepts,
 )
 from pegasus_tpu.storage.bloom import bloom_probe_enabled
 from pegasus_tpu.storage.memtable import Memtable, TOMBSTONE
 from pegasus_tpu.storage.sstable import (
     BLOCK_CAPACITY,
+    Block,
     SSTable,
     SSTableWriter,
 )
@@ -56,6 +63,22 @@ Record = Tuple[bytes, Optional[bytes], int]
 # records per L1 output run before the compactor starts a new one:
 # bounds every future range-compaction step (and its device batches)
 L1_RUN_CAPACITY = 262_144
+
+# blocks an L0 table needs before the block path lets its blocks flow
+# unchanged (a chained table leaves at most one undersized block at its
+# seam); a smaller table is spliced row-wise, so a train of small
+# flushes packs into full blocks
+CHAIN_MIN_BLOCKS = 4
+
+# consecutive blocks one splice may decode and merge at a time (bounds
+# what a load of the block path's read stage holds)
+SPLICE_GROUP_BLOCKS = 8
+
+# blocks a future of the block path's transform pool holds (a future
+# round trip costs a condition-variable wait, which at one per block
+# ate the whole overlap win): a rewrite of no more blocks than this
+# has nothing to run in parallel
+TRANSFORM_CHUNK_BLOCKS = 16
 
 # process-unique store ids: cache owners (the node row cache) key
 # entries by store identity + generation, and an int token can never
@@ -74,6 +97,11 @@ def survivor_mask(drop: np.ndarray, flags) -> np.ndarray:
     if flags is not None:
         keep &= np.asarray(flags) == 0  # tombstones never stay
     return keep
+
+
+def _seq_of(name: str) -> int:
+    """The sequence number in an `l0-<seq>.sst` / `l1-<seq>.sst` name."""
+    return int(os.path.basename(name).split("-")[1].split(".")[0])
 
 
 class LSMStore:
@@ -114,16 +142,22 @@ class LSMStore:
     def _manifest_path(self) -> str:
         return os.path.join(self.data_dir, "MANIFEST.json")
 
-    def _write_manifest(self, l1_names: List[str]) -> None:
+    def _write_manifest(self, l1_names: List[str],
+                        live_l0: Sequence[SSTable] = ()) -> None:
         """Atomically record the live L1 run set + the seq horizon. Any
         l1-* file not listed, and any l0-* file older than the horizon,
-        is a crash leftover boot removes."""
+        is a crash leftover boot removes. `live_l0`: the L0 tables the
+        publish leaves (flushed while a snapshot-mode compaction ran,
+        so numbered below its later outputs): the horizon stays at the
+        oldest of them."""
         import json as _json
         import tempfile as _tempfile
 
+        horizon = min([self._file_seq] + [_seq_of(t.path)
+                                           for t in live_l0])
         fd, tmp = _tempfile.mkstemp(dir=self.data_dir)
         with os.fdopen(fd, "w") as f:
-            _json.dump({"seq": self._file_seq, "l1": l1_names,
+            _json.dump({"seq": horizon, "l1": l1_names,
                         "mcft": self.compact_finish_time}, f)
             f.flush()
             os.fsync(f.fileno())
@@ -145,7 +179,7 @@ class LSMStore:
         l1_files = []
         for name in os.listdir(self.data_dir):
             if name.endswith(".sst"):
-                seq = int(name.split("-")[1].split(".")[0])
+                seq = _seq_of(name)
                 self._file_seq = max(self._file_seq, seq + 1)
                 if name.startswith("l0-"):
                     l0_files.append((seq, name))
@@ -360,8 +394,17 @@ class LSMStore:
         patch_headers: bool = False,
         publish_lock=None,
     ) -> int:
-        """Full compaction as a sequence of BOUNDED range steps. Returns
-        the key + value bytes of the rows it merged (its input).
+        """Full compaction as a sequence of BOUNDED range steps, record
+        by record. Returns the key + value bytes of the rows it merged
+        (its input).
+
+        Which stores take it: since the block path reads a snapshot
+        with L0 tables (bulk_compact_snapshot), only what that path
+        cannot read — a live memtable merged under the caller's lock
+        (legacy mode), v1 files without the hash_lo column, and a
+        rules callable that has no parsed `operations` (the engine's
+        choice). It stays the reference the block path is held to
+        (tests/test_block_compact_overlay.py).
 
         One merged pass over the overlay + L1 runs; output runs are
         size-capped (`l1_run_capacity`), so no monolithic rewrite and a
@@ -557,19 +600,20 @@ class LSMStore:
                     "discarded")
             if mcft:
                 self.compact_finish_time = mcft
+            live_l0: List[SSTable] = []
+            if consumed_l0 is not None:
+                consumed = {id(t) for t in consumed_l0}
+                live_l0 = [t for t in self.l0 if id(t) not in consumed]
             self._write_manifest([os.path.basename(t.path)
-                                  for t in new_runs])
+                                  for t in new_runs], live_l0)
             superseded = self.l1_runs
             self.l1_runs = new_runs
             self.generation += 1
             if consumed_l0 is None:
                 old_l0, self.l0 = self.l0, []
                 self.memtable = Memtable()
-            elif consumed_l0:
-                consumed = {id(t) for t in consumed_l0}
-                self.l0 = [t for t in self.l0
-                           if id(t) not in consumed]
-                old_l0 = list(consumed_l0)
+            else:
+                old_l0, self.l0 = list(consumed_l0), live_l0
             # Input files are unlinked now (crash-safe: the manifest no
             # longer names them) but their HANDLES are released by GC,
             # not closed here: a reader admitted before the swap may
@@ -591,15 +635,56 @@ class LSMStore:
 
     # ---- bulk block-level compaction (the GB/s path) -------------------
 
-    def bulk_compact_eligible(self) -> bool:
-        """The store is pure non-overlapping L1 (manual-compact steady
-        state): no merge is needed, so compaction can rewrite block-wise
-        with vectorized gathers instead of streaming per-record Python.
-        v1 files (no hash_lo column) fall back to the merge path."""
-        return (len(self.memtable) == 0 and not self.l0
-                and bool(self.l1_runs)
-                and all(getattr(r, "_has_hash_lo", False)
-                        for r in self.l1_runs))
+    def bulk_compact_snapshot(self, frozen: bool = False
+                              ) -> Optional["BulkSnapshot"]:
+        """What a block-path compaction would read and, at publish,
+        replace — or None where only `compact` (the per-record merge)
+        can read the store: a live memtable (`frozen` False and rows in
+        it: the legacy lock-held mode merges it), a v1 file without the
+        hash_lo column, nothing on disk. `frozen`: the caller froze the
+        memtable with a flush (snapshot mode), so what decides is the
+        file snapshot alone — a write that lands after the freeze stays
+        in the overlay the publish leaves behind.
+
+        The snapshot's tables are split by their own key ranges. The
+        CHAIN: the L1 runs, and every L0 table of CHAIN_MIN_BLOCKS
+        blocks or more whose [first_key, last_key] is disjoint from
+        every older table's — concatenated in key order, their blocks
+        flow through the path unchanged. The OVERLAY: every other L0
+        table (it overlaps an older one, or is small), whose rows are
+        spliced into the chain's blocks. An overlay table is therefore
+        newer than every chain table its range meets, so an overlay
+        row wins against a chain row of the same key."""
+        if not frozen and len(self.memtable):
+            return None
+        l0, runs = list(self.l0), list(self.l1_runs)
+        tables = [t for t in l0 + runs if t.blocks]
+        if not tables or not all(getattr(t, "_has_hash_lo", False)
+                                 for t in tables):
+            return None
+        chain = [t for t in runs if t.blocks]
+        overlay: List[SSTable] = []
+        for t in reversed(l0):  # oldest first
+            if not t.blocks:
+                continue
+            if len(t.blocks) >= CHAIN_MIN_BLOCKS and all(
+                    t.last_key < o.first_key or o.last_key < t.first_key
+                    for o in chain + overlay):
+                chain.append(t)
+            else:
+                overlay.append(t)
+        chain.sort(key=lambda t: t.first_key)
+        overlay.reverse()  # newest first: the merge order
+        return BulkSnapshot(l0, runs, chain, overlay)
+
+    def bulk_compact_eligible(self, frozen: bool = False) -> bool:
+        """The block path can compact the store as it stands: see
+        bulk_compact_snapshot. True for pure L1 (the manual-compact
+        steady state), for an L0 left by a flush (a table's first
+        compaction, a live table's every one) whether its tables chain
+        by key range or overlap, and for both together; False over a
+        live memtable and over v1 files."""
+        return self.bulk_compact_snapshot(frozen) is not None
 
     @property
     def filter_batch_rows(self) -> int:
@@ -609,32 +694,76 @@ class LSMStore:
         filter evaluation."""
         return self._block_capacity * 16
 
-    def bulk_compact_entries(self):
-        """Every L1 block in global key order: [(run, idx, BlockMeta)]."""
-        out = []
-        for run in self.l1_runs:
-            for i, bm in enumerate(run.blocks):
-                out.append((run, i, bm))
+    def bulk_compact_entries(self, snap: Optional["BulkSnapshot"] = None):
+        """The snapshot (default: the L1 runs alone) as ONE key-ordered
+        sequence of entries: `(table, idx, BlockMeta)` for a block that
+        flows unchanged, a `Splice` where overlay rows fall into a
+        chain block's span `[first_key, next block's first_key)` (the
+        first span open below, the last above): that block — with an
+        undersized neighbour, and the next blocks that take overlay
+        rows too, up to SPLICE_GROUP_BLOCKS — is decoded and merged
+        with those rows."""
+        chain = self.l1_runs if snap is None else snap.chain
+        blocks = [(t, i, bm) for t in chain
+                  for i, bm in enumerate(t.blocks)]
+        if snap is None or not snap.overlay:
+            return blocks
+        ov = _Overlay(snap.overlay)
+        cap = self._block_capacity
+        m = len(blocks)
+        # overlay rows [cut[j], cut[j + 1]) fall into block j's span
+        cut = [0] + [bisect.bisect_left(ov.keys, b[2].first_key)
+                     for b in blocks[1:]] + [len(ov.keys)]
+        if m == 0:
+            return [Splice([], ov, 0, len(ov.keys), cap)]
+        out: list = []
+        j = 0
+        while j < m:
+            if cut[j] == cut[j + 1]:
+                out.append(blocks[j])
+                j += 1
+                continue
+            g0 = j
+            if out and not isinstance(out[-1], Splice) \
+                    and out[-1][2].count * 2 < cap:
+                out.pop()   # an undersized block before: packed too
+                g0 = j - 1
+            g1 = j + 1
+            while g1 < m and g1 - g0 < SPLICE_GROUP_BLOCKS and (
+                    cut[g1] < cut[g1 + 1]
+                    or blocks[g1][2].count * 2 < cap):
+                g1 += 1
+            out.append(Splice(blocks[g0:g1], ov, cut[g0], cut[g1], cap))
+            j = g1
         return out
 
     def bulk_compact_rewrite(self, per_block, meta,
                              ttl_may_change: bool,
                              patch_headers: bool = False,
                              publish_lock=None,
-                             transform_workers: int = 0) -> None:
+                             transform_workers: int = 0,
+                             snap: Optional["BulkSnapshot"] = None) -> None:
         """Rewrite the L1 level from precomputed per-block filter results.
 
-        `per_block`: [(run, idx, blk, drop, new_ets)] in key order (drop
-        / new_ets sized to the block's real count). Untouched blocks are
+        `per_block`: [(source, idx, blk, drop, new_ets)] in key order
+        (drop / new_ets sized to the block's real count): the blocks of
+        `snap`'s entries — a chain table's own block as read, or a
+        block a Splice merged from a chain block and the overlay rows
+        in its span (decoded columns) — whatever shape the snapshot
+        has: pure L1, one L0 or several chained by key range, an
+        overlay over L1. Untouched blocks are
         re-serialized straight from their already-decoded columns (no
         gather, no crc recompute, no second disk read); touched blocks
         are rebuilt with numpy gathers — the value heap survivor bytes
         via one boolean-repeat mask, expire_ts headers patched with
         scatter stores — so no per-record Python runs at any drop
-        rate. The rewrite never touches the memtable/L0 (eligibility
-        requires them empty at snapshot), so with `publish_lock` the
-        whole disk pass runs with writes flowing and the lock is taken
-        only for the publish cut-over.
+        rate. A chained L0 block's tombstones drop with the filter's
+        rows (survivor_mask). The rewrite never touches the memtable,
+        and of the L0 only the snapshot's tables, so with
+        `publish_lock` the whole disk pass runs with writes flowing and
+        the lock is taken only for the publish cut-over, which replaces
+        exactly `snap.l0` and `snap.runs` (default: no L0 table and the
+        live L1 runs).
 
         `transform_workers` > 0 (the pipelined compactor's write
         stage): the per-block transform — subset kernel, heap
@@ -652,7 +781,8 @@ class LSMStore:
             block_codec,
         )
 
-        runs_snap = list(self.l1_runs)
+        runs_snap = list(self.l1_runs) if snap is None else snap.runs
+        l0_snap = [] if snap is None else snap.l0
         # filled runs finish on the shared _FinishPool (fsync releases
         # the GIL) while this thread keeps appending; joined before
         # the manifest publish
@@ -701,7 +831,8 @@ class LSMStore:
             inflate, numpy gathers — and runs identically inline
             (serial) or on the ordered worker pool (pipelined)."""
             _run, _idx, blk, drop, new_ets = item
-            dropped = bool(drop.any())
+            # a chained L0 block may hold tombstones: they never stay
+            dropped = bool(drop.any()) or bool(np.any(blk.flags))
             encoded = isinstance(blk, EncodedBlock)
             ets_changed = ttl_may_change and \
                 not np.array_equal(new_ets, blk.expire_ts)
@@ -816,14 +947,12 @@ class LSMStore:
         try:
             if transform_workers > 0:
                 # ordered lookahead: transforms run CHUNKED on the
-                # pool (one future per ~16 blocks — a future round
-                # trip costs a condition-variable wait, which at one
-                # per block ate the whole overlap win) while results
-                # append in order — the write stage's own intra-stage
-                # parallelism
+                # pool (one future per TRANSFORM_CHUNK_BLOCKS) while
+                # results append in order — the write stage's own
+                # intra-stage parallelism
                 from collections import deque
 
-                CHUNK = 16
+                CHUNK = TRANSFORM_CHUNK_BLOCKS
                 depth = 2 * transform_workers + 2
 
                 def transform_chunk(chunk):
@@ -862,14 +991,179 @@ class LSMStore:
         finally:
             finish_pool.shutdown(ok, open_writer=writer)
         mark("compact_write")
-        # memtable/L0 are untouched by construction
-        # (bulk_compact_eligible requires them empty at snapshot time;
-        # writes that arrived since stay in the live overlay)
-        self._publish_l1(new_runs, consumed_l0=[], old_runs=runs_snap,
+        # exactly the snapshot's tables leave; writes that arrived
+        # since stay in the live overlay (memtable, newer L0 flushes)
+        self._publish_l1(new_runs, consumed_l0=l0_snap, old_runs=runs_snap,
                          publish_lock=publish_lock,
                          mcft=(meta or {}).get(
                              "manual_compact_finish_time", 0))
         mark("compact_publish")
+
+
+class BulkSnapshot:
+    """The tables one block-path compaction reads (LSMStore.
+    bulk_compact_snapshot): `l0` (newest first) and `runs` are what
+    its publish replaces; `chain` (key order) and `overlay` (newest
+    first) are the same tables by how they are read."""
+
+    __slots__ = ("l0", "runs", "chain", "overlay")
+
+    def __init__(self, l0: List[SSTable], runs: List[SSTable],
+                 chain: List[SSTable], overlay: List[SSTable]) -> None:
+        self.l0, self.runs = l0, runs
+        self.chain, self.overlay = chain, overlay
+
+
+class _Overlay:
+    """The overlay tables' rows as one key-ordered sequence, the newest
+    table's row for a key that several hold, tombstones kept (they
+    shadow a chain row, then drop). A row is (block, row in it): the
+    columns stay in the decoded source blocks, which a Splice gathers
+    from; `keys` is the one per-row Python list, for the bisects."""
+
+    def __init__(self, tables: List[SSTable]) -> None:  # newest first
+        self.blocks: List[Block] = []
+        keys: List[bytes] = []
+        blk_id, row, tomb, row_bytes = [], [], [], []
+        for t in tables:
+            for i in range(len(t.blocks)):
+                blk = t.read_block(i)
+                keys.extend(blk.key_list())
+                blk_id.append(np.full(blk.count, len(self.blocks),
+                                      dtype=np.int64))
+                row.append(np.arange(blk.count, dtype=np.int64))
+                tomb.append(np.asarray(blk.flags) != 0)
+                row_bytes.append(blk.key_len.astype(np.int64) + np.diff(
+                    blk.value_offs.astype(np.int64)))
+                self.blocks.append(blk)
+        blk_id, row = np.concatenate(blk_id), np.concatenate(row)
+        tomb, row_bytes = np.concatenate(tomb), np.concatenate(row_bytes)
+        if len(tables) > 1:
+            # stable, and the newest table's rows come first: of equal
+            # keys the first is the winner
+            order = sorted(range(len(keys)), key=keys.__getitem__)
+            order = [o for n, o in enumerate(order)
+                     if n == 0 or keys[o] != keys[order[n - 1]]]
+            keys = [keys[o] for o in order]
+            blk_id, row = blk_id[order], row[order]
+            tomb, row_bytes = tomb[order], row_bytes[order]
+        self.keys = keys
+        self.blk_id, self.row = blk_id, row
+        self.tomb, self.row_bytes = tomb, row_bytes
+        self.codec = getattr(tables[0], "codec", None)
+
+
+class Splice:
+    """One entry of the block path's sequence that is merged, not
+    copied: consecutive chain blocks `base` ([(table, idx, BlockMeta)],
+    none where the snapshot has no chain) and the overlay rows
+    [lo, hi) that fall into their spans."""
+
+    __slots__ = ("base", "overlay", "lo", "hi", "capacity")
+
+    def __init__(self, base, overlay: _Overlay, lo: int, hi: int,
+                 capacity: int) -> None:
+        self.base, self.overlay = base, overlay
+        self.lo, self.hi, self.capacity = lo, hi, capacity
+
+    @property
+    def codec(self):
+        """The codec of the tables it reads: where the engine's filter
+        stage evaluates such a table's blocks, it evaluates these."""
+        return (self.base[0][0].codec if self.base
+                else self.overlay.codec)
+
+    @property
+    def base_bytes(self) -> int:
+        """On-disk bytes of the chain blocks it reads."""
+        return sum(bm.size for _t, _i, bm in self.base)
+
+    @property
+    def overlay_bytes(self) -> int:
+        """Key and value bytes of its overlay rows."""
+        return int(self.overlay.row_bytes[self.lo:self.hi].sum())
+
+    def blocks(self) -> List[Block]:
+        """The merged rows as columnar blocks of at most `capacity`
+        rows, every one full but the last: `_merge`'s rules (the
+        overlay's row wins, a tombstone shadows then drops), every
+        column gathered from the source blocks' own (hash_lo included:
+        no crc64 is computed again)."""
+        ov, lo, hi = self.overlay, self.lo, self.hi
+        sources = [t.read_block(i) for t, i, _bm in self.base]
+        n_base = len(sources)
+        sources += ov.blocks
+        base_keys: List[bytes] = []
+        for blk in sources[:n_base]:
+            base_keys.extend(blk.key_list())
+        n = len(base_keys)
+        # where an overlay row goes: before chain row pos[j], which it
+        # replaces when the keys are equal
+        pos = np.empty(hi - lo, dtype=np.int64)
+        shadowed = np.zeros(n, dtype=bool)
+        for j, key in enumerate(ov.keys[lo:hi]):
+            p = pos[j] = bisect.bisect_left(base_keys, key)
+            if p < n and base_keys[p] == key:
+                shadowed[p] = True
+        ov_src = ov.blk_id[lo:hi] + n_base
+        ov_row = ov.row[lo:hi]
+        ov_live = np.flatnonzero(~ov.tomb[lo:hi])
+        if n:
+            base_src = np.concatenate([
+                np.full(b.count, s, dtype=np.int64)
+                for s, b in enumerate(sources[:n_base])])
+            base_row = np.concatenate([
+                np.arange(b.count, dtype=np.int64)
+                for b in sources[:n_base]])
+            base_live = np.flatnonzero(~shadowed & (np.concatenate(
+                [b.flags for b in sources[:n_base]]) == 0))
+        else:
+            base_src = base_row = base_live = np.zeros(0, dtype=np.int64)
+        # chain row i sorts at 2i + 1, an overlay row at 2 pos: before
+        # the chain row it precedes, after the overlay rows before it
+        order = np.argsort(np.concatenate(
+            [2 * base_live + 1, 2 * pos[ov_live]]), kind="stable")
+        src = np.concatenate([base_src[base_live], ov_src[ov_live]])[order]
+        row = np.concatenate([base_row[base_live], ov_row[ov_live]])[order]
+        return [_gather_block(sources, src[off:off + self.capacity],
+                              row[off:off + self.capacity])
+                for off in range(0, len(src), self.capacity)]
+
+
+def _gather_block(sources: List[Block], src: np.ndarray,
+                  row: np.ndarray) -> Block:
+    """A columnar block of rows (sources[src[i]], row[i]), in that
+    order: one vectorized gather a source and column, the value heap
+    by ragged byte ranges."""
+    n = len(src)
+    used = [(s, np.flatnonzero(src == s)) for s in np.unique(src)]
+    width = max(sources[s].keys.shape[1] for s, _at in used)
+    keys = np.zeros((n, width), dtype=np.uint8)
+    key_len = np.empty(n, dtype=np.int32)
+    ets = np.empty(n, dtype=np.uint32)
+    hash_lo = np.empty(n, dtype=np.uint32)
+    lens = np.empty(n, dtype=np.int64)
+    starts = np.empty(n, dtype=np.int64)
+    for s, at in used:
+        blk, r = sources[s], row[at]
+        keys[at, :blk.keys.shape[1]] = blk.keys[r]
+        key_len[at] = blk.key_len[r]
+        ets[at] = blk.expire_ts[r]
+        hash_lo[at] = blk.hash_lo[r]
+        vo = blk.value_offs.astype(np.int64)
+        starts[at] = vo[r]
+        lens[at] = vo[r + 1] - vo[r]
+    offs = np.zeros(n + 1, dtype=np.uint32)
+    offs[1:] = np.cumsum(lens)
+    heap = np.empty(int(offs[-1]), dtype=np.uint8)
+    for s, at in used:
+        src_heap = sources[s].value_heap
+        if not isinstance(src_heap, np.ndarray):
+            src_heap = np.frombuffer(src_heap, dtype=np.uint8)
+        ragged_scatter(heap, offs[:-1][at].astype(np.int64), src_heap,
+                        starts[at], lens[at])
+    return Block(keys, key_len, ets, hash_lo,
+                 np.zeros(n, dtype=np.uint8), offs, heap)
 
 
 class _FinishPool:

@@ -38,13 +38,24 @@ def _fill(eng, n, ets_of=lambda i: 0, prefix=b"hk", start_decree=1):
     return d
 
 
+def _merge_compact(eng, **kwargs):
+    """manual_compact held to the per-record merge path (which a store
+    the block path can read no longer takes by itself)."""
+    eng.lsm.bulk_compact_snapshot = lambda frozen=False: None
+    try:
+        eng.manual_compact(**kwargs)
+    finally:
+        del eng.lsm.bulk_compact_snapshot
+
+
 def test_bulk_path_engages_and_matches_merge(tmp_path):
     """Second compact (pure L1) must produce the same visible records
     the merge compact produced."""
     eng = StorageEngine(str(tmp_path / "e"))
     now = epoch_now()
     _fill(eng, 3000, ets_of=lambda i: (now - 10 if i % 10 == 0 else 0))
-    eng.manual_compact()            # merge path (L0 exists)
+    assert eng.lsm.bulk_compact_eligible()      # a lone L0: eligible too
+    _merge_compact(eng)             # the per-record path over the L0
     assert eng.lsm.bulk_compact_eligible()
     first = [(k, v, e) for k, v, e in eng.iterate()]
     assert len(first) == 2700       # 10% expired dropped
@@ -111,7 +122,7 @@ def test_default_ttl_patches_headers_only_for_server_tables(tmp_path):
                          values_carry_expire_header=True)
     eng2.write_batch([WriteBatchItem(
         OP_PUT, key, generate_value(1, b"payload", 0), 0)], decree=1)
-    eng2.manual_compact(default_ttl=100, now=now)   # merge path
+    _merge_compact(eng2, default_ttl=100, now=now)
     v, ets = eng2.get(key)
     assert ets == now + 100 and extract_expire_ts(1, v) == now + 100
     eng2.manual_compact(default_ttl=0, now=now)     # bulk no-op keeps it
@@ -127,7 +138,7 @@ def test_bulk_ttl_header_patch_and_reopen(tmp_path):
     path = str(tmp_path / "e")
     eng = StorageEngine(path, values_carry_expire_header=True)
     _fill(eng, 1500)
-    eng.manual_compact()                        # merge -> pure L1
+    eng.manual_compact()                        # L0 -> pure L1
     eng.manual_compact(default_ttl=500, now=now)  # BULK ttl rewrite
     key = generate_key(b"hk000007", b"s")
     v, ets = eng.get(key)

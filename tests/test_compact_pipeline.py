@@ -93,8 +93,8 @@ def test_pipelined_identical_to_serial_mixed_codecs(tmp_path,
                                                     monkeypatch):
     """The tentpole gate: the pipelined stages must produce the exact
     bytes the serial path produces, over a store mixing legacy raw,
-    dcz, and dcz2 runs — through BOTH compaction shapes (merge over
-    L0s, then bulk over pure L1)."""
+    dcz, and dcz2 runs — through both shapes of the block path (twelve
+    L0 tables of three codecs chained by key range, then pure L1)."""
     import pegasus_tpu.storage.engine as engine_mod
 
     # the compaction meta stamps manual_compact_finish_time =
@@ -113,9 +113,11 @@ def test_pipelined_identical_to_serial_mixed_codecs(tmp_path,
         shutil.copytree(src, d)
         FLAGS.set("pegasus.storage", "compact_pipeline", mode)
         eng = StorageEngine(d, block_capacity=64)
-        eng.manual_compact()          # merge path: L0s -> L1
+        snap = eng.lsm.bulk_compact_snapshot()
+        assert len(snap.chain) == 12 and not snap.overlay
+        eng.manual_compact()          # chained L0s -> L1
         assert eng.lsm.bulk_compact_eligible()
-        eng.manual_compact()          # bulk path over pure L1
+        eng.manual_compact()          # pure L1
         digs[mode] = _digest(eng)
         eng.close()
     assert digs[True] == digs[False]

@@ -335,7 +335,7 @@ def _build_engine(data_dir, codec, expired_every=4):
             d += 1
             eng.write_batch(items, d)
         eng.flush()
-        eng.manual_compact()        # merge path -> compressed L1
+        eng.manual_compact()        # the L0 -> compressed L1
         assert eng.lsm.bulk_compact_eligible()
         eng.manual_compact()        # bulk path (encoded drop masks)
     finally:

@@ -147,7 +147,7 @@ def test_bloom_built_for_flush_compact_and_bulk_outputs(server):
     server.flush()
     lsm = server.engine.lsm
     assert all(t.bloom is not None for t in lsm.l0)
-    server.manual_compact()  # merge path (overlay present at snapshot)
+    server.manual_compact()  # the flush's L0, block-wise, into L1
     assert lsm.l1_runs and all(r.bloom is not None for r in lsm.l1_runs)
     assert lsm.bulk_compact_eligible()
     server.manual_compact()  # bulk block-level rewrite path

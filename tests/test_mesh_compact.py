@@ -242,7 +242,7 @@ def test_publish_refresh_reuses_survivor_masks(tmp_path, mesh_guard,
         assert c.set(b"hk000", b"snew", b"fresh") == 0
         for s in t.partitions.values():
             s.engine.flush()
-            s.engine.manual_compact()  # merge path, no mesh masks
+            s.engine.manual_compact()  # an L0 in the snapshot: no mesh masks
         assert MESH_SERVING.ensure_current()
         st2 = MESH_SERVING.status()
         assert st2["refresh_rebuilds"] >= 1

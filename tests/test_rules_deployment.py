@@ -305,10 +305,11 @@ def test_an_accepted_trigger_compiles_before_the_run_starts(tmp_path,
                                                            monkeypatch):
     """What the run and the reads beside it dispatch is compiled on the
     thread that delivers the trigger, before the pool gets the run:
-    the per-record path's programs, the block path's, the read path's
-    static mask over one block and over a stack. Nothing compiles
-    after that, on either compaction path or in a torn batch's
-    fallback; a sibling with the same shapes warms nothing again."""
+    the block path's program at the bucket of its window, the read
+    path's static mask over one block and over a stack. Nothing
+    compiles after that, over a store with an overlay (spliced into
+    its blocks) or over pure L1, or in a torn batch's fallback; a
+    sibling with the same shapes warms nothing again."""
     import jax.monitoring as mon
 
     from pegasus_tpu.server import partition_server as ps
@@ -341,8 +342,9 @@ def test_an_accepted_trigger_compiles_before_the_run_starts(tmp_path,
     servers = [PartitionServer(str(tmp_path / f"p{i}")) for i in range(2)]
     try:
         for s in servers:
-            # two blocks in L1 and an overlay: the first run takes the
-            # per-record path, the next the block path
+            # two blocks in L1 and an overlay: the first run splices the
+            # overlay's row into them, the next is pure L1; both on the
+            # block path
             s.engine.write_batch(
                 [WriteBatchItem(OP_PUT, generate_key(b"user%07d" % i, b"f9"),
                                 b"v", 0) for i in range(1500)],
@@ -372,7 +374,7 @@ def test_an_accepted_trigger_compiles_before_the_run_starts(tmp_path,
                                     validate, servers[0].partition_version,
                                     filter_key=filter_key))
         envs["manual_compact.once.trigger_time"] = str(int(time.time()) + 10)
-        servers[0].update_app_envs(envs)     # pure L1 now: the block path
+        servers[0].update_app_envs(envs)     # pure L1 now
         servers[1].update_app_envs(envs)     # the sibling, the same shapes
         assert pool.wait_idle(60)
         # a torn batch is served request by request: over a store with
@@ -386,7 +388,12 @@ def test_an_accepted_trigger_compiles_before_the_run_starts(tmp_path,
                 one_page=True, validate_partition_hash=True))
             assert len(resp.kvs) == min(n, 1000)    # the iteration bound
         m = _engine_counters(servers[0])
-        assert m["compact_path_merge"] == 2 and m["compact_path_bulk"] == 1
+        # the load's, the overlay's, pure L1's: none per record
+        assert m["compact_path_merge"] == 0 and m["compact_path_bulk"] == 3
+        # the overlay's row sorts first (a shorter hashkey): merged into
+        # the first block, the undersized second packed with it
+        assert m["compact_blocks_spliced"] == 2
+        assert m["compact_overlay_rows"] == 1500 + 1
         assert m["compact_rows_dropped_rules"] == 0
         assert compiled[0] == at_submit[0] == at_submit[1] == at_submit[2]
     finally:
