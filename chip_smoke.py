@@ -15,8 +15,8 @@ starts:
              the chip, a table loaded and read over TCP, the node's
              `placement` verdict fetched at the end. The child itself
              (launcher + client) stays on the CPU backend.
-  stage A  - the path bench.py measures: an in-process 3-node SimCluster,
-             BASELINE.json config #2 at bench.py's size and record shape
+  stage A  - the path the benchmark's cells measure (BENCHMARK.json): an
+             in-process 3-node SimCluster, BASELINE.json config #2
              (1,000,000 records, 64 partitions, 3 replicas), served
              through ClusterClient; compactions with the filter stage on
              the device; the resident mesh image; the device kernels one
@@ -60,7 +60,7 @@ SIZE_A = dict(n_records=1_000_000, n_partitions=64, n_nodes=3,
               n_scans=2048, n_gets=2048, n_sets=3000)
 SIZE_B = dict(n_records=100_000, n_partitions=8, n_scans=200, n_gets=1000)
 
-# BASELINE.json config #4 as bench.py states it: a hashkey-prefix delete
+# BASELINE.json config #4's shape: a hashkey-prefix delete
 # plus a hashkey-pattern + sortkey-prefix delete
 RULES_BASELINE = [
     {"op": "delete_key",
@@ -191,7 +191,7 @@ class Model:
 
 
 def make_records(n_records: int, seed: int, now: int):
-    """bench.py's table (build_cluster): n/10 hashkeys x 10 sortkeys,
+    """The smoke's table: n/10 hashkeys x 10 sortkeys,
     `field0=%064d` values, 10% already expired. Yields
     (hk, sk, value, expire_ts)."""
     import numpy as np
@@ -475,7 +475,7 @@ def check_kernels(pallas_interpret: bool, seed: int) -> dict:
     return times
 
 
-# -- stage A: in-process cluster, the path bench.py measures ------------------
+# -- stage A: in-process cluster, the path the benchmark measures -------------
 
 def _scan_all(client, model, requests, now, digest, batch: int = 32):
     """Send `requests` [(pidx, start_key, n, sk_prefix)] through
@@ -585,7 +585,7 @@ def _drift_samples(drift_status: dict) -> int:
 
 
 def _requests(rng, model, n_scans: int, n_hashkeys: int):
-    """bench.py's scan stream (zipfian-ish partition and start key, up to
+    """A YCSB-E-shaped scan stream (zipfian-ish partition and start key, up to
     100 records), half of it with a sortkey prefix; two prefixes of one
     width so a batch also takes the multi-flavor program."""
     from pegasus_tpu.base.key_schema import generate_key

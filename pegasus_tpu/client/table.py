@@ -33,8 +33,8 @@ def compact_partitions_parallel(servers, parallel: Optional[int] = None,
     round-3 serial heuristic was the single largest bench regression).
 
     The pool is for stores of more than one pipeline window of blocks
-    (`compact_pipeline_window`): their native kernels, device waits
-    and fsyncs are long enough to overlap. A smaller store's compaction
+    (`compact_pipeline.PIPELINE_WINDOW`): their native kernels, device
+    waits and fsyncs are long enough to overlap. A smaller store's compaction
     is a few hundred short calls under the interpreter lock, and beside
     other threads each of them waits for that lock (my chip run, PR 28:
     192 replicas of 8 blocks took 11.1 s one after another, 15.3 s on 3

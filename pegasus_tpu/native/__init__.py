@@ -4,8 +4,8 @@ The reference's host hot loops are C++ (rDSN runtime + server codecs);
 ours live here. The library builds on first use with the toolchain in
 the image (g++). The pure-Python paths stay for the tests that compare
 against them; a failed build prints the compiler's stderr once, and
-`available()` says which mode is active (bench.py and chip_smoke.py
-refuse to run without the library).
+`available()` says which mode is active (chip_smoke.py and
+benchmarks/run.py refuse to run without the library).
 """
 
 from __future__ import annotations

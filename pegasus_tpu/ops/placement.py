@@ -245,10 +245,9 @@ def mesh_wave_pays(n_programs: int, batch_bytes: int) -> bool:
 def offload_breakdown(workload: str, batch_bytes: int) -> dict:
     """Quantified pays/doesn't-pay verdict for one movement-bound
     filter batch — the compaction pipeline's filter stage logs this,
-    and the bench publishes it (PERF round-12's offload table). The
-    verdict mirrors choose_eval_device exactly; the accelerator cost
-    estimate is the probed round-trip plus the bytes at the probed
-    upload rate."""
+    and `shell placement` prints it. The verdict mirrors
+    choose_eval_device exactly; the accelerator cost estimate is the
+    probed round-trip plus the bytes at the probed upload rate."""
     probe = probe_link()
     routed_host = choose_eval_device(workload) is not None
     out = {
@@ -279,8 +278,8 @@ def compact_breakdown(batch_bytes: int,
     of the scan-wave breakdown, so `shell placement` (and the drift
     auditor reading the `mesh_compact` class) cover the compaction
     dispatch site exactly like the wave one. Window count defaults to
-    the modeled pipeline geometry (compact_pipeline_window blocks of
-    BLOCK_CAPACITY rows at ~MESH_COMPACT_ROW_BYTES_EST per row)."""
+    the modeled pipeline geometry (compact_pipeline.PIPELINE_WINDOW
+    blocks of BLOCK_CAPACITY rows at ~MESH_COMPACT_ROW_BYTES_EST per row)."""
     rows = batch_bytes / MESH_COMPACT_ROW_BYTES_EST
     if n_windows is None:
         window_rows = 128 * 1024  # pipeline window x block capacity

@@ -82,9 +82,9 @@ def _make_cached(filter_type: int, pattern: bytes, _device) -> FilterSpec:
     """FilterSpec fields are immutable (jax arrays), so identical
     filters share one device copy — each cache hit saves two
     host->device transfers per scan batch.
-    Keyed by the ambient default device so a multi-backend process
-    (e.g. bench.py's accel phase vs cpu-baseline phase) never leaks
-    one backend's arrays into the other's dispatches."""
+    Keyed by the ambient default device so a process that dispatches
+    under more than one backend (`jax.default_device` is thread-local)
+    never leaks one backend's arrays into the other's dispatches."""
     width = next_bucket(len(pattern))
     buf = np.zeros(width, dtype=np.uint8)
     if pattern:

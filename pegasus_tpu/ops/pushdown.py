@@ -376,7 +376,7 @@ def aggregate_rows(spec: PushdownSpec,
     """Client-side fallback: evaluate the whole spec (value filter +
     aggregate) over materialized (key, user_value) rows — what a client
     does when the server ignored the pushdown spec (pre-pushdown
-    server), and what the bench's client-side arm measures."""
+    server)."""
     vf = spec.value_filter
     st = AggState(spec)
     for key, value in rows:

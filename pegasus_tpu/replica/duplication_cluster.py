@@ -18,7 +18,7 @@ an envelope's ops in decree order as one 2PC mutation and acks at the
 batch's max decree; the ack carries the follower's foreground-pressure
 counters back for the governor's AIMD backoff. Setting
 ship_batch_mutations <= 1 degrades to the original solo-mutation
-client_write shipping (the bench baseline).
+client_write shipping.
 
 Confirmation discipline (the part the in-process TableShipper doesn't
 need): `confirmed_decree` advances ONLY after every follower partition

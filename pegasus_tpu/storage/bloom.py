@@ -18,8 +18,7 @@ is NOT in the table — a run/block lookup can be skipped); True means
 filter and degrade to the unfiltered path.
 
 Knobs (`[pegasus.server]`): `bloom_bits_per_key` (build-time; 0 turns
-filter building off), `bloom_probe` (mutable probe-time kill switch —
-bench baselines measure against it).
+filter building off), `bloom_probe` (mutable probe-time kill switch).
 """
 
 from __future__ import annotations

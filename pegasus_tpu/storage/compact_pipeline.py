@@ -9,8 +9,16 @@ blocks, the GIL-free native subset kernel downstream), and the
 compressed-write stage is CPU+disk-bound. Serially they add; staged
 they hide behind the slowest one.
 
-Topology (one compaction = one pipeline; stages are threads, the
-inter-stage queues are bounded so memory stays a few windows deep):
+What selects (storage/engine.py `_manual_compact_bulk`, from what it
+observes; no flag): a snapshot of more than one window
+(PIPELINE_WINDOW entries) on a host of 4+ cores
+(`stage_threads_enabled`) runs the stages below as threads; a smaller
+snapshot, or fewer cores, runs the same three stage functions inline
+on the calling thread, window by window: one window is read, then
+filtered, then written, so threads would have nothing to overlap.
+
+Topology (one threaded compaction = one pipeline; the inter-stage
+queues are bounded so memory stays a few windows deep):
 
     READ thread    walks the snapshot's entries in key order (a block
                    that flows unchanged, or a splice of chain blocks
@@ -24,8 +32,8 @@ inter-stage queues are bounded so memory stays a few windows deep):
                    cost model; encoded blocks with key-free rulesets
                    evaluate host-direct off their raw predicate
                    columns), then drain the PREVIOUS window while this
-                   one evaluates — the device lookahead the serial
-                   path had, kept inside the stage
+                   one evaluates — the inline loop's one-window
+                   device lookahead, kept inside the stage
     WRITE (caller) the consuming generator feeds
                    LSMStore.bulk_compact_rewrite unchanged: subset
                    kernel, async SST writers, threaded finish, and the
@@ -33,10 +41,9 @@ inter-stage queues are bounded so memory stays a few windows deep):
                    exactly where they were
 
 Because the queues are FIFO and the stages preserve entry order, the
-rewrite consumes the identical (block, drop-mask) stream the serial
-path would produce — pipelined output is byte-identical by
-construction, and the bench/tests gate on a content digest to prove
-it stays that way.
+rewrite consumes the identical (block, drop-mask) stream the inline
+loop would produce — the output is byte-identical by construction,
+and the tests gate on a content digest to prove it stays that way.
 
 Mesh-filtered mode: when the table's blocks are resident on the
 device mesh (parallel/mesh_resident.py), the engine pre-computes the
@@ -59,36 +66,24 @@ import threading
 import time
 from typing import Callable, Iterator, List, Optional, Sequence
 
-from pegasus_tpu.utils.flags import FLAGS, define_flag
 from pegasus_tpu.utils import tracing
 from pegasus_tpu.utils.metrics import METRICS
 
-define_flag("pegasus.storage", "compact_pipeline", True,
-            "overlap bulk compaction's block-read / filter-eval / "
-            "write stages on dedicated threads with bounded queues; "
-            "off = the serial windowed path (same output bytes either "
-            "way)", mutable=True)
-define_flag("pegasus.storage", "compact_pipeline_window", 128,
-            "blocks per pipeline window (the unit the stages hand "
-            "each other); bounds per-window memory and the filter "
-            "batch size — smaller windows feed the write-stage "
-            "transform pool sooner (measured best 64-128 on the "
-            "round-12 box)", mutable=True)
-define_flag("pegasus.storage", "compact_pipeline_depth", 2,
-            "windows each bounded inter-stage queue may hold — total "
-            "in-flight memory is ~(2*depth + 2) windows", mutable=True)
-
-
-def pipeline_enabled() -> bool:
-    return bool(FLAGS.get("pegasus.storage", "compact_pipeline"))
+# blocks per pipeline window (the unit the stages hand each other):
+# bounds per-window memory and the filter batch size, and a snapshot
+# of at most one window is what the engine runs inline
+PIPELINE_WINDOW = 128
+# windows each bounded inter-stage queue may hold: total in-flight
+# memory is ~(2*depth + 2) windows
+PIPELINE_DEPTH = 2
 
 
 def pipeline_window() -> int:
-    return int(FLAGS.get("pegasus.storage", "compact_pipeline_window"))
+    return PIPELINE_WINDOW
 
 
 def pipeline_depth() -> int:
-    return int(FLAGS.get("pegasus.storage", "compact_pipeline_depth"))
+    return PIPELINE_DEPTH
 
 
 def window_count(n_entries: int) -> int:

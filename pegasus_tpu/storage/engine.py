@@ -32,10 +32,9 @@ from pegasus_tpu.ops.compaction import (
     note_filter_program,
 )
 from pegasus_tpu.ops.record_block import build_record_block
-# imported for their flag definitions (compact_pipeline /
-# compact_max_mbps etc. must exist before any config file applies)
+# imported for its flag definitions (compact_max_mbps etc. must exist
+# before any config file applies)
 from pegasus_tpu.storage import compact_governor  # noqa: F401
-from pegasus_tpu.storage import compact_pipeline  # noqa: F401
 from pegasus_tpu.storage.lsm import (
     TRANSFORM_CHUNK_BLOCKS,
     LSMStore,
@@ -342,15 +341,20 @@ class StorageEngine:
         pure L1, L0 tables chained by key range, an overlay spliced
         into the blocks it overlaps).
 
-        Pipelined (default): the block-read, filter-eval, and
-        compressed-write stages run on dedicated threads connected by
-        bounded queues (storage/compact_pipeline.py) — disk reads,
-        device/XLA filter programs, the native subset kernel, and the
-        output writers all overlap, and the read stage pays the
-        CompactionGovernor's token bucket so background bandwidth
-        answers foreground pressure. Serial (flag off): the original
-        windowed loop with one-window device lookahead. Both produce
-        the identical (block, mask) stream, so output bytes match.
+        One set of stage functions (read, filter submit/drain, write),
+        two loops over them, chosen by what this call observes. A
+        snapshot of more than one window (`pipeline_window()` entries)
+        on a host of 4+ cores: the block-read and filter-eval stages
+        run on dedicated threads connected by bounded queues
+        (storage/compact_pipeline.py) and the rewrite transforms on a
+        worker pool — disk reads, device/XLA filter programs, the
+        native subset kernel and the output writers all overlap.
+        Anything smaller, or fewer cores: the windowed loop runs the
+        stages inline on the calling thread with one-window device
+        lookahead. Either way the read stage pays the
+        CompactionGovernor's token bucket, so background bandwidth
+        answers foreground pressure, and both loops produce the
+        identical (block, mask) stream, so output bytes match.
 
         Mesh-filtered: when the table's blocks are resident on the
         device mesh (parallel/mesh_resident.py), the whole store's drop
@@ -371,7 +375,6 @@ class StorageEngine:
         from pegasus_tpu.storage.compact_pipeline import (
             CompactPipeline,
             pipeline_depth,
-            pipeline_enabled,
             pipeline_window,
             stage_threads_enabled,
             transform_workers,
@@ -522,8 +525,7 @@ class StorageEngine:
         # thousand rows) runs its stages inline on the calling thread.
         # Beside other compactions of a pool its helper threads only
         # queue for the interpreter lock.
-        if (pipeline_enabled() and stage_threads_enabled()
-                and len(entries) > pipeline_window()):
+        if stage_threads_enabled() and len(entries) > pipeline_window():
             pipe = CompactPipeline(
                 entries, load, submit_window, drain_window,
                 window=pipeline_window(), depth=pipeline_depth(),
@@ -535,7 +537,7 @@ class StorageEngine:
                 eager=lambda token: not token[1])
             results = pipe.results()
         else:
-            def serial_results():
+            def inline_results():
                 # one-window lookahead ONLY for windows with an
                 # in-flight device program: while window w's masks
                 # drain and its survivors rewrite, window w+1 is
@@ -559,15 +561,15 @@ class StorageEngine:
                 if pending is not None:
                     yield from drain_window(pending)
 
-            results = serial_results()
+            results = inline_results()
 
         self.lsm.bulk_compact_rewrite(
             results, meta, ttl_may_change=ttl_may_change,
             patch_headers=self.values_carry_expire_header,
             publish_lock=publish_lock,
             transform_workers=(
-                transform_workers() if pipeline_enabled()
-                and len(entries) > TRANSFORM_CHUNK_BLOCKS else 0),
+                transform_workers()
+                if len(entries) > TRANSFORM_CHUNK_BLOCKS else 0),
             snap=snap)
 
     def manual_compact(self, default_ttl: int = 0, pidx: int = 0,

@@ -765,8 +765,9 @@ class LSMStore:
         exactly `snap.l0` and `snap.runs` (default: no L0 table and the
         live L1 runs).
 
-        `transform_workers` > 0 (the pipelined compactor's write
-        stage): the per-block transform — subset kernel, heap
+        `transform_workers` > 0 (the engine passes a pool size for a
+        snapshot of more than one TRANSFORM_CHUNK_BLOCKS chunk, 0 for
+        a smaller one): the per-block transform — subset kernel, heap
         inflate/re-deflate, numpy gathers — runs on an ordered worker
         pool while this thread only appends results, so the GIL-free
         kernel work of block N+1..N+k overlaps block N's writer append.
@@ -829,7 +830,7 @@ class LSMStore:
             """Stateless per-block transform -> (kind, payload). The
             expensive work lives here — subset kernel (GIL-free), heap
             inflate, numpy gathers — and runs identically inline
-            (serial) or on the ordered worker pool (pipelined)."""
+            (`transform_workers` 0) or on the ordered worker pool."""
             _run, _idx, blk, drop, new_ets = item
             # a chained L0 block may hold tombstones: they never stay
             dropped = bool(drop.any()) or bool(np.any(blk.flags))

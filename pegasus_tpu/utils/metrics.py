@@ -86,12 +86,6 @@ class VolatileCounter(Counter):
             self._cursors[reader_id] = v
             return delta
 
-    def fetch_and_reset(self) -> int:
-        """Deprecated shim for the old reset-on-read surface: one
-        implicit shared reader. `value()` keeps reporting the
-        cumulative sum (it no longer resets underneath anyone)."""
-        return self.delta_since("__legacy_reset__")
-
     def snapshot(self) -> Dict[str, Any]:
         # cumulative, like a plain counter: a snapshot (JSON /metrics or
         # Prometheus scrape) must never consume another reader's delta —

@@ -18,8 +18,7 @@ Design rules, in order:
   attribute read + a truthiness check (the same discipline as
   tracing.annotate); `start()` returns None when the
   ``[pegasus.perfctx] enabled`` kill switch is off, so nothing is ever
-  pushed and every hook sees None. The bench `perfctx_overhead` phase
-  gates contexts-ENABLED within 2% of hard-off.
+  pushed and every hook sees None.
 - ON must stay cheap: fields are plain ints on a __slots__ object
   (`pc.blocks_decoded += 1`), and batched paths accumulate locals in
   their loops and add once per flush, exactly like the metric
@@ -45,7 +44,7 @@ from pegasus_tpu.utils.flags import FLAGS, define_flag
 
 define_flag("pegasus.perfctx", "enabled", True,
             "collect per-op PerfContext cost vectors on the read/scan/"
-            "write paths (kill switch; bench-gated <=2% overhead)",
+            "write paths (kill switch)",
             mutable=True)
 
 # (name, kind) registrations — metrics_lint scans the perf_field(...)

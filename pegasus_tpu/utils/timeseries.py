@@ -41,7 +41,7 @@ from pegasus_tpu.utils.metrics import (
 
 define_flag("pegasus.health", "recorder_enabled", True,
             "master switch for the per-node flight recorder tick "
-            "(rings + health rules); the bench's off-baseline",
+            "(rings + health rules)",
             mutable=True)
 define_flag("pegasus.health", "recorder_interval_s", 10.0,
             "minimum seconds between flight-recorder ticks (a caller "
@@ -234,8 +234,7 @@ class FlightRecorder:
     # ---- read surfaces -------------------------------------------------
 
     def nbytes(self) -> int:
-        """Ring-memory estimate (the cost the bench records and the cap
-        enforces)."""
+        """Ring-memory estimate (what the cap enforces)."""
         return (len(self._series) * SERIES_OVERHEAD
                 + self._total_points * POINT_BYTES)
 

@@ -58,3 +58,20 @@ def _reset_tenant_registry():
     except Exception:  # noqa: BLE001 - package not imported by this test
         return
     TENANTS.reset()
+
+
+@pytest.fixture
+def started_threads(monkeypatch):
+    """Names of the threads started while the test runs, in order (a
+    compaction's stage threads are `compact-read`, `compact-filter`)."""
+    import threading
+
+    started = []
+    real_start = threading.Thread.start
+
+    def start(thread):
+        started.append(thread.name)
+        real_start(thread)
+
+    monkeypatch.setattr(threading.Thread, "start", start)
+    return started

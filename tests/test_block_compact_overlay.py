@@ -322,7 +322,8 @@ def test_chained_tombstones_never_reach_l1(tmp_path):
     b.eng.close()
 
 
-def test_pool_is_for_stores_of_more_than_one_window(tmp_path):
+def test_pool_is_for_stores_of_more_than_one_window(tmp_path, monkeypatch,
+                                                    started_threads):
     """compact_partitions_parallel compacts a store of at most one
     pipeline window of blocks on the calling thread (its compaction is
     interpreter-bound: a pool only queues for the lock), a larger one
@@ -330,18 +331,9 @@ def test_pool_is_for_stores_of_more_than_one_window(tmp_path):
     from pegasus_tpu.client.table import compact_partitions_parallel
     from pegasus_tpu.server.partition_server import PartitionServer
     from pegasus_tpu.storage import compact_pipeline
-    from pegasus_tpu.utils.flags import FLAGS
 
     servers = [PartitionServer(str(tmp_path / f"p{i}")) for i in range(3)]
     where = {}
-    started = []
-    real_start = threading.Thread.start
-
-    def start(thread):
-        started.append(thread.name)
-        real_start(thread)
-
-    old = FLAGS.get("pegasus.storage", "compact_pipeline_window")
     try:
         def noting(s, real):
             def manual_compact(**kw):
@@ -354,12 +346,8 @@ def test_pool_is_for_stores_of_more_than_one_window(tmp_path):
                 s.on_put(_key(i), b"v%d" % i)
             s.manual_compact = noting(s, s.manual_compact)
         # a window of 2 blocks: 300 rows are inside one, 5,000 are not
-        FLAGS.set("pegasus.storage", "compact_pipeline_window", 2)
-        threading.Thread.start = start
-        try:
-            compact_partitions_parallel(servers)
-        finally:
-            threading.Thread.start = real_start
+        monkeypatch.setattr(compact_pipeline, "PIPELINE_WINDOW", 2)
+        compact_partitions_parallel(servers)
         here = threading.current_thread().name
         assert where[id(servers[0])] == where[id(servers[1])] == here
         assert where[id(servers[2])] != here
@@ -367,9 +355,8 @@ def test_pool_is_for_stores_of_more_than_one_window(tmp_path):
                    for s in servers)
         # the stage threads started once: for the store of two windows
         assert compact_pipeline.stage_threads_enabled()
-        assert started.count("compact-read") == 1
+        assert started_threads.count("compact-read") == 1
     finally:
-        FLAGS.set("pegasus.storage", "compact_pipeline_window", old)
         for s in servers:
             s.close()
 

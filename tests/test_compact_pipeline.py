@@ -1,8 +1,9 @@
 """PR 8: accelerator-pipelined compaction + the background-IO scheduler.
 
 Covers the four acceptance surfaces:
-- byte-identity of pipelined vs serial compaction over MIXED
-  legacy(none)+dcz+dcz2 stores (both compaction shapes);
+- byte-identity of the stage threads vs the inline loop over MIXED
+  legacy(none)+dcz+dcz2 stores (both compaction shapes), and which of
+  the two the engine picks from the snapshot's size and the cores;
 - crash mid-pipeline: a write fault aborts the compaction, nothing of
   the half-built output is adopted at reopen (manifest-then-unlink
   ordering holds) and the data still serves;
@@ -25,6 +26,7 @@ import pytest
 
 from pegasus_tpu.base.key_schema import generate_key
 from pegasus_tpu.base.value_schema import epoch_now
+from pegasus_tpu.storage import compact_pipeline
 from pegasus_tpu.storage.compact_governor import CompactionGovernor
 from pegasus_tpu.storage.engine import StorageEngine, WriteBatchItem
 from pegasus_tpu.storage.wal import OP_PUT
@@ -39,15 +41,22 @@ def _set_flag(section, name, value):
 
 @pytest.fixture
 def pipeline_flags():
-    """Snapshot + restore the storage flags the tests flip."""
-    saved = [(s, n, FLAGS.get(s, n)) for s, n in (
-        ("pegasus.storage", "compact_pipeline"),
-        ("pegasus.storage", "block_codec"),
-        ("pegasus.storage", "compact_pipeline_window"),
-    )]
+    """Snapshot + restore the storage flag the tests flip."""
+    saved = FLAGS.get("pegasus.storage", "block_codec")
     yield
-    for s, n, v in saved:
-        FLAGS.set(s, n, v)
+    FLAGS.set("pegasus.storage", "block_codec", saved)
+
+
+# _build_mixed_store's snapshot is 60 entries: a window of 8 is what
+# the engine hands the stage threads, the module's own 128 what it
+# runs inline (tests of the threaded side need a host of 4+ cores,
+# which stage_threads_enabled reads off os.cpu_count)
+LOOPS = {"inline": 128, "stage_threads": 8}
+STAGE_THREADS = ["compact-read", "compact-filter"]
+
+
+def _stage_threads(started):
+    return [n for n in started if n in STAGE_THREADS]
 
 
 def _build_mixed_store(d: str, block_capacity: int = 64) -> None:
@@ -90,9 +99,11 @@ def _digest(eng: StorageEngine) -> str:
 
 def test_pipelined_identical_to_serial_mixed_codecs(tmp_path,
                                                     pipeline_flags,
-                                                    monkeypatch):
-    """The tentpole gate: the pipelined stages must produce the exact
-    bytes the serial path produces, over a store mixing legacy raw,
+                                                    monkeypatch,
+                                                    started_threads):
+    """The tentpole gate: the stage threads must produce the exact
+    bytes the inline loop produces (the engine picks by the snapshot's
+    size against the window), over a store mixing legacy raw,
     dcz, and dcz2 runs — through both shapes of the block path (twelve
     L0 tables of three codecs chained by key range, then pure L1)."""
     import pegasus_tpu.storage.engine as engine_mod
@@ -106,37 +117,45 @@ def test_pipelined_identical_to_serial_mixed_codecs(tmp_path,
     src = str(tmp_path / "src")
     _build_mixed_store(src)
     FLAGS.set("pegasus.storage", "block_codec", "dcz2")
-    FLAGS.set("pegasus.storage", "compact_pipeline_window", 8)
-    digs = {}
-    for mode in (False, True):
-        d = str(tmp_path / f"m{mode}")
+    digs, threads = {}, {}
+    for loop, window in LOOPS.items():
+        d = str(tmp_path / loop)
         shutil.copytree(src, d)
-        FLAGS.set("pegasus.storage", "compact_pipeline", mode)
+        monkeypatch.setattr(compact_pipeline, "PIPELINE_WINDOW", window)
+        del started_threads[:]
         eng = StorageEngine(d, block_capacity=64)
         snap = eng.lsm.bulk_compact_snapshot()
         assert len(snap.chain) == 12 and not snap.overlay
         eng.manual_compact()          # chained L0s -> L1
         assert eng.lsm.bulk_compact_eligible()
         eng.manual_compact()          # pure L1
-        digs[mode] = _digest(eng)
+        digs[loop] = _digest(eng)
+        threads[loop] = _stage_threads(started_threads)
         eng.close()
-    assert digs[True] == digs[False]
+    assert digs["stage_threads"] == digs["inline"]
+    assert threads == {"inline": [], "stage_threads": STAGE_THREADS * 2}
 
 
-def test_crash_mid_pipeline_keeps_old_store(tmp_path, pipeline_flags):
-    """A disk fault mid-compaction must abort the pipeline cleanly:
-    the error propagates, stage threads stop, no half-built l1 output
-    is adopted at reopen (the manifest still names the old runs), and
-    every record still serves."""
+@pytest.mark.parametrize("loop", list(LOOPS))
+def test_crash_mid_pipeline_keeps_old_store(tmp_path, pipeline_flags,
+                                            monkeypatch,
+                                            started_threads, loop):
+    """A disk fault mid-compaction must abort either loop cleanly:
+    the error propagates, stage threads (where the snapshot's size
+    started them) stop, no half-built l1 output is adopted at reopen
+    (the manifest still names the old runs), and every record still
+    serves."""
+    import threading
+
     from pegasus_tpu.utils.fail_point import FAIL_POINTS
 
     d = str(tmp_path / "s")
     _build_mixed_store(d)
     FLAGS.set("pegasus.storage", "block_codec", "dcz2")
-    FLAGS.set("pegasus.storage", "compact_pipeline", True)
-    FLAGS.set("pegasus.storage", "compact_pipeline_window", 8)
+    monkeypatch.setattr(compact_pipeline, "PIPELINE_WINDOW", LOOPS[loop])
     eng = StorageEngine(d, block_capacity=64)
     eng.manual_compact()  # pure L1 now
+    del started_threads[:]
     before = _digest(eng)
     runs_before = [os.path.basename(t.path) for t in eng.lsm.l1_runs]
     gen = eng.lsm.generation
@@ -149,6 +168,10 @@ def test_crash_mid_pipeline_keeps_old_store(tmp_path, pipeline_flags):
             eng.manual_compact()
     finally:
         FAIL_POINTS.teardown()
+    assert _stage_threads(started_threads) == (
+        STAGE_THREADS if loop == "stage_threads" else [])
+    assert not [t for t in threading.enumerate()
+                if t.name in STAGE_THREADS]
     # publish never happened: same run set, same generation
     assert eng.lsm.generation == gen
     assert [os.path.basename(t.path)
@@ -162,6 +185,47 @@ def test_crash_mid_pipeline_keeps_old_store(tmp_path, pipeline_flags):
     # and a clean retry completes
     eng2.manual_compact()
     eng2.close()
+
+
+@pytest.mark.parametrize("case", ["one_window", "window_plus_one",
+                                  "two_cores"])
+def test_stage_choice_follows_what_the_engine_observes(
+        tmp_path, pipeline_flags, monkeypatch, started_threads, case):
+    """No switch selects the loop: a snapshot of exactly one window
+    runs inline, one entry more starts both stage threads, and a host
+    of 2 cores starts none whatever the size. Same bytes in all."""
+    import pegasus_tpu.storage.engine as engine_mod
+
+    monkeypatch.setattr(engine_mod, "epoch_now", lambda: 334_000_000)
+    d = str(tmp_path / "s")
+    _build_mixed_store(d)
+    FLAGS.set("pegasus.storage", "block_codec", "dcz2")
+
+    def compact(window, cores):
+        c = str(tmp_path / f"w{window}c{cores}")
+        shutil.copytree(d, c)
+        monkeypatch.setattr(compact_pipeline, "PIPELINE_WINDOW", window)
+        monkeypatch.setattr(os, "cpu_count", lambda: cores)
+        eng = StorageEngine(c, block_capacity=64)
+        del started_threads[:]
+        eng.manual_compact()
+        dig = _digest(eng)
+        eng.close()
+        return dig, _stage_threads(started_threads)
+
+    eng = StorageEngine(d, block_capacity=64)
+    n = len(eng.lsm.bulk_compact_entries(eng.lsm.bulk_compact_snapshot()))
+    eng.close()
+    assert n > 16   # more than one transform chunk: the pool runs too
+    window, cores, want = {
+        "one_window": (n, 8, []),
+        "window_plus_one": (n - 1, 8, STAGE_THREADS),
+        "two_cores": (8, 2, []),
+    }[case]
+    dig, threads = compact(window, cores)
+    assert threads == want
+    # the reference: the module's own window of 128, inline
+    assert dig == compact(128, 8)[0]
 
 
 # ---- dcz2 column codecs ------------------------------------------------
@@ -545,14 +609,13 @@ def test_env_trigger_defers_until_granted(server):
 
 
 def test_scrub_restarts_once_per_publish(tmp_path, pipeline_flags):
-    """One pipelined manual compaction bumps the store generation
+    """One manual compaction bumps the store generation
     more than once (freeze-flush + publish cut-over); the scrubber
     must restart its pass exactly ONCE for it — and pause (not
     restart) while the compaction holds the lock."""
     from pegasus_tpu.storage.scrub import ReplicaScrubber
     from pegasus_tpu.utils.metrics import METRICS
 
-    FLAGS.set("pegasus.storage", "compact_pipeline", True)
     d = str(tmp_path / "s")
     _build_mixed_store(d)
     eng = StorageEngine(d, block_capacity=64)
@@ -594,15 +657,15 @@ def test_scrub_restarts_once_per_publish(tmp_path, pipeline_flags):
     eng.close()
 
 
-def test_pipeline_stall_counters_populate(tmp_path, pipeline_flags):
-    """Observability satellite: a pipelined compaction must leave
+def test_pipeline_stall_counters_populate(tmp_path, pipeline_flags,
+                                          monkeypatch):
+    """Observability satellite: a threaded compaction must leave
     per-stage evidence behind (bytes/s gauge; stall counters may or
     may not tick depending on which stage bottlenecks, but the gauges
     exist on the storage entity and the run must not zero them out)."""
     from pegasus_tpu.utils.metrics import METRICS
 
-    FLAGS.set("pegasus.storage", "compact_pipeline", True)
-    FLAGS.set("pegasus.storage", "compact_pipeline_window", 4)
+    monkeypatch.setattr(compact_pipeline, "PIPELINE_WINDOW", 4)
     d = str(tmp_path / "s")
     _build_mixed_store(d)
     eng = StorageEngine(d, block_capacity=64)

@@ -1,7 +1,7 @@
 """Mesh-resident compaction filtering acceptance: ONE whole-table SPMD
 dispatch must hand every sibling partition's bulk compaction its drop
-masks (and rewritten-TTL column) BYTE-IDENTICALLY to the host-serial
-and host-pipelined filter stages over every store shape — mixed
+masks (and rewritten-TTL column) BYTE-IDENTICALLY to the host filter
+stages, run inline or on the stage threads, over every store shape — mixed
 none/dcz/dcz2 histories, empty-hashkey overflow rows, verbatim-carry
 blocks, default-TTL rewrites and user rulesets — degrade through the
 dispatch watchdog to host filtering with identical published files, and
@@ -28,6 +28,7 @@ from pegasus_tpu.client.client import PegasusClient
 from pegasus_tpu.client.table import Table
 from pegasus_tpu.ops.compaction_rules import compile_rules
 from pegasus_tpu.parallel.mesh_resident import MESH_SERVING
+from pegasus_tpu.storage import compact_pipeline
 from pegasus_tpu.utils.flags import FLAGS
 
 N_PARTS = 8
@@ -50,7 +51,6 @@ def mesh_guard(monkeypatch):
 
     saved = [(sec, name, FLAGS.get(sec, name)) for sec, name in (
         ("pegasus.storage", "block_codec"),
-        ("pegasus.storage", "compact_pipeline"),
         ("pegasus.mesh", "serving_enabled"),
         ("pegasus.mesh", "dispatch_deadline_s"),
     )]
@@ -65,13 +65,13 @@ def mesh_guard(monkeypatch):
 def force_compact_pays(monkeypatch):
     """Tiny fixtures never amortize a dispatch; identity tests pin the
     gate open so every compaction exercises the mesh path (the honest
-    gate has its own unit test + the bench's 8-partition phase)."""
+    gate has its own unit test)."""
     from pegasus_tpu.ops import placement
     monkeypatch.setattr(placement, "mesh_compact_pays",
                         lambda *_a, **_k: True)
 
 
-def build_store(tmp_path, final_codec="none"):
+def build_store(tmp_path, final_codec="none", rows_per_codec=200):
     """8 partitions crossing every storage shape: rows written under
     three codec generations, TTL'd rows that will expire at the arms'
     fixed filter timestamp, empty-hashkey overflow rows — then
@@ -84,7 +84,7 @@ def build_store(tmp_path, final_codec="none"):
     i = 0
     for codec in ("none", "dcz", "dcz2"):
         FLAGS.set("pegasus.storage", "block_codec", codec)
-        for _ in range(200):
+        for _ in range(rows_per_codec):
             rc = c.set(b"hk%03d" % (i % 40), b"s%05d" % i, b"v%05d" % i,
                        ttl_seconds=7 if i % 3 == 0 else 0)
             assert rc == 0
@@ -116,14 +116,16 @@ def digest(d):
 
 
 def compact_arm(base, name, now, *, mesh=False, wedge=False,
-                pipelined=True, default_ttl=0, rules=None):
+                window=compact_pipeline.PIPELINE_WINDOW, default_ttl=0,
+                rules=None):
     """Copy the base store, compact every partition at the shared
-    fixed `now`, return (sst digests, iterated rows, serving status)."""
+    fixed `now`, return (sst digests, iterated rows, serving status).
+    `window`: the pipeline window the engine weighs each snapshot
+    against (more entries than it: the stage threads run)."""
     d = base + "_" + name
     shutil.rmtree(d, ignore_errors=True)
     shutil.copytree(base, d)
     MESH_SERVING.reset()
-    FLAGS.set("pegasus.storage", "compact_pipeline", pipelined)
     t = Table(d, partition_count=N_PARTS)
     try:
         if mesh:
@@ -131,9 +133,11 @@ def compact_arm(base, name, now, *, mesh=False, wedge=False,
                 MESH_SERVING.attach(s)
         if wedge:
             MESH_SERVING.watchdog.deadline_s = 1e-9
-        for s in t.partitions.values():
-            s.manual_compact(default_ttl=default_ttl, rules_filter=rules,
-                             now=now)
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(compact_pipeline, "PIPELINE_WINDOW", window)
+            for s in t.partitions.values():
+                s.manual_compact(default_ttl=default_ttl,
+                                 rules_filter=rules, now=now)
         st = MESH_SERVING.status()
         rows = {p: list(s.engine.lsm.iterate())
                 for p, s in sorted(t.partitions.items())}
@@ -145,14 +149,20 @@ def compact_arm(base, name, now, *, mesh=False, wedge=False,
 
 @pytest.mark.parametrize("codec", ["none", "dcz", "dcz2"])
 def test_identity_host_serial_pipelined_mesh(tmp_path, mesh_guard,
-                                             monkeypatch, codec):
-    """The tentpole gate: host-serial, host-pipelined, and mesh-filter
-    modes publish the exact same bytes, and the mesh mode really serves
-    the whole table from ONE dispatch (7 sibling cache hits)."""
-    base = build_store(tmp_path, final_codec=codec)
+                                             monkeypatch,
+                                             started_threads, codec):
+    """The tentpole gate: the host filter run inline, the host filter
+    on the stage threads (the engine picks by the snapshot's size),
+    and the mesh filter publish the exact same bytes, and the mesh mode
+    really serves the whole table from ONE dispatch (7 sibling cache
+    hits)."""
+    # 2-3 blocks a partition: above a window of 1, inside the module's
+    base = build_store(tmp_path, final_codec=codec, rows_per_codec=4000)
     now = epoch_now() + 3600  # every ttl_seconds=7 row is expired
-    serial, s_rows, _ = compact_arm(base, "serial", now, pipelined=False)
-    piped, p_rows, _ = compact_arm(base, "piped", now)
+    serial, s_rows, _ = compact_arm(base, "serial", now)
+    assert "compact-read" not in started_threads
+    piped, p_rows, _ = compact_arm(base, "piped", now, window=1)
+    assert started_threads.count("compact-read") == N_PARTS
     force_compact_pays(monkeypatch)
     meshed, m_rows, st = compact_arm(base, "mesh", now, mesh=True)
     assert serial == piped == meshed

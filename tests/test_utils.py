@@ -33,6 +33,28 @@ def test_flags_define_get_set(tmp_path):
         reg.load_ini(str(ini))
 
 
+def test_ini_key_of_a_removed_flag_loads_and_changes_nothing(tmp_path):
+    """An operator's old config must keep booting: `compact_pipeline`
+    was a flag until PR 29 (the engine now picks the compaction loop
+    from the snapshot's size), and load_ini skips keys it does not
+    define."""
+    from pegasus_tpu.storage import compact_pipeline, engine  # noqa: F401
+    from pegasus_tpu.utils.flags import FLAGS
+
+    before = FLAGS.snapshot()
+    window = compact_pipeline.pipeline_window()
+    ini = tmp_path / "config.ini"
+    ini.write_text("[pegasus.storage]\ncompact_pipeline = false\n"
+                   "compact_pipeline_window = 4\n"
+                   "compact_pipeline_depth = 9\n")
+    FLAGS.load_ini(str(ini))
+    assert FLAGS.snapshot() == before
+    assert not [n for n in before["pegasus.storage"]
+                if n.startswith("compact_pipe")]
+    assert (compact_pipeline.pipeline_window(),
+            compact_pipeline.pipeline_depth()) == (window, 2)
+
+
 def test_metrics_entities_and_percentile():
     reg = MetricRegistry()
     ent = reg.entity("replica", "1.2", {"table": "temp"})
@@ -50,17 +72,16 @@ def test_metrics_entities_and_percentile():
     assert reg.snapshot(entity_type="table") == []
 
 
-def test_volatile_counter_legacy_shim_still_reads_deltas():
+def test_volatile_counter_reader_reads_deltas_value_stays_cumulative():
     reg = MetricRegistry()
     c = reg.entity("server", "s1").volatile_counter("qps")
     c.increment(10)
-    # the deprecated reset-on-read surface keeps its delta semantics
-    # through one implicit shared cursor...
-    assert c.fetch_and_reset() == 10
-    assert c.fetch_and_reset() == 0
+    # a reader sees the increments since its own last call...
+    assert c.delta_since("scraper") == 10
+    assert c.delta_since("scraper") == 0
     c.increment(3)
-    assert c.fetch_and_reset() == 3
-    # ...but the stored value is now CUMULATIVE: nothing resets under
+    assert c.delta_since("scraper") == 3
+    # ...but the stored value is CUMULATIVE: nothing resets under
     # other readers, and snapshots report the sum
     assert c.value() == 13
     assert c.snapshot() == {"type": "volatile_counter", "value": 13}
