@@ -82,6 +82,7 @@ def probe_nat(blk):
         nat = (km, np.asarray(blk.key_len, dtype=np.int64),
                km.view(vt).ravel())
         blk._probe = nat
+        blk.recount()  # the int64 length column is the block's now
     return nat
 
 

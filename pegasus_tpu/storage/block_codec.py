@@ -624,16 +624,6 @@ class EncodedBlock:
                         self.sk_offs[:-1], sk_len)
         return out
 
-    def inflate_heap(self) -> np.ndarray:
-        if self.heap_mode == _HEAP_ZLIB:
-            return np.frombuffer(zlib.decompress(self._heap_comp),
-                                 dtype=np.uint8)
-        if self.heap_mode == _HEAP_ZSTD:
-            return np.frombuffer(
-                _Zstd.decompress(self._heap_comp, self.raw_heap_len),
-                dtype=np.uint8)
-        return self._heap_comp
-
     def decode(self):
         """Full materialization to the standard columnar Block — the
         value heap stays a lazy thunk until a survivor's bytes are
@@ -643,16 +633,27 @@ class EncodedBlock:
         return Block(self.key_matrix(), self.key_len, self.expire_ts,
                      self.hash_lo, self.flags, self.value_offs,
                      self._heap_comp if self.heap_mode == _HEAP_RAW
-                     else self.inflate_heap)
+                     else LazyHeap(self._heap_comp, self.heap_mode,
+                                   self.raw_heap_len))
 
-    def mem_bytes(self) -> int:
-        """Resident-byte estimate of the DECODED block (cache
-        accounting: a decoded compressed block is real allocation, not
-        an mmap view; the +64/row covers the lazily materialized
-        key_list / probe table a resident block grows)."""
-        n = self.n
-        return (n * (self.key_width + 64) + 13 * n
-                + self.raw_heap_len + 512)
+
+class LazyHeap:
+    """A deflated value heap, inflated by the call. It holds the stored
+    bytes (`stored`: a view over the block's on-disk bytes) and not the
+    EncodedBlock, so a decoded Block that has not served a value yet
+    pins, and is charged, no more than those."""
+
+    __slots__ = ("stored", "mode", "raw_len")
+
+    def __init__(self, stored: np.ndarray, mode: int, raw_len: int) -> None:
+        self.stored, self.mode, self.raw_len = stored, mode, raw_len
+
+    def __call__(self) -> np.ndarray:
+        if self.mode == _HEAP_ZLIB:
+            out = zlib.decompress(self.stored)
+        else:
+            out = _Zstd.decompress(self.stored, self.raw_len)
+        return np.frombuffer(out, dtype=np.uint8)
 
 
 # ---- wire-payload compression (shared with cross-cluster duplication) ----

@@ -32,6 +32,9 @@ import bisect
 import json
 import os
 import struct
+import sys
+import threading
+import weakref
 from dataclasses import dataclass
 from typing import Iterator, List, Optional, Tuple
 
@@ -92,7 +95,13 @@ define_flag("pegasus.storage", "block_cache_bytes", 33_554_432,
             "per-table decoded-block cache budget in bytes (LRU). "
             "Replaces the old fixed 256-block count cap: compressed "
             "blocks decode into real allocations of wildly varying "
-            "size, so only a byte budget bounds memory", mutable=True)
+            "size, so only a byte budget bounds memory. A resident "
+            "block is charged what it allocates: the arrays it owns "
+            "(key matrix, rebuilt columns), a value heap once "
+            "inflated, the key list and point-probe table once "
+            "built, and a read() copy of its file bytes where the "
+            "file is not mmapped; views over the file's mmap are "
+            "free", mutable=True)
 
 
 def block_crc_enabled() -> bool:
@@ -134,6 +143,28 @@ _COMPRESSED_DECODE = _STORAGE_METRICS.relaxed_counter(
     "compressed_block_decode_count")
 _BLOCK_EVICT_BYTES = _STORAGE_METRICS.relaxed_counter(
     "block_cache_evict_bytes")
+# the budget's use beside them: the sum of the charges in every live
+# table's block cache. A sum of deltas from many tables, so (unlike the
+# relaxed counters) it is updated under a lock: a lost update would
+# stay in a gauge for the process's life. Misses, evictions and lazy
+# builds move it; a hit does not
+_BLOCK_RESIDENT = _STORAGE_METRICS.gauge("block_cache_resident_bytes")
+_BLOCK_RESIDENT_LOCK = threading.Lock()
+
+
+def _resident_add(delta: int) -> None:
+    if delta:
+        with _BLOCK_RESIDENT_LOCK:
+            _BLOCK_RESIDENT.set(_BLOCK_RESIDENT.value() + delta)
+
+
+def _drop_charges(cache) -> None:
+    """Empty one table's block cache and take its charges out of the
+    node's gauge. Also the table's finalizer: a superseded run is
+    released by GC, not closed (LSMStore publish), and its blocks go
+    with it."""
+    _resident_add(-sum(nb for _blk, nb in cache.values()))
+    cache.clear()
 
 from pegasus_tpu.utils.tracing import annotate as _trace_annotate  # noqa: E402
 from pegasus_tpu.utils.perf_context import current as _perf_current  # noqa: E402
@@ -163,6 +194,31 @@ class BlockMeta:
     crc: Optional[int] = None
 
 
+# what a resident Block costs before any array: the Block, its seven
+# ndarray headers (112-128 bytes each) and the cache's entry. It keeps
+# a file of pure mmap views (codec none) from being cached without end
+BLOCK_OBJECT_BYTES = 1024
+
+
+def _owned_nbytes(parts) -> int:
+    """Bytes of the allocations that `parts` (arrays, or the buffers
+    behind them) keep alive, each allocation counted once and whole: an
+    array that owns its data, or the bytes object a view was cut from
+    (a read() copy of the block, an inflated heap). A view over the
+    file's mmap keeps no allocation: those pages are the page cache's."""
+    owned = {}
+    for a in parts:
+        while isinstance(a, np.ndarray) and a.base is not None:
+            a = a.base
+        if isinstance(a, memoryview):
+            a = a.obj
+        if isinstance(a, np.ndarray):
+            owned[id(a)] = a.nbytes
+        elif isinstance(a, (bytes, bytearray)):
+            owned[id(a)] = len(a)
+    return sum(owned.values())
+
+
 class Block:
     """A decoded columnar block; arrays are views over the file bytes\n    (plus, for blocks that prove hot, one lazily materialized Python\n    key list — see key_list()).
 
@@ -170,11 +226,17 @@ class Block:
     a zero-arg thunk: the heap decompression runs on first value access, so
     key-only work (point probes, bloom builds, fence walks, no-value
     scans) over a compressed block never pays the heap decode —
-    materialization is deferred to the rows that actually serve."""
+    materialization is deferred to the rows that actually serve.
+
+    `resident` is what the block allocates as it stands, the charge it
+    carries in its table's block cache. The block recounts it whenever
+    a lazy part is built (the heap inflated, key_list(), the point-probe
+    table); the cache re-reads it on the next hit. Not counted: the
+    alive mask (a byte a row, replaced each second, never added to)."""
 
     __slots__ = ("keys", "key_len", "expire_ts", "hash_lo", "flags",
                  "value_offs", "_vh", "_key_list", "_gets",
-                 "_nat", "_cmp", "_probe")
+                 "_nat", "_cmp", "_probe", "resident")
 
     def __init__(self, keys, key_len, expire_ts, hash_lo, flags, value_offs,
                  value_heap):
@@ -188,12 +250,32 @@ class Block:
         self.flags = flags            # uint8[N]
         self.value_offs = value_offs  # uint32[N+1]
         self._vh = value_heap         # uint8[heap] view, or lazy thunk
+        self.recount()
+
+    def recount(self) -> None:
+        """Set `resident` from what the block holds now. Callers: the
+        block's own lazy builders, and page.probe_nat after it sets
+        `_probe`."""
+        vh = self._vh
+        parts = [self.keys, self.key_len, self.expire_ts, self.hash_lo,
+                 self.flags, self.value_offs,
+                 # a deflated heap pins its stored bytes until inflated
+                 vh.stored if callable(vh) else vh]
+        if self._probe is not None:
+            parts.extend(self._probe)
+        total = BLOCK_OBJECT_BYTES + _owned_nbytes(parts)
+        kl = self._key_list
+        if kl is not None:
+            total += (sys.getsizeof(kl) + len(kl) * sys.getsizeof(b"")
+                      + int(self.key_len.sum()))
+        self.resident = total
 
     @property
     def value_heap(self):
         vh = self._vh
         if callable(vh):
             vh = self._vh = vh()
+            self.recount()
         return vh
 
     @property
@@ -227,6 +309,7 @@ class Block:
             kl = [keys[i, :lens[i]].tobytes()
                   for i in range(keys.shape[0])]
             self._key_list = kl
+            self.recount()
         return kl
 
     def value_at(self, i: int) -> bytes:
@@ -627,9 +710,11 @@ class SSTable:
         # the decoded-block cache is BYTE-capped (LRU, like the node
         # row cache): a raw-file Block is zero-copy numpy views over
         # the mmap and charges only bookkeeping, but a block decoded
-        # from a COMPRESSED file is a real allocation whose size the
-        # old fixed 256-block count cap could not see. `cache_bytes`
-        # None -> the mutable [pegasus.storage] block_cache_bytes flag.
+        # from a COMPRESSED file allocates (key matrix, rebuilt
+        # columns, an inflated heap) what the old fixed 256-block count
+        # cap could not see. A block is charged its Block.resident.
+        # `cache_bytes` None -> the mutable [pegasus.storage]
+        # block_cache_bytes flag.
         import io as _io
         import mmap as _mmap
 
@@ -720,16 +805,17 @@ class SSTable:
             self.phash = PHashIndex.from_bytes(raw, ph)
         from collections import OrderedDict as _OD
 
-        import threading
-
-        # idx -> (Block, charged_bytes); bytes tracked alongside so
-        # eviction never recomputes sizes. Insert/evict accounting runs
-        # under a lock: serving and compaction threads share run caches,
-        # and an interleaved += / -= on _cache_bytes would drift the
-        # budget for the file's whole lifetime (hits stay lock-free)
+        # idx -> (Block, charged_bytes): the block's `resident` as the
+        # cache last read it, tracked alongside so eviction never
+        # recomputes sizes. Insert/evict accounting runs under a lock:
+        # serving and compaction threads share run caches, and an
+        # interleaved += / -= on _cache_bytes would drift the budget
+        # for the file's whole lifetime (a hit stays lock-free unless
+        # its block has grown)
         self._cache: "_OD[int, Tuple[Block, int]]" = _OD()
         self._cache_bytes = 0
         self._cache_lock = threading.Lock()
+        weakref.finalize(self, _drop_charges, self._cache)
         self._cache_budget = cache_bytes  # None -> flag at use
         self._off2idx: Optional[dict] = None  # block_index lookup
         self._last_keys: Optional[List[bytes]] = None  # iter_blocks bisect
@@ -749,7 +835,7 @@ class SSTable:
         """Drop every decoded block (and its byte accounting) — tests
         and cache-pressure tooling; the serving path never needs it."""
         with self._cache_lock:
-            self._cache.clear()
+            _drop_charges(self._cache)
             self._cache_bytes = 0
 
     def may_contain(self, key: bytes, key_hash: Optional[int] = None
@@ -823,10 +909,14 @@ class SSTable:
                 pass  # raced a concurrent eviction (serving vs
                 # compaction threads share run caches); the decoded
                 # block in hand stays valid
+            blk, charged = hit
+            if blk.resident != charged:
+                # a lazy part was built since the cache last looked
+                self._charge(idx, blk, fresh=False)
             _BLOCK_CACHE_HIT.increment()
             if pc is not None:
                 pc.block_cache_hit += 1
-            return hit[0]
+            return blk
         _BLOCK_CACHE_MISS.increment()
         if pc is not None:
             pc.blocks_decoded += 1
@@ -839,9 +929,8 @@ class SSTable:
             # storage join point: a traced request that paid a cold
             # compressed-block decode records it on its span
             _trace_annotate("block_decode")
-            # a decoded compressed block is real allocation (the raw
-            # path below is mmap views): charge its materialized size
-            nbytes = enc.mem_bytes()
+            decoded = raw_block_size(enc.n, enc.key_width,
+                                     enc.raw_heap_len)
         else:
             n, width, heap_size = _BLOCK_HDR.unpack_from(raw, 0)
             pos = _BLOCK_HDR.size
@@ -866,45 +955,45 @@ class SSTable:
             pos += 4 * (n + 1)
             heap = np.frombuffer(raw, dtype=np.uint8, count=heap_size,
                                  offset=pos)
+            # zero-copy views over the page cache, or over a real
+            # read() copy on encrypted stores: Block.resident tells them
+            # apart, as it does for a compressed file's RAW heap
             blk = Block(keys, key_len, ets, hash_lo, flags, offs, heap)
-            # raw blocks start as zero-copy views over the page cache
-            # (or a real read() copy on encrypted stores), but a
-            # resident block lazily materializes real memory the views
-            # don't show — key_list() (~a bytes object per row) and the
-            # point-probe table — so the charge models that worst-case
-            # resident footprint, not the view bookkeeping. Charging
-            # only ~2KB would let the 32MiB default admit ~16k blocks
-            # (the old count cap held 256) whose hidden side tables
-            # could grow unchecked.
-            lazy = n * (width + 64)
-            nbytes = (512 + lazy if self._mv is not None
-                      else bm.size + 512 + lazy)
+            decoded = bm.size
         if pc is not None:
-            # materialized bytes after the codec: the decoded size for
-            # compressed blocks, the on-disk (zero-copy view) size for
-            # raw ones — against bytes_read this is the decode ratio
-            pc.bytes_decoded += (nbytes if self.codec is not None
-                                 else bm.size)
+            # materialized bytes after the codec: the raw layout's size
+            # of a compressed block, the on-disk (zero-copy view) size
+            # of a raw one — against bytes_read this is the decode ratio
+            pc.bytes_decoded += decoded
+        self._charge(idx, blk, fresh=True)
+        return blk
+
+    def _charge(self, idx: int, blk: Block, fresh: bool) -> None:
+        """Enter `blk`'s charge (`fresh`: a block just decoded) or
+        bring it up to what the block has grown to since, and evict
+        from the cold end while the table is over its budget."""
         budget = (self._cache_budget if self._cache_budget is not None
                   else block_cache_budget())
+        nbytes = blk.resident
         evicted = 0
         with self._cache_lock:
             prev = self._cache.get(idx)
-            if prev is not None:
-                # two threads raced the same cold block (serving +
-                # compaction share run caches): the overwrite must
-                # release the first insert's charge or the budget
-                # drifts up by one block per race, forever
-                self._cache_bytes -= prev[1]
+            if not fresh and (prev is None or prev[0] is not blk):
+                return  # evicted or replaced since the hit
+            # prev on a fresh insert: two threads raced the same cold
+            # block (serving + compaction share run caches): the
+            # overwrite must release the first insert's charge or the
+            # budget drifts up by one block per race, forever
+            grown = nbytes - (prev[1] if prev is not None else 0)
             self._cache[idx] = (blk, nbytes)
-            self._cache_bytes += nbytes
+            self._cache_bytes += grown
             while self._cache_bytes > budget and len(self._cache) > 1:
                 _k, (_b, nb) = self._cache.popitem(last=False)
                 self._cache_bytes -= nb
                 evicted += nb
         if evicted:
             _BLOCK_EVICT_BYTES.increment(evicted)
-        return blk
+        _resident_add(grown - evicted)
 
     def verify_block(self, idx: int) -> bool:
         """Scrub entry point: re-read block `idx`'s raw bytes and check

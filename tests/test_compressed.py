@@ -8,8 +8,10 @@ compaction identity (including the verbatim compressed-copy path).
 """
 
 import json
+import mmap
 import os
 import struct
+import sys
 
 import numpy as np
 import pytest
@@ -18,8 +20,10 @@ from pegasus_tpu.base.crc import crc32
 from pegasus_tpu.base.key_schema import generate_key
 from pegasus_tpu.base.value_schema import epoch_now
 from pegasus_tpu.storage.block_codec import EncodedBlock, encode_block
+from pegasus_tpu.server.page import probe_nat
 from pegasus_tpu.storage.lsm import LSMStore
 from pegasus_tpu.storage.sstable import (
+    BLOCK_OBJECT_BYTES,
     FOOTER,
     MAGIC,
     SSTable,
@@ -287,13 +291,13 @@ def test_block_cache_byte_cap_and_evict_counter(tmp_path):
     t = _write(str(tmp_path / "t.sst"), "dcz", n_hash=64, n_sort=4,
                block_capacity=16)
     assert len(t.blocks) >= 8
+    # learn one decoded block's charge, then budget two of them
+    t.read_block(0)
+    one = t._cache[0][1]
+    t.close()
     ent = METRICS.entity("storage", "node")
     d0 = ent.counter("compressed_block_decode_count").value()
     e0 = ent.counter("block_cache_evict_bytes").value()
-    t.close()
-    # budget for ~2 decoded blocks: each charges n*W + 13n + heap + 512
-    one = (16 * 32 + 13 * 16 + 16 * 2 * len(b"value|00000|000|")
-           + 512)
     t = SSTable(str(tmp_path / "t.sst"), cache_bytes=2 * one + 64)
     for i in range(len(t.blocks)):
         t.read_block(i)
@@ -308,6 +312,136 @@ def test_block_cache_byte_cap_and_evict_counter(tmp_path):
     t.read_block(0)
     assert ent2.counter(
         "compressed_block_decode_count").value() == d1 + 1
+    t.close()
+
+
+# ---- what a resident block is charged --------------------------------
+
+
+def _owned_reference(blk):
+    """The bytes `blk` keeps allocated, from its arrays: each buffer an
+    array owns, or the bytes object it was cut from, once; nothing for
+    a view that ends in the file's mmap. Plus the key list, object by
+    object."""
+    heap = blk._vh.stored if callable(blk._vh) else blk._vh
+    arrays = [blk.keys, blk.key_len, blk.expire_ts, blk.hash_lo,
+              blk.flags, blk.value_offs, heap]
+    arrays += list(blk._probe or ())
+    owners = {}
+    for a in arrays:
+        if a is None:
+            continue
+        while isinstance(a, np.ndarray) and not a.flags.owndata:
+            a = a.base
+            if isinstance(a, memoryview):
+                a = a.obj
+        if isinstance(a, mmap.mmap):
+            continue
+        assert isinstance(a, (np.ndarray, bytes)), type(a)
+        owners[id(a)] = a.nbytes if isinstance(a, np.ndarray) else len(a)
+    total = sum(owners.values())
+    if blk._key_list is not None:
+        total += sys.getsizeof(blk._key_list) + sum(
+            sys.getsizeof(k) for k in blk._key_list)
+    return total
+
+
+def _write_heap_mode(path, codec, heap, monkeypatch):
+    """A file of 4 blocks whose value heaps the codec stores RAW
+    (random printable bytes), or deflated with zstd or zlib."""
+    from pegasus_tpu.storage import block_codec as bc
+
+    rng = np.random.default_rng(7)
+    if heap == "zlib":
+        monkeypatch.setattr(bc._Zstd, "_lib", None)
+        monkeypatch.setattr(bc._Zstd, "_tried", True)
+    old = FLAGS.get("pegasus.storage", "block_codec")
+    FLAGS.set("pegasus.storage", "block_codec", codec)
+    try:
+        w = SSTableWriter(path, block_capacity=256)
+        for i in range(1024):
+            value = (rng.integers(32, 127, size=100, dtype=np.uint8)
+                     .tobytes() if heap == "raw"
+                     else b"value|%05d|" % (i // 7) * 8)
+            w.add(generate_key(b"user%08d" % (i // 10),
+                               b"field%d" % (i % 10)), value,
+                  epoch_now() + 1000 if i % 3 == 0 else 0)
+        w.finish()
+    finally:
+        FLAGS.set("pegasus.storage", "block_codec", old)
+        monkeypatch.undo()
+    if codec != "none":
+        t = SSTable(path)
+        want = {"raw": bc._HEAP_RAW, "zstd": bc._HEAP_ZSTD,
+                "zlib": bc._HEAP_ZLIB}[heap]
+        assert {t.read_block_encoded(i).heap_mode
+                for i in range(len(t.blocks))} == {want}
+        t.close()
+
+
+@pytest.mark.parametrize("mapped", [True, False],
+                         ids=["mmap", "read_copy"])
+@pytest.mark.parametrize("codec,heap", [
+    ("none", "raw"), ("dcz", "raw"), ("dcz", "zstd"),
+    ("dcz2", "raw"), ("dcz2", "zstd"), ("dcz2", "zlib")])
+def test_block_charge_is_what_the_block_owns(tmp_path, monkeypatch,
+                                             codec, heap, mapped):
+    """The cache's charges sum to the bytes its resident blocks
+    allocate, before and after each lazy part is built; a RAW heap over
+    the mmap adds nothing, a read() copy adds the copy."""
+    path = str(tmp_path / "t.sst")
+    _write_heap_mode(path, codec, heap, monkeypatch)
+    if not mapped:
+        def no_mmap(*_a, **_k):
+            raise OSError("no mmap on this filesystem")
+        monkeypatch.setattr(mmap, "mmap", no_mmap)
+    t = SSTable(path)
+    monkeypatch.undo()
+    assert (t._mv is not None) == mapped
+
+    def check():
+        blks = [t.read_block(i) for i in range(len(t.blocks))]
+        assert t._cache_bytes == sum(nb for _b, nb in t._cache.values())
+        for i, blk in enumerate(blks):
+            assert t._cache[i][1] == blk.resident \
+                == BLOCK_OBJECT_BYTES + _owned_reference(blk), (i, codec)
+        return blks
+
+    blks = check()
+    n = blks[0].count
+    heap_len = int(blks[0].value_offs[-1])
+    owned = blks[0].resident - BLOCK_OBJECT_BYTES
+    if not mapped:
+        # every view into the block's bytes pins the one read() copy
+        assert owned >= t.blocks[0].size
+    elif codec == "none":
+        assert owned == 0  # views over the mmap, all of them
+    else:
+        # the rebuilt key matrix and columns; a RAW heap is a view
+        # over the mmap and a deflated one is not inflated yet
+        assert n * blks[0].keys.shape[1] <= owned < heap_len
+    if heap != "raw":
+        assert callable(blks[0]._vh), "deflated heap inflated early"
+    before = blks[0].resident
+    assert blks[0].value_at(3)  # inflates a deflated heap
+    grew = blks[0].resident - before
+    if heap == "raw":
+        assert grew == 0
+    elif mapped:
+        assert grew == heap_len
+    else:
+        # the inflated heap is owned now; the read() copy stays pinned
+        # only while another column still views it
+        assert grew in (heap_len, heap_len - t.blocks[0].size)
+    check()
+    before = blks[1].resident
+    probe_nat(blks[1])
+    assert blks[1].resident == before + 8 * n  # the int64 lengths
+    check()
+    before = blks[2].resident
+    kl = blks[2].key_list()
+    assert blks[2].resident >= before + n * 33 + sum(map(len, kl))
+    check()
     t.close()
 
 

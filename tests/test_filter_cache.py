@@ -8,19 +8,31 @@ NEGATIVE (results must stay byte-identical to the unfiltered path),
 and the row cache may never serve a value a completed write replaced.
 """
 
+import gc
+import os
+import sys
 import threading
+import time
 
 import numpy as np
 import pytest
 
 from pegasus_tpu.base.key_schema import generate_key
+from pegasus_tpu.base.value_schema import generate_value
 from pegasus_tpu.server import PartitionServer
+from pegasus_tpu.server.page import probe_nat
 from pegasus_tpu.server.row_cache import ROW_CACHE, RowCache
 from pegasus_tpu.storage.bloom import BloomFilter
 from pegasus_tpu.storage.lsm import LSMStore
-from pegasus_tpu.storage.sstable import SSTable, SSTableWriter
+from pegasus_tpu.storage.sstable import (
+    BLOCK_OBJECT_BYTES,
+    SSTable,
+    SSTableWriter,
+    block_cache_budget,
+)
 from pegasus_tpu.utils.errors import StorageStatus
 from pegasus_tpu.utils.flags import FLAGS
+from pegasus_tpu.utils.metrics import METRICS
 
 OK = int(StorageStatus.OK)
 NOT_FOUND = int(StorageStatus.NOT_FOUND)
@@ -239,6 +251,152 @@ def test_block_cache_true_lru(tmp_path):
     t.read_block(2)   # must evict block 1, NOT block 0
     assert set(t._cache) == {0, 2}
     t.close()
+
+
+def _ycsb_table(path, blocks):
+    """A file of the benchmark's record shape (ycsb_c.p4r1, PERF.md
+    §4): hashkey user%08d, sortkeys field0..9 (key width 32), values of
+    100 random printable bytes behind the value header; 1,024 rows a
+    block."""
+    rng = np.random.default_rng(11)
+    n = blocks * 1024
+    flat = rng.integers(32, 127, size=n * 100, dtype=np.uint8).tobytes()
+    w = SSTableWriter(path)
+    for i in range(n):
+        w.add(generate_key(b"user%08d" % (i // 10), b"field%d" % (i % 10)),
+              generate_value(1, flat[i * 100:(i + 1) * 100], 0), 0)
+    w.finish()
+    return SSTable(path)
+
+
+def _resident_gauge():
+    return METRICS.entity("storage", "node").gauge(
+        "block_cache_resident_bytes").value()
+
+
+def test_block_cache_holds_a_cell_sized_file(tmp_path):
+    """One L1 file of ycsb_c.p4r1 (245 blocks, ~31 MB on disk) stays
+    resident under the default 32 MiB: a block is charged its key
+    matrix and five columns (n*(32+4+4+4+1) + 4(n+1) + the objects),
+    not the ~105 KB RAW heap it views over the mmap nor a key list and
+    probe table nobody built. Every block is decoded once."""
+    ent = METRICS.entity("storage", "node")
+    t = _ycsb_table(str(tmp_path / "t.sst"), 245)
+    assert len(t.blocks) == 245 and t.codec == "dcz2"
+    gc.collect()  # earlier tests' tables leave the gauge first
+    g0 = _resident_gauge()
+    m0 = ent.counter("block_cache_miss").value()
+    e0 = ent.counter("block_cache_evict_bytes").value()
+    for _round in range(3):
+        for i in range(245):
+            t.read_block(i)
+    assert ent.counter("block_cache_miss").value() - m0 == 245
+    assert ent.counter("block_cache_evict_bytes").value() == e0
+    n = 1024
+    assert t._cache[7][1] == (BLOCK_OBJECT_BYTES + n * (32 + 4 + 4 + 4 + 1)
+                              + 4 * (n + 1))
+    assert len(t._cache) == 245
+    assert t._cache_bytes == 245 * t._cache[7][1] < block_cache_budget() / 2
+    assert _resident_gauge() - g0 == t._cache_bytes
+    t.clear_block_cache()
+    assert _resident_gauge() == g0
+    t.close()
+
+
+def test_block_cache_charge_follows_lazy_parts_and_evicts(tmp_path):
+    """key_list() and the point-probe table are charged when built, at
+    the block's next hit, and the growth evicts from the cold end; the
+    node's gauge moves with every charge and loses a table's when the
+    table is collected."""
+    ent = METRICS.entity("storage", "node")
+    t = _ycsb_table(str(tmp_path / "t.sst"), 4)
+    one = t.read_block(0).resident
+    t.close()
+    del t
+    gc.collect()
+    g0 = _resident_gauge()
+    budget = 4 * one + 4096
+    t = SSTable(str(tmp_path / "t.sst"), cache_bytes=budget)
+    blks = [t.read_block(i) for i in range(4)]
+    assert list(t._cache) == [0, 1, 2, 3]
+    assert t._cache_bytes == 4 * one == _resident_gauge() - g0
+    e0 = ent.counter("block_cache_evict_bytes").value()
+    # the probe table's int64 lengths: 8 KB, over the slack of 4 KB
+    probe_nat(blks[3])
+    assert blks[3].resident == one + 8 * 1024
+    assert t._cache_bytes == 4 * one, "charged before the next hit"
+    assert t.read_block(3) is blks[3]
+    assert list(t._cache) == [1, 2, 3]
+    assert t._cache_bytes == 3 * one + 8 * 1024 == _resident_gauge() - g0
+    assert ent.counter("block_cache_evict_bytes").value() - e0 == one
+    # a key list is larger than a block: it takes another with it
+    blks[2].key_list()
+    assert blks[2].resident > 2 * one
+    t.read_block(2)
+    assert list(t._cache) == [3, 2]
+    assert t._cache_bytes == blks[3].resident + blks[2].resident <= budget
+    assert _resident_gauge() - g0 == t._cache_bytes
+    # a block the cache let go of is not charged again by a late hit
+    blks[0].key_list()
+    t._charge(0, blks[0], fresh=False)
+    assert list(t._cache) == [3, 2]
+    assert _resident_gauge() - g0 == t._cache_bytes
+    t.close()
+    del t, blks
+    gc.collect()
+    assert _resident_gauge() == g0
+
+
+def test_block_cache_accounting_under_threads(tmp_path):
+    """Serving and compaction threads share a table's cache: misses,
+    hits, evictions and lazy builds race on two tables at once, and
+    neither a table's byte count nor the node's gauge may lose an
+    update (a lost one would stay for the process's life)."""
+    a = _ycsb_table(str(tmp_path / "a.sst"), 6)
+    one = a.read_block(0).resident
+    a.close()
+    del a
+    gc.collect()
+    g0 = _resident_gauge()
+    budget = 3 * one + 16384
+    tables = [SSTable(str(tmp_path / "a.sst"), cache_bytes=budget),
+              SSTable(str(tmp_path / "a.sst"), cache_bytes=budget)]
+    deadline = time.monotonic() + 1.5
+    errors = []
+
+    def worker(seed):
+        rng = np.random.default_rng(seed)
+        try:
+            while time.monotonic() < deadline:
+                t = tables[int(rng.integers(2))]
+                blk = t.read_block(int(rng.integers(6)))
+                roll = rng.random()
+                if roll < 0.2:
+                    probe_nat(blk)
+                elif roll < 0.25:
+                    blk.key_list()
+        except BaseException as e:  # noqa: BLE001 - reported below
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=worker, args=(i,))
+                   for i in range(2 * (os.cpu_count() or 4))]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=30)
+    finally:
+        sys.setswitchinterval(old)
+    assert not errors and not any(th.is_alive() for th in threads)
+    for t in tables:
+        assert t._cache_bytes == sum(nb for _b, nb in t._cache.values())
+        # over the budget only by a single block larger than it
+        assert t._cache_bytes <= budget or len(t._cache) == 1
+    assert _resident_gauge() - g0 == sum(t._cache_bytes for t in tables)
+    for t in tables:
+        t.close()
 
 
 # ---- row cache --------------------------------------------------------
