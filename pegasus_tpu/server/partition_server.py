@@ -149,6 +149,13 @@ _OVERLAY_ROWS_WALKED = METRICS.entity(
     "storage", "node").counter("overlay_rows_walked")
 _SCAN_MERGE_PATH_REQUESTS = METRICS.entity(
     "storage", "node").counter("scan_merge_path_requests")
+# the point path's twin: distinct keys a get/multi-get plan resolved
+# (row cache, memtable or base alike) and those of them the memtable
+# answered before any base look-up, one add per plan
+_POINT_KEYS_RESOLVED = METRICS.entity(
+    "storage", "node").counter("point_keys_resolved")
+_POINT_OVERLAY_HITS = METRICS.entity(
+    "storage", "node").counter("point_overlay_hits")
 
 
 
@@ -1312,6 +1319,10 @@ class PartitionServer:
             self._row_cache_hits.increment(rc_hits)
         if rc_misses:
             self._row_cache_misses.increment(rc_misses)
+        if uniq:
+            _POINT_KEYS_RESOLVED.increment(len(uniq))
+        if ov_hits:
+            _POINT_OVERLAY_HITS.increment(ov_hits)
         if ppc is not None:
             # the flush's cost vector, batched like the counters it
             # mirrors: ONE attribute pass per plan, never per key.

@@ -79,6 +79,9 @@ class RowCache:
         self._hit = ent.relaxed_counter("row_cache_hit")
         self._miss = ent.relaxed_counter("row_cache_miss")
         self._evicted = ent.relaxed_counter("row_cache_evict_bytes")
+        # entries a write really dropped, not keys it offered
+        self._invalidated = ent.relaxed_counter(
+            "row_cache_invalidated_rows")
 
     @property
     def capacity(self) -> int:
@@ -227,6 +230,7 @@ class RowCache:
             # which is all the admission check needs)
             self._flush_epoch += 1
             return
+        dropped = 0
         with self._lock:
             self._epochs[gid] = self._epochs.get(gid, 0) + 1
             entries = self._entries
@@ -235,10 +239,13 @@ class RowCache:
                 k = (gid, store_uid, generation, key)
                 ent = entries.pop(k, None)
                 if ent is not None:
+                    dropped += 1
                     self._bytes -= ent[2]
                     if idx is not None:
                         idx.discard(k)
                 self._touch.pop((gid, key), None)
+        if dropped:
+            self._invalidated.increment(dropped)
 
     def invalidate_gid(self, gid) -> None:
         """Wholesale drop for one partition: store publish (compaction
