@@ -102,6 +102,29 @@ def memory_peak_bytes() -> int:
                for d in jax.devices())
 
 
+def workdir_bytes(path: str) -> int:
+    """Bytes of the files under `path`, a store that may be live: the
+    program's compaction threads unlink and rename its files under the
+    store's lock, not under one the harness holds. A file or a
+    directory that is gone by the time it is looked at counts 0, and
+    the walk goes on."""
+    total, pending = 0, [path]
+    while pending:
+        try:
+            with os.scandir(pending.pop()) as entries:
+                for entry in entries:
+                    try:
+                        if entry.is_dir(follow_symlinks=False):
+                            pending.append(entry.path)
+                        else:
+                            total += entry.stat().st_size
+                    except OSError:
+                        pass
+        except OSError:
+            pass
+    return total
+
+
 def layer_metric_specs(cell: str) -> list:
     """Every layer_metrics/*.json whose cells include this one."""
     specs = []
@@ -347,8 +370,7 @@ def run_cell(cell: str, config: dict, traffic: dict, seed: int,
             log(f"WARNING: {compiled_in_window} programs compiled inside the "
                 f"window: the warm-up missed a shape")
         peak = memory_peak_bytes()
-        on_disk = sum(os.path.getsize(os.path.join(d, f))
-                      for d, _dirs, files in os.walk(workdir) for f in files)
+        on_disk = workdir_bytes(workdir)
 
         # ---- what the window received, against the reference -------------
         # the reference table is made here, from the seed, and not

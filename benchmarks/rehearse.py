@@ -1,5 +1,5 @@
 #!/usr/bin/env python3
-"""CPU rehearsal of both cells at a tiny size, before chip time is spent.
+"""CPU rehearsal of every cell at a tiny size, before chip time is spent.
 
     JAX_PLATFORMS=cpu python3 benchmarks/rehearse.py [--fault <name>]
 
@@ -23,6 +23,21 @@ TINY = {"records": 400}
 SECONDS = 2.0
 
 
+def rehearse_cell(name: str, seed: int, trace: bool, fault: str = None,
+                  seconds: float = SECONDS):
+    """One cell of BENCHMARK.json at the rehearsal's size: run_cell's
+    result and the line run.py would print of it."""
+    from benchmarks.harness import run_cell
+    from benchmarks.run import load_cell, result_line
+
+    bench, cell, config, traffic = load_cell(name)
+    traffic = dict(traffic, warmup_windows=8, trace_slice_s=[0.5, 1.0])
+    res = run_cell(name, dict(config, **TINY), traffic, seed, seconds,
+                   trace, time.perf_counter(), fault=fault)
+    return res, result_line(bench, cell, res, trace,
+                            {"platform": "cpu-rehearsal"})
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--fault", default=None)
@@ -30,20 +45,12 @@ def main() -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=1)
     args = ap.parse_args()
 
-    from benchmarks.harness import run_cell
-    from benchmarks.run import load_cell, result_line
-
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         names = [w["name"] for w in json.load(f)["workloads"]]
     ok = True
     for name in names:
-        bench, cell, config, traffic = load_cell(name)
-        traffic = dict(traffic, warmup_windows=8, trace_slice_s=[0.5, 1.0])
-        res = run_cell(name, dict(config, **TINY), traffic, args.seed,
-                       SECONDS, bool(args.trace), time.perf_counter(),
-                       fault=args.fault)
-        line = result_line(bench, cell, res, bool(args.trace),
-                           {"platform": "cpu-rehearsal"})
+        res, line = rehearse_cell(name, args.seed, bool(args.trace),
+                                  args.fault)
         print(f"[rehearse] {name}: correct={res['correct']} "
               f"attempted={res['attempted']} failed={res['failed']} "
               f"metrics reported={sorted(line['metrics'])} "

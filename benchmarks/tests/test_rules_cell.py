@@ -7,10 +7,13 @@ sound and with a planted fault. Run by hand:
     JAX_PLATFORMS=cpu python3 -m pytest benchmarks/tests/test_rules_cell.py -q -p no:cacheprovider
 """
 
+import contextlib
 import json
 import os
+import signal
 import subprocess
 import sys
+import time
 from types import SimpleNamespace
 
 import pytest
@@ -222,7 +225,7 @@ def test_filter_roofline_reader(monkeypatch):
     assert filter_roofline.read(spec, before, {"trace": trace}) is None
 
 
-def test_program_counts_the_bytes_the_reader_states(tmp_path):
+def test_program_counts_the_bytes_the_reader_states(tmp_path, monkeypatch):
     """One replica compacted on each path: `filter_bytes` grows by the
     reader's formula for the programs each path dispatches."""
     from benchmarks.readers.filter_roofline import program_bytes
@@ -242,20 +245,33 @@ def test_program_counts_the_bytes_the_reader_states(tmp_path):
     try:
         server.update_app_envs({"user_specified_compaction":
                                 json.dumps(RULES)})
-        server.engine.write_batch(
-            [WriteBatchItem(OP_PUT, generate_key(b"user%d" % i, b"f9"),
-                            b"v", 0) for i in range(200)],
-            server.engine.last_committed_decree + 1)
-        p0, r0, b0 = counted()
-        server.manual_compact()     # an overlay: the per-record path
-        p1, r1, b1 = counted()
-        assert (p1 - p0, r1 - r0) == (2, 2048)
-        assert b1 - b0 == (program_bytes("rules", 1024, 32)
-                           + program_bytes("ttl", 1024, 32))
-        server.manual_compact()     # pure L1 now: the block path
-        p2, r2, b2 = counted()
-        assert (p2 - p1, r2 - r1) == (1, 4096)
-        assert b2 - b1 == program_bytes("bulk", 4096, 32, want_ets=False)
+
+        def put(lo, hi):
+            server.engine.write_batch(
+                [WriteBatchItem(OP_PUT, generate_key(b"user%d" % i, b"f9"),
+                                b"v", 0) for i in range(lo, hi)],
+                server.engine.last_committed_decree + 1)
+
+        def compacted():
+            before = counted()
+            server.manual_compact()
+            return tuple(a - b for a, b in zip(counted(), before))
+
+        # an overlay, and a pure L1 after it: the block path either way
+        # (since PR 28), one fused program over a 4,096-row bucket
+        put(0, 200)
+        bulk = program_bytes("bulk", 4096, 32, want_ets=False)
+        assert compacted() == (1, 4096, bulk)
+        assert compacted() == (1, 4096, bulk)
+        # the per-record merge, for a store the block path declines:
+        # the rules' program, then the TTL filter's, 1,024 rows each
+        put(200, 300)
+        with monkeypatch.context() as m:
+            m.setattr(server.engine.lsm, "bulk_compact_snapshot",
+                      lambda *a, **kw: None)
+            assert compacted() == (
+                2, 2048, program_bytes("rules", 1024, 32)
+                + program_bytes("ttl", 1024, 32))
         # the read path's static mask, one block and a stack of 16
         from pegasus_tpu.server.scan_coordinator import stacked_block_eval
 
@@ -345,3 +361,74 @@ def test_rehearsal_of_every_cell(fault):
     line = next(ln for ln in out.stderr.splitlines()
                 if ln.startswith("[rehearse] ycsb_e_compact.p64r3"))
     assert f"correct={fault is None}" in line
+
+
+@contextlib.contextmanager
+def _time_limit(seconds):
+    """A test's own time limit: SIGALRM raises in the main thread."""
+    def ring(signum, frame):
+        raise TimeoutError(f"the test ran past {seconds} s")
+
+    old = signal.signal(signal.SIGALRM, ring)
+    signal.alarm(seconds)
+    try:
+        yield
+    finally:
+        signal.alarm(0)
+        signal.signal(signal.SIGALRM, old)
+
+
+@pytest.mark.parametrize("seed", [3_500_000_011, 3_500_000_012, 7])
+def test_a_run_ends_in_a_result_with_the_pool_at_work(seed, monkeypatch):
+    """The stretch between the window's end and run_cell's return,
+    crossed at the rehearsal's size while the pool compacts: the walk of
+    the work directory, the verification, the trace's reduction, every
+    reader, the close and the removal of the directory. The walk's
+    wrapper samples the pool where the harness calls it, just after the
+    window closed; a run of this test in which the pool was idle there
+    has not crossed the stretch that PRs 31, 33 and 34 fell on."""
+    from benchmarks import harness
+    from benchmarks.rehearse import rehearse_cell
+    from pegasus_tpu.storage.compact_governor import MANUAL_COMPACT_POOL
+
+    walks, workdir_bytes = [], harness.workdir_bytes
+
+    def walk(path):
+        with MANUAL_COMPACT_POOL._cv:
+            at_work = (MANUAL_COMPACT_POOL.running,
+                       len(MANUAL_COMPACT_POOL._waiting))
+        n = workdir_bytes(path)
+        walks.append((time.perf_counter(), at_work, n))
+        return n
+
+    monkeypatch.setattr(harness, "workdir_bytes", walk)
+    # the sim's timers, which carry a trigger to the replicas, fire
+    # every 3 s: the rehearsal's 2 s window would close before the
+    # first trigger arrived, so this one lasts 4 s. And 400 records
+    # compact in a few ms a replica, where a pass over the 192 replicas
+    # takes ~100 s at the cell's size: 20 ms before each run make a
+    # pass (3.8 s) outlast what is left of the window
+    submit = MANUAL_COMPACT_POOL.submit
+
+    def slow_submit(owner, fn, name, **kw):
+        submit(owner, lambda: (time.sleep(0.02), fn()), name, **kw)
+
+    monkeypatch.setattr(MANUAL_COMPACT_POOL, "submit", slow_submit)
+    with _time_limit(300):
+        res, line = rehearse_cell("ycsb_e_compact.p64r3", seed, True,
+                                  seconds=4.0)
+    assert res["correct"], line["checks"]
+    assert all(c["value"] == 0 for c in line["checks"].values())
+    assert res["attempted"] > 0
+    (walked_at, (running, waiting), on_disk), = walks
+    print(f"at the walk: {running} compaction(s) running, {waiting} "
+          f"waiting, {on_disk} bytes under the work directory")
+    assert running >= 1, "no compaction ran when the window closed"
+    assert on_disk == res["info"]["workdir_bytes_after_window"] > 0
+    assert any(at > walked_at for at, _took in MANUAL_COMPACT_POOL.history), \
+        "no compaction finished after the walk"
+    assert {"compact_passes_in_window", "compact_rules_dropped_rows",
+            "compact_in_MB_s"} <= set(line["metrics"])
+    # the cluster's close drained the pool: nothing was writing under
+    # the work directory while it was removed
+    assert MANUAL_COMPACT_POOL.wait_idle(0)
