@@ -193,6 +193,7 @@ def _scan_block_predicate(keys, key_len, hashkey_len, expire_ts, valid,
                                              "sort_filter_type",
                                              "validate_hash",
                                              "use_hash_lo", "pack"))
+@jax.named_scope("pegasus_scan_mask")
 def _static_block_predicate(keys, key_len, hashkey_len, valid,
                             hash_pattern, hash_pattern_len,
                             sort_pattern, sort_pattern_len,

@@ -156,6 +156,18 @@ _POINT_KEYS_RESOLVED = METRICS.entity(
     "storage", "node").counter("point_keys_resolved")
 _POINT_OVERLAY_HITS = METRICS.entity(
     "storage", "node").counter("point_overlay_hits")
+# the paging path: scanners whose first page left a context behind
+# (on_get_scanner) and later pages served from a held context
+# (on_scan); and the static-mask cache of both read paths, one
+# look-up a (block, flavor), one add per plan or window of blocks
+_SCAN_CONTEXTS_OPENED = METRICS.entity(
+    "storage", "node").counter("scan_contexts_opened")
+_SCAN_PAGES_SERVED = METRICS.entity(
+    "storage", "node").counter("scan_pages_served")
+_MASK_CACHE_HIT = METRICS.entity(
+    "storage", "node").counter("mask_cache_hit")
+_MASK_CACHE_MISS = METRICS.entity(
+    "storage", "node").counter("mask_cache_miss")
 
 
 
@@ -2287,7 +2299,10 @@ class PartitionServer:
         stop_key = req.stop_key or b""
         if stop_key and req.stop_inclusive:
             stop_key = _after(stop_key)
-        return self._serve_scan_batch(req, start_key, stop_key)
+        resp = self._serve_scan_batch(req, start_key, stop_key)
+        if resp.context_id >= 0:
+            _SCAN_CONTEXTS_OPENED.increment()
+        return resp
 
     def on_scan(self, context_id: int) -> ScanResponse:
         """Parity: on_scan (pegasus_server_impl.cpp:1399)."""
@@ -2302,6 +2317,7 @@ class PartitionServer:
             resp.error = int(StorageStatus.NOT_FOUND)
             resp.context_id = SCAN_CONTEXT_ID_NOT_EXIST
             return resp
+        _SCAN_PAGES_SERVED.increment()
         return self._serve_scan_batch(ctx.request, ctx.resume_key,
                                       ctx.stop_key,
                                       agg_state=ctx.agg_state)
@@ -2852,6 +2868,8 @@ class PartitionServer:
                     keep_masks[ckey] = cached
                     continue
                 misses[ckey] = (run, bm, blk)
+        _MASK_CACHE_HIT.increment(len(keep_masks))
+        _MASK_CACHE_MISS.increment(len(misses))
         pv = self.partition_version
         encoded_resolved = []
         for ckey, (run, bm, blk) in list(misses.items()):
@@ -3515,6 +3533,8 @@ class PartitionServer:
                     keeps[j] = cached
                 else:
                     misses.append((j, ckey, blk))
+        _MASK_CACHE_HIT.increment(len(window) - len(misses))
+        _MASK_CACHE_MISS.increment(len(misses))
         if misses:
             blocks = [((j, ckey), self._device_cached_block(ckey, blk),
                        self.pidx) for j, ckey, blk in misses]
