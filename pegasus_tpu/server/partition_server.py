@@ -18,6 +18,8 @@ layer drives apply with its own decrees.
 
 from __future__ import annotations
 
+import functools
+import itertools
 import threading
 import time
 from collections import OrderedDict
@@ -168,6 +170,14 @@ _MASK_CACHE_HIT = METRICS.entity(
     "storage", "node").counter("mask_cache_hit")
 _MASK_CACHE_MISS = METRICS.entity(
     "storage", "node").counter("mask_cache_miss")
+# blocks evaluated into a stack's padding ahead of a look-ahead window
+# (_static_keep_window's fill), one add a window that fills
+_MASK_FILL_BLOCKS = METRICS.entity(
+    "storage", "node").counter("mask_fill_blocks")
+
+# blocks a ranged read's look-ahead window gathers: the window's mask
+# misses go to the device in one stacked wave
+LOOKAHEAD = 8
 
 
 
@@ -220,6 +230,57 @@ def _lower_bound(blk, key: bytes) -> int:
         else:
             hi = mid
     return lo
+
+
+def _scan_windows(sorted_runs, start_key: bytes, stop_key: Optional[bytes],
+                  limiter: RangeReadLimiter):
+    """The look-ahead windows of a ranged read over the sorted L1 runs,
+    in key order: lists of up to LOOKAHEAD (ckey, blk, lo, hi). A
+    boundary block's rows outside [start_key, stop_key) are trimmed off
+    by (lo, hi), a bisect on its sorted keys, and only in-range rows
+    count against `limiter` (out-of-range rows were never "examined"),
+    as the block enters a window. Fetching one window past the stop
+    point costs unused masks, never correctness. Each window comes with
+    the range's blocks after it (_blocks_after): what a stack's padding
+    may be filled with."""
+
+    def ranged_blocks():
+        for ri, run in enumerate(sorted_runs):
+            if stop_key is not None and (run.first_key or b"") >= stop_key:
+                continue
+            if start_key and (run.last_key or b"") < start_key:
+                continue
+            for bm, blk in run.iter_blocks(start_key, stop_key):
+                yield ri, run, bm, blk
+
+    blocks = ranged_blocks()
+    while True:
+        window = []
+        for ri, run, bm, blk in itertools.islice(blocks, LOOKAHEAD):
+            lo, hi = 0, blk.count
+            if start_key and bm.first_key < start_key:
+                lo = _lower_bound(blk, start_key)
+            if stop_key is not None and bm.last_key >= stop_key:
+                hi = _lower_bound(blk, stop_key)
+            limiter.add_count(hi - lo)
+            window.append(((run.path, bm.offset), blk, lo, hi))
+        if not window:
+            return
+        yield window, _blocks_after(sorted_runs, ri, bm, stop_key)
+
+
+def _blocks_after(sorted_runs, ri: int, bm, stop_key: Optional[bytes]):
+    """(ckey, read) of the blocks after block `bm` of run `ri` that
+    start before `stop_key`, in key order. Lazy, and `read()` decodes
+    the block: a caller pays only for the blocks it takes."""
+    bi = sorted_runs[ri].block_index(bm) + 1
+    for run in sorted_runs[ri:]:
+        for i in range(bi, len(run.blocks)):
+            nxt = run.blocks[i]
+            if stop_key is not None and nxt.first_key >= stop_key:
+                return
+            yield (run.path, nxt.offset), functools.partial(run.read_block, i)
+        bi = 0
 
 
 class PartitionServer:
@@ -1887,6 +1948,7 @@ class PartitionServer:
         with_values: bool = True,
         value_filter=None,
         pd_stats=None,
+        one_page: bool = True,
     ) -> Tuple[List[Tuple[bytes, bytes, int]], bool, Optional[bytes]]:
         """Core ranged read: iterate candidates, device-validate in batches.
 
@@ -1897,7 +1959,9 @@ class PartitionServer:
 
         `value_filter`: normalized (type, pattern) pushdown value
         predicate ANDed into the keep mask; `pd_stats` accumulates its
-        "pruned" count (rows key-alive but value-rejected).
+        "pruned" count (rows key-alive but value-rejected). `one_page`:
+        no later page of this read will follow (a scanner that may page
+        on passes False).
         """
         sorted_runs = None if reverse else self.engine.lsm.sorted_runs()
         if sorted_runs is not None:
@@ -1905,7 +1969,7 @@ class PartitionServer:
                                        now, hash_filter, sort_filter,
                                        validate_hash, limiter, max_records,
                                        max_bytes, with_values,
-                                       value_filter, pd_stats)
+                                       value_filter, pd_stats, one_page)
 
         out: List[Tuple[bytes, bytes, int]] = []
         out_bytes = 0
@@ -1973,6 +2037,7 @@ class PartitionServer:
         with_values: bool,
         value_filter=None,
         pd_stats=None,
+        one_page: bool = True,
     ) -> Tuple[List[Tuple[bytes, bytes, int]], bool, Optional[bytes]]:
         """Fast path: the store is a sequence of non-overlapping sorted L1
         runs with no overlay, so SST blocks stream columnar through the
@@ -1981,10 +2046,11 @@ class PartitionServer:
         (filters + partition-hash, `now`-independent) is evaluated on
         device once per block lifetime; this scan combines it with TTL
         expiry host-side (one vectorized AND over the expire_ts column)
-        and materializes only survivors per record. Runs are visited in
-        key order, skipping runs outside the range; boundary trimming
-        ([start_key, stop_key)) is a host slice of the mask (at most 2
-        partial blocks per scan).
+        and materializes only survivors per record. Blocks come in
+        look-ahead windows (_scan_windows) whose mask misses go to the
+        device in ONE stacked wave (a cold cache after compaction would
+        otherwise pay one serialized round-trip PER block), then
+        assemble host-side.
         """
         from pegasus_tpu.ops.predicates import host_alive_mask
 
@@ -1997,50 +2063,12 @@ class PartitionServer:
             self._register_flavor(validate_hash, filter_key,
                                   time.monotonic())
 
-        def ranged_blocks():
-            for run in sorted_runs:
-                if stop_key is not None and (run.first_key or b"") >= stop_key:
-                    continue
-                if start_key and (run.last_key or b"") < start_key:
-                    continue
-                for bm_blk in run.iter_blocks(start_key, stop_key or None):
-                    yield run, bm_blk
-
-        # look-ahead windows: gather up to LOOKAHEAD blocks, evaluate
-        # every window miss in ONE stacked device wave (a cold cache
-        # after compaction would otherwise pay one serialized round-trip
-        # PER block), then assemble host-side. Fetching one window past
-        # the stop point costs unused masks, never correctness.
-        LOOKAHEAD = 8
-        blocks_iter = ranged_blocks()
-        done_iter = False
-        stopped = False
-        while not stopped:
-            window = []
-            while not done_iter and len(window) < LOOKAHEAD:
-                nxt = next(blocks_iter, None)
-                if nxt is None:
-                    done_iter = True
-                    break
-                run, (bm, blk) = nxt
-                n = blk.count
-                # boundary blocks: trim rows outside the range (bisect on
-                # the block's sorted keys — O(log n) materializations)
-                lo, hi = 0, n
-                if start_key and bm.first_key < start_key:
-                    lo = _lower_bound(blk, start_key)
-                if stop_key is not None and bm.last_key >= stop_key:
-                    hi = _lower_bound(blk, stop_key)
-                # only in-range rows count against the iteration budget
-                # (out-of-range rows in a boundary block were never
-                # "examined")
-                limiter.add_count(hi - lo)
-                window.append(((run.path, bm.offset), blk, lo, hi))
-            if not window:
-                break
-            keeps = self._static_keep_window(window, validate_hash,
-                                             hash_filter, sort_filter,
-                                             filter_key)
+        for window, after in _scan_windows(sorted_runs, start_key,
+                                           stop_key, limiter):
+            keeps = self._static_keep_window(
+                window, validate_hash, filter_key,
+                fill=None if one_page else after)
+            stopped = False
             for (ckey, blk, lo, hi), static_keep in zip(window, keeps):
                 n = blk.count
                 ets = blk.expire_ts
@@ -2079,6 +2107,8 @@ class PartitionServer:
                     exhausted = False
                     stopped = True
                     break
+            if stopped:
+                break
         return out, exhausted, resume_key
 
     def _validate_batch(self, batch: List[Tuple[bytes, bytes, int]],
@@ -2386,7 +2416,7 @@ class PartitionServer:
             limiter=limiter, max_records=batch_size,
             max_bytes=-1 if req.only_return_count else SCAN_BYTES_CAP,
             with_values=not req.no_value and not req.only_return_count,
-            value_filter=vf, pd_stats=pd_stats)
+            value_filter=vf, pd_stats=pd_stats, one_page=req.one_page)
         if tracer is not None:
             tracer.add_point("block_scan")
             if pd is not None:
@@ -2526,39 +2556,12 @@ class PartitionServer:
                         tracer.add_point("assemble")
                     return resp
 
-            def ranged_blocks():
-                for run in sorted_runs:
-                    if stop is not None and (run.first_key or b"") >= stop:
-                        continue
-                    if start_key and (run.last_key or b"") < start_key:
-                        continue
-                    for bm_blk in run.iter_blocks(start_key, stop):
-                        yield run, bm_blk
-
-            LOOKAHEAD = 8
-            blocks_iter = ranged_blocks()
-            done_iter = False
-            stopped = False
-            while not stopped:
-                window = []
-                while not done_iter and len(window) < LOOKAHEAD:
-                    nxt = next(blocks_iter, None)
-                    if nxt is None:
-                        done_iter = True
-                        break
-                    run, (bm, blk) = nxt
-                    lo, hi = 0, blk.count
-                    if start_key and bm.first_key < start_key:
-                        lo = _lower_bound(blk, start_key)
-                    if stop is not None and bm.last_key >= stop:
-                        hi = _lower_bound(blk, stop)
-                    limiter.add_count(hi - lo)
-                    window.append(((run.path, bm.offset), blk, lo, hi))
-                if not window:
-                    break
-                keeps = self._static_keep_window(window, validate,
-                                                 hash_filter, sort_filter,
-                                                 filter_key)
+            for window, after in _scan_windows(sorted_runs, start_key,
+                                               stop, limiter):
+                keeps = self._static_keep_window(
+                    window, validate, filter_key,
+                    fill=None if req.one_page else after)
+                stopped = False
                 for (ckey, blk, lo, hi), static_keep in zip(window,
                                                             keeps):
                     n = blk.count
@@ -2588,6 +2591,8 @@ class PartitionServer:
                         exhausted = False
                         stopped = True
                         break
+                if stopped:
+                    break
         else:
             # overlay / reverse-free generic arm: the iterator merge
             # already applies newest-wins shadowing and tombstones, so
@@ -3509,17 +3514,27 @@ class PartitionServer:
                                       self.partition_version,
                                       filter_key=filter_key)
 
-    def _static_keep_window(self, window, validate: bool,
-                            hash_filter: FilterSpec,
-                            sort_filter: FilterSpec,
-                            filter_key) -> list:
+    def _static_keep_window(self, window, validate: bool, filter_key,
+                            fill=None) -> list:
         """Cached static keep masks for a window of blocks (solo-path
         form): filter match + partition-hash validation,
         `now`-independent. Window misses are evaluated in ONE stacked
         device wave — one round-trip per window instead of per block —
         and cached for every later scan to combine with TTL host-side.
-        `window`: [(ckey, blk, lo, hi)]; returns masks aligned to it."""
-        from pegasus_tpu.server.scan_coordinator import stacked_block_eval
+        `window`: [(ckey, blk, lo, hi)]; returns masks aligned to it.
+
+        `fill`: the range's blocks after the window (_blocks_after), or
+        None where the read will not page on (one_page). A window that
+        misses tops its misses up to a whole number of stacks with the
+        next of those blocks whose mask is not cached: a stack is
+        STACK_CHUNK blocks whatever it holds, so their masks cost the
+        device nothing more, and the later pages find them as hits
+        where they would each have paid a program of their own. Their
+        masks are published, not returned, and are no look-ups."""
+        from pegasus_tpu.server.scan_coordinator import (
+            STACK_CHUNK,
+            stacked_block_eval,
+        )
 
         pv = self.partition_version
         keeps: list = [None] * len(window)
@@ -3535,19 +3550,49 @@ class PartitionServer:
                     misses.append((j, ckey, blk))
         _MASK_CACHE_HIT.increment(len(window) - len(misses))
         _MASK_CACHE_MISS.increment(len(misses))
-        if misses:
-            blocks = [((j, ckey), self._device_cached_block(ckey, blk),
-                       self.pidx) for j, ckey, blk in misses]
-            for (j, ckey), keep in stacked_block_eval(
-                    blocks, validate, pv, filter_key=filter_key):
-                keep = np.asarray(keep)
+        if not misses:
+            return keeps
+        blocks = [((j, ckey), self._device_cached_block(ckey, blk),
+                   self.pidx) for j, ckey, blk in misses]
+        if fill is not None and len(blocks) % STACK_CHUNK:
+            filled = self._fill_blocks(fill, -len(blocks) % STACK_CHUNK,
+                                       blocks[-1][1].keys.shape, validate,
+                                       filter_key, pv)
+            _MASK_FILL_BLOCKS.increment(len(filled))
+            blocks += filled
+        for (j, ckey), keep in stacked_block_eval(
+                blocks, validate, pv, filter_key=filter_key):
+            keep = np.asarray(keep)
+            if j is not None:
                 keeps[j] = keep
-                self.store_mask_for(ckey, validate, filter_key, keep,
-                                    computed_pv=pv)
+            self.store_mask_for(ckey, validate, filter_key, keep,
+                                computed_pv=pv)
         return keeps
 
+    def _fill_blocks(self, fill, need: int, shape, validate: bool,
+                     filter_key, pv: int) -> list:
+        """Stacker entries ((None, ckey), device block, pidx) for up to
+        `need` of `fill`'s blocks whose mask for this flavor is not
+        cached. Stops at the first block of another shape than
+        `shape`: it would take a program of its own."""
+        out = []
+        for ckey, read in fill:
+            if len(out) == need:
+                break
+            # GIL-atomic membership test: a racing store at worst
+            # evaluates one mask twice
+            if (ckey, pv, validate, filter_key) in self._mask_cache:
+                continue
+            dev = self._device_cached_block(ckey, read)
+            if dev.keys.shape != shape:
+                break
+            out.append(((None, ckey), dev, self.pidx))
+        return out
+
     def _device_cached_block(self, cache_key, blk):
-        """The shared device-upload cache used by both scan paths."""
+        """The shared device-upload cache used by both scan paths.
+        `blk`: the decoded block, or a function that reads it, called
+        only when the block is not on the device yet."""
         import jax.numpy as jnp
 
         from pegasus_tpu.ops.record_block import RecordBlock, block_from_columns
@@ -3558,6 +3603,8 @@ class PartitionServer:
             if dev_block is not None:
                 self._device_block_cache.move_to_end(cache_key)
                 return dev_block
+        if callable(blk):
+            blk = blk()
         # upload outside the lock (serving and the prefresher may race
         # to a duplicate upload of the same block — harmless, last wins)
         n = blk.count

@@ -26,6 +26,7 @@ from benchmarks.reference_prefix import (n_tenants, prefix_of, prefix_rows,
 from pegasus_tpu.base.key_schema import generate_key, key_hash_parts
 from pegasus_tpu.client.client import ScanOptions
 from pegasus_tpu.ops.predicates import FT_MATCH_PREFIX
+from pegasus_tpu.server.scan_coordinator import STACK_CHUNK
 from pegasus_tpu.server.types import GetScannerRequest
 from pegasus_tpu.utils import tracing
 from pegasus_tpu.utils.flags import FLAGS
@@ -354,6 +355,10 @@ def test_paging_counters_count_contexts_and_pages(loaded, monkeypatch):
 def test_mask_cache_counters_count_hits_and_misses(loaded):
     cluster, model = loaded
     n_blocks = _n_blocks(cluster)
+    # a partition of b blocks: at most ceil(b / STACK_CHUNK) programs
+    most_programs = sum(
+        -(-sum(len(run.blocks) for run in r.server.engine.lsm.l1_runs)
+          // STACK_CHUNK) for r in cluster.primary_of)
     fresh, other = _args(prefix_of(20, WIDTH)), _args(prefix_of(21, WIDTH))
 
     def moved(args):
@@ -362,18 +367,19 @@ def test_mask_cache_counters_count_hits_and_misses(loaded):
                                  epoch_now()) is None
         d = _delta("storage", before)
         return (d.get("mask_cache_hit", 0), d.get("mask_cache_miss", 0),
+                d.get("mask_fill_blocks", 0),
                 _delta("engine", programs).get("mask_programs", 0))
 
-    hit1, miss1, programs1 = moved(fresh)
-    # a fresh pattern: every block's mask is computed once, by a
-    # program over one block or over a look-ahead window of them, and
+    hit1, miss1, fill1, programs1 = moved(fresh)
+    # a fresh pattern: every block's mask is computed once, a window's
+    # misses and the blocks that fill their stack in one program, and
     # looked up again by each later page whose window still holds it
-    assert miss1 == n_blocks and hit1 > 0
-    assert PARTS <= programs1 <= n_blocks
+    assert miss1 + fill1 == n_blocks and hit1 > 0
+    assert PARTS <= programs1 <= most_programs
     # the same pattern again: the same look-ups, every one a hit
-    assert moved(fresh) == (hit1 + miss1, 0, 0)
+    assert moved(fresh) == (hit1 + miss1, 0, 0, 0)
     # another fresh pattern misses as the first did
-    assert moved(other) == (hit1, miss1, programs1)
+    assert moved(other) == (hit1, miss1, fill1, programs1)
 
 
 def test_batched_scan_path_counts_its_mask_lookups_too(loaded):
