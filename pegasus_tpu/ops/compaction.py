@@ -41,6 +41,16 @@ _PROGRAM_COUNTERS = {
     kind: tuple(_FILTER_ENT.counter(f"{kind}_{what}") for what in (
         "programs", "rows", "bytes", "programs_traced", "bytes_traced"))
     for kind in ("filter", "mask")}
+# a serving wave of mask programs in its three steps, host time in
+# whole microseconds, always on (scan_coordinator._WaveClock)
+_MASK_STEP_US = tuple(_FILTER_ENT.counter(f"mask_{step}_us")
+                      for step in ("stack", "launch", "fetch"))
+
+
+def note_mask_steps(stack_ns: int, launch_ns: int, fetch_ns: int) -> None:
+    """One wave's three steps, host nanoseconds each."""
+    for counter, ns in zip(_MASK_STEP_US, (stack_ns, launch_ns, fetch_ns)):
+        counter.increment(ns // 1000)
 
 
 def note_filter_program(rows: int, nbytes: int,

@@ -33,12 +33,13 @@ device round-trips.
 from __future__ import annotations
 
 import logging
+import time
 from collections import OrderedDict
 from typing import Dict, List, Tuple
 
 import numpy as np
 
-from pegasus_tpu.ops.compaction import note_filter_program
+from pegasus_tpu.ops.compaction import note_filter_program, note_mask_steps
 from pegasus_tpu.ops.predicates import (
     FT_NO_FILTER,
     FilterSpec,
@@ -200,8 +201,6 @@ def stacked_block_eval(blocks, validate: bool, pv: int,
     ambient PerfContext (when an op is being tracked) records the
     verdict + predicted/measured kernel ms.
     """
-    import time as _time
-
     blocks = list(blocks)
     if not blocks:
         return
@@ -223,12 +222,13 @@ def stacked_block_eval(blocks, validate: bool, pv: int,
     # from the call of the jitted predicate programs to their masks on
     # the host
     with tracing.layer("dispatch.wave"):
-        t0 = _time.perf_counter()
+        clock = _WaveClock()
         submitted = list(stacked_block_submit(blocks, validate, pv,
-                                              filter_key))
-        fetched = _fetch_wave([o[2] for o in submitted])
-        measured_s = _time.perf_counter() - t0
-    _audit_kernel_wave(blocks, filter_key, measured_s, perf_ctxs)
+                                              filter_key, clock))
+        with tracing.layer("dispatch.fetch"):
+            fetched = _fetch_wave([o[2] for o in submitted])
+        clock.lap(FETCH)
+    _audit_kernel_wave(blocks, filter_key, clock.close(), perf_ctxs)
     for (group, cap, _dev), packed in zip(submitted, fetched):
         keep_all = unpack_masks(packed, len(group) * cap)
         if len(group) == 1:
@@ -275,7 +275,7 @@ def _audit_kernel_wave(blocks, filter_key, measured_s: float,
 
 
 def stacked_block_submit(blocks, validate: bool, pv: int,
-                         filter_key=None):
+                         filter_key=None, clock=None):
     """Phase 1: dispatch predicate programs WITHOUT waiting. Yields
     (group, cap, packed_keep_device_array). Buckets by (key width,
     capacity) so differently-capped tail blocks can never misalign mask
@@ -283,28 +283,63 @@ def stacked_block_submit(blocks, validate: bool, pv: int,
     width ([cap, W] and [STACK_CHUNK*cap, W]) — variable stack sizes
     made every batch a fresh XLA compile. A stack mixing hash_lo and
     non-hash_lo blocks drops the precomputed column (the kernel computes
-    the hash on device instead)."""
+    the hash on device instead). `clock`: the wave's _WaveClock, which
+    times each chunk's stacking and launch."""
     hft, hfp, sft, sfp = filter_key or (FT_NO_FILTER, b"",
                                         FT_NO_FILTER, b"")
     hash_f = FilterSpec.make(hft, hfp)
     sort_f = FilterSpec.make(sft, sfp)
     for group, cap, stacked, pidx in _stacked_chunks(blocks):
-        keep = static_block_predicate(
-            stacked, hash_filter=hash_f, sort_filter=sort_f,
-            validate_hash=validate, pidx=pidx, partition_version=pv,
-            pack=True)
-        # key matrix + key_len, hashkey_len (4 B each) + valid, the
-        # hash column where it is used, the packed mask back; a stack
-        # also reads a pidx column, after its six columns were
-        # gathered on the device (read and written once)
-        rows, width = stacked.keys.shape
-        columns = width + 9 + (
-            4 if validate and stacked.hash_lo is not None else 0)
-        note_filter_program(
-            rows, rows * columns + rows // 8
-            + (rows * (4 + 2 * (width + 17)) if len(group) > 1 else 0),
-            kind="mask")
+        if clock is not None:
+            clock.lap(STACK)
+        with tracing.layer("dispatch.launch"):
+            keep = static_block_predicate(
+                stacked, hash_filter=hash_f, sort_filter=sort_f,
+                validate_hash=validate, pidx=pidx, partition_version=pv,
+                pack=True)
+            # key matrix + key_len, hashkey_len (4 B each) + valid, the
+            # hash column where it is used, the packed mask back; a
+            # stack also reads a pidx column, after its six columns
+            # were gathered on the device (read and written once)
+            rows, width = stacked.keys.shape
+            columns = width + 9 + (
+                4 if validate and stacked.hash_lo is not None else 0)
+            note_filter_program(
+                rows, rows * columns + rows // 8
+                + (rows * (4 + 2 * (width + 17)) if len(group) > 1 else 0),
+                kind="mask")
+        if clock is not None:
+            clock.lap(LAUNCH)
         yield group, cap, keep
+
+
+STACK, LAUNCH, FETCH = range(3)
+
+
+class _WaveClock:
+    """A wave of mask programs in its three steps on the host's clock:
+    STACK (a chunk's blocks concatenated on the device), LAUNCH (the
+    jitted call, which uploads a stack's pidx column and queues the
+    program) and FETCH (the wait for the wave's packed masks and their
+    copy to the host). Each step boundary is one clock read that ends
+    one step and starts the next, so the steps add up to the wave's
+    wall; `close` counts them (`engine`/`mask_{stack,launch,fetch}_us`)
+    and returns that wall, which the drift audit takes."""
+
+    __slots__ = ("at", "ns")
+
+    def __init__(self) -> None:
+        self.ns = [0, 0, 0]
+        self.at = time.perf_counter_ns()
+
+    def lap(self, step: int) -> None:
+        now = time.perf_counter_ns()
+        self.ns[step] += now - self.at
+        self.at = now
+
+    def close(self) -> float:
+        note_mask_steps(*self.ns)
+        return sum(self.ns) / 1e9
 
 
 STACK_CHUNK = 16
@@ -334,19 +369,23 @@ def _stacked_chunks(blocks):
                 yield chunk, cap, dev, pidx
                 continue
             padded = chunk + [chunk[0]] * (STACK_CHUNK - len(chunk))
-            pidx_col = np.concatenate([
-                np.full(cap, pidx, dtype=np.uint32)
-                for _t, _d, pidx in padded])
-            all_hash_lo = all(d.hash_lo is not None
-                              for _t, d, _p in padded)
-            stacked = RecordBlock(
-                jnp.concatenate([d.keys for _t, d, _p in padded]),
-                jnp.concatenate([d.key_len for _t, d, _p in padded]),
-                jnp.concatenate([d.hashkey_len for _t, d, _p in padded]),
-                jnp.concatenate([d.expire_ts for _t, d, _p in padded]),
-                jnp.concatenate([d.valid for _t, d, _p in padded]),
-                (jnp.concatenate([d.hash_lo for _t, d, _p in padded])
-                 if all_hash_lo else None))
+            with tracing.layer("dispatch.stack"):
+                pidx_col = np.concatenate([
+                    np.full(cap, pidx, dtype=np.uint32)
+                    for _t, _d, pidx in padded])
+                all_hash_lo = all(d.hash_lo is not None
+                                  for _t, d, _p in padded)
+                stacked = RecordBlock(
+                    jnp.concatenate([d.keys for _t, d, _p in padded]),
+                    jnp.concatenate([d.key_len for _t, d, _p in padded]),
+                    jnp.concatenate([d.hashkey_len
+                                     for _t, d, _p in padded]),
+                    jnp.concatenate([d.expire_ts for _t, d, _p in padded]),
+                    jnp.concatenate([d.valid for _t, d, _p in padded]),
+                    (jnp.concatenate([d.hash_lo for _t, d, _p in padded])
+                     if all_hash_lo else None))
+            # the scope closes before the yield: the consumer's frames
+            # are not the stack's children
             yield chunk, cap, stacked, pidx_col
 
 
@@ -436,16 +475,19 @@ def _eval_cross_partition_multi(flavors: dict, validate: bool,
 
     blocks = [((server, ckey), dev, server.pidx)
               for server, ckey, dev in union.values()]
-    import time as _time
-
-    t0 = _time.perf_counter()
     submitted = []
     with tracing.layer("dispatch.wave"):
+        clock = _WaveClock()
         for group, cap, stacked, pidx in _stacked_chunks(blocks):
-            packed = multi_static_block_predicate_submit(
-                stacked, specs, validate, pidx, pv)
+            clock.lap(STACK)
+            with tracing.layer("dispatch.launch"):
+                packed = multi_static_block_predicate_submit(
+                    stacked, specs, validate, pidx, pv)
+            clock.lap(LAUNCH)
             submitted.append((group, cap, packed))
-        fetched = _fetch_wave([p for _g, _c, p in submitted])
+        with tracing.layer("dispatch.fetch"):
+            fetched = _fetch_wave([p for _g, _c, p in submitted])
+        clock.lap(FETCH)
     # the multi-flavor wave audits like the single-flavor one: any
     # filtered flavor makes it the "rules" class (its compute bound)
     audit_fkey = next(
@@ -453,7 +495,7 @@ def _eval_cross_partition_multi(flavors: dict, validate: bool,
          if fk[0] != FT_NO_FILTER or fk[2] != FT_NO_FILTER),
         fkeys[0])
     _audit_kernel_wave(
-        blocks, audit_fkey, _time.perf_counter() - t0,
+        blocks, audit_fkey, clock.close(),
         _state_perf_ctxs(st for states in wanted.values()
                          for st in states))
     for (group, cap, _p), packed in zip(submitted, fetched):
@@ -564,9 +606,7 @@ class MaskPrefresher:
         (`now` accepted for back-compat; static masks don't depend on
         it.) Flavors sharing filter types and pattern widths warm in
         one multi-flavor program per stack chunk."""
-        import time as _time
-
-        wall = _time.monotonic()
+        wall = time.monotonic()
         warmed = 0
         groups: Dict[tuple, dict] = {}
         for srv in self.servers:

@@ -190,6 +190,13 @@ LAYER_OF = {
     "block_probe": "decode", "block_scan": "decode", "decode": "decode",
     # device predicate programs: call to mask on the host
     "dispatch": "dispatch", "pushdown": "dispatch",
+    # the steps of a wave of mask programs (dispatch.wave,
+    # server/scan_coordinator.py): a chunk's blocks and pidx column
+    # concatenated into one stack; the jitted call, which uploads the
+    # pidx column and queues the program; the wait for the wave's
+    # packed masks and their copy to the host
+    "dispatch.stack": "dispatch", "dispatch.launch": "dispatch",
+    "dispatch.fetch": "dispatch",
     # replica/: 2PC, plog, group commit
     "repl": "repl", "2pc": "repl", "prepare": "repl", "ack": "repl",
     "replica": "repl", "prepare_local": "repl", "append_plog": "repl",
