@@ -11,7 +11,7 @@ Parity with the reference's per-record scalar loop:
 
 Filter types are *static* arguments: each of the four types compiles to its
 own XLA program (4 variants max per shape bucket), so FT_NO_FILTER costs
-nothing and PREFIX doesn't pay for the ANYWHERE sliding window.
+nothing and each matching type compiles only its own start selector.
 """
 
 from __future__ import annotations
@@ -100,41 +100,34 @@ def match_filter(keys: jax.Array, region_start: jax.Array,
 
     keys uint8[B, K]; region_start/region_len int32[B] (region within the
     padded key row); pattern uint8[P]; pattern_len int32; filter_type static.
+
+    No gather (TPUs run one a scalar at a time): `window_ok[b, t]`, "the
+    pattern matches from byte t of row b", is P static shifted compares
+    of the zero-padded rows, AND-accumulated — O(B*K) memory per step
+    instead of materializing B*K*P windows. The three types differ only
+    in which starts they admit, picked by an iota compare: PREFIX the
+    region's first byte, POSTFIX the start that ends at the region's
+    end, ANYWHERE every start from the one to the other. A region that
+    runs past the row (a header the writer never produces) reads zeros
+    there.
     """
     b, k = keys.shape
     if filter_type == FT_NO_FILTER:
         return jnp.ones((b,), dtype=bool)
 
     p = pattern.shape[0]
-    jp = jnp.arange(p, dtype=jnp.int32)
-    pat_mask = jp < pattern_len                      # bool[P]
-    empty = pattern_len == 0
-    fits = region_len >= pattern_len                 # bool[B]
-
-    if filter_type in (FT_MATCH_PREFIX, FT_MATCH_POSTFIX):
-        if filter_type == FT_MATCH_PREFIX:
-            offs = region_start
-        else:
-            offs = region_start + region_len - pattern_len
-        idx = jnp.clip(offs[:, None] + jp[None, :], 0, k - 1)
-        window = jnp.take_along_axis(keys, idx, axis=1)        # uint8[B, P]
-        eq = (window == pattern[None, :]) | ~pat_mask[None, :]
-        return (eq.all(axis=1) & fits) | empty
-
-    # FT_MATCH_ANYWHERE: AND-accumulate shifted byte compares — O(B*K)
-    # memory per step instead of materializing B*K*P windows. `t` indexes
-    # absolute window-start positions within the padded row; a window is a
-    # real candidate iff it lies inside [region_start, region_start +
-    # region_len - pattern_len].
+    last_start = region_start + region_len - pattern_len
+    lo = last_start if filter_type == FT_MATCH_POSTFIX else region_start
+    hi = region_start if filter_type == FT_MATCH_PREFIX else last_start
     padded = jnp.pad(keys, ((0, 0), (0, p)))
     window_ok = jnp.ones((b, k), dtype=bool)
     for j in range(p):  # static unroll over the pattern buffer; XLA fuses
         cmp = (padded[:, j:j + k] == pattern[j]) | (j >= pattern_len)
         window_ok = window_ok & cmp
-    t = jnp.arange(k, dtype=jnp.int32)
-    t_ok = ((t[None, :] >= region_start[:, None]) &
-            (t[None, :] <= (region_start + region_len - pattern_len)[:, None]))
-    return (jnp.any(window_ok & t_ok, axis=1) & fits) | empty
+    t = jnp.arange(k, dtype=jnp.int32)[None, :]
+    t_ok = (t >= lo[:, None]) & (t <= hi[:, None])
+    fits = region_len >= pattern_len
+    return (jnp.any(window_ok & t_ok, axis=1) & fits) | (pattern_len == 0)
 
 
 def ttl_expired(expire_ts: jax.Array, now: jax.Array) -> jax.Array:

@@ -131,7 +131,7 @@ def region_filter_ranges(heap, starts: np.ndarray, ends: np.ndarray,
     that do NOT tile the heap contiguously (value regions skip the
     stored header). One vectorized AND-of-shifted-compares pass marks
     every heap position where the pattern starts (the numpy analogue of
-    match_filter's ANYWHERE accumulation), then each region answers from
+    match_filter's shifted-compare accumulation), then each region answers from
     endpoint gathers (PREFIX/POSTFIX) or a hit-count prefix sum
     (ANYWHERE). Device-kernel semantics: empty pattern matches
     everything; a region shorter than the pattern never matches.
