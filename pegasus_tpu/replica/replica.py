@@ -40,7 +40,6 @@ from typing import Any, Callable, Dict, List, Optional, Set, Tuple
 from pegasus_tpu.base.value_schema import PEGASUS_EPOCH_BEGIN
 from pegasus_tpu.replica.mutation import (
     ATOMIC_OPS,
-    BATCHABLE_OPS,
     Mutation,
     WriteOp,
 )
@@ -64,7 +63,8 @@ from pegasus_tpu.rpc.codec import (
     OP_REMOVE,
 )
 from pegasus_tpu.server.partition_server import PartitionServer
-from pegasus_tpu.utils.errors import ErrorCode
+from pegasus_tpu.utils.errors import ErrorCode, StorageStatus
+from pegasus_tpu.utils.metrics import METRICS
 from pegasus_tpu.utils.thread_check import SerialAccessChecker
 
 
@@ -82,10 +82,21 @@ def _serial(fn):
 
 PREPARE_LIST_CAPACITY = 1024
 
+# the read-modify-write ops (incr, check_and_set, check_and_mutate) of
+# every primary of the process: those applied, those whose check failed
+# (TRY_AGAIN), those that waited in the write queue behind the 2PC
+# window, and those refused with ReplicaBusyError
+_WRITE = METRICS.entity("write", "node")
+_ATOMIC_APPLIED = _WRITE.counter("atomic_ops")
+_ATOMIC_CHECK_FAILED = _WRITE.counter("atomic_check_failed")
+_ATOMIC_QUEUED = _WRITE.counter("atomic_queued")
+_ATOMIC_BUSY_REJECTS = _WRITE.counter("atomic_busy_rejects")
+_TRY_AGAIN = int(StorageStatus.TRY_AGAIN)
+
 
 class ReplicaBusyError(RuntimeError):
-    """Write-queue overload: the mutation queue is full, or a
-    non-batchable op is stuck behind an in-flight round. RETRYABLE —
+    """Write-queue overload: the mutation queue is full, or an atomic
+    op of a duplicated table is stuck behind an in-flight round. RETRYABLE —
     the stub maps it to ERR_BUSY so the client's backoff machinery
     handles write overload exactly like read shedding (never
     ERR_INVALID_STATE, which would burn a config refresh per retry)."""
@@ -184,10 +195,13 @@ class Replica:
         # (the logged dup-puts apply as ints; the client wants the
         # original atomic op's response object)
         self._idempotent_responses: Dict[int, List[Any]] = {}
-        # the mutation-queue batch: (op_count, callback) spans + the ops
-        # accumulated while a 2PC round is in flight
+        # the mutation queue: (op_count, callback) spans + the ops
+        # accumulated while a 2PC round is in flight, in submission
+        # order; each queued atomic op is a mutation of its own, and the
+        # batchable spans between two of them one mutation
         self._write_queue: List[Tuple[int, Optional[Callable]]] = []
         self._queued_ops: List[WriteOp] = []
+        self._queued_atomic = 0     # of them, atomic ops
         # per-mutation latency tracers (parity: every mutation carries a
         # latency_tracer, replica_2pc.cpp:338-359; slow dumps via
         # dump_trace_points). Write traces share the server's slow log so
@@ -310,6 +324,7 @@ class Replica:
         # queued writes die unacked with the primaryship (clients retry)
         self._write_queue.clear()
         self._queued_ops.clear()
+        self._queued_atomic = 0
         self._idempotent_responses.clear()
         self._learners.clear()
         # learn snapshots for in-flight learners die with the primaryship
@@ -372,7 +387,8 @@ class Replica:
     # writes queued while a 2PC round is in flight coalesce into ONE
     # following mutation (parity: mutation_queue batching — requests with
     # rpc_request_is_write_allow_batch join the pending mutation,
-    # mutation.cpp:390,553; the queue drains when the window moves)
+    # mutation.cpp:390,553; the queue drains when the window moves).
+    # Also the bound of the queue: ops of either kind waiting in it
     MAX_BATCH_OPS = 128
     # in-flight 2PC rounds allowed before writes start coalescing (the
     # bounded-staleness pipelining window)
@@ -387,7 +403,8 @@ class Replica:
         round), or raises on gate failure."""
         if self.status != PartitionStatus.PRIMARY:
             raise RuntimeError(f"{self.name}: not primary")
-        if any(wo.op in ATOMIC_OPS for wo in ops) and len(ops) > 1:
+        atomic = any(wo.op in ATOMIC_OPS for wo in ops)
+        if atomic and len(ops) > 1:
             raise ValueError("atomic ops cannot batch with other writes")
         if self.write_metrics is not None:
             if self._queue_depth_metric is None:
@@ -398,29 +415,58 @@ class Replica:
                 or len(self._pending_acks) >= self.PIPELINE_DEPTH):
             # the window is at its pipelining depth (or earlier writes
             # already queued — a later write must NOT overtake them, or
-            # two puts to one key could apply in reversed order):
-            # coalesce batchable writes into the NEXT mutation (bounded
-            # staleness, replica_2pc.cpp:366); non-batchable ones and a
-            # full batch busy-reject for a client retry
-            if (all(wo.op in BATCHABLE_OPS for wo in ops)
-                    and sum(n for n, _cb in self._write_queue)
-                    + len(ops) <= self.MAX_BATCH_OPS):
+            # two puts to one key could apply in reversed order): queue
+            # behind them, in submission order. Batchable writes
+            # coalesce into the NEXT mutation (bounded staleness,
+            # replica_2pc.cpp:366); an atomic op waits as a mutation of
+            # its own (parity: mutation_queue::add_work, where only
+            # allow_batch requests join the pending mutation), and a
+            # batchable write behind it starts a batch after it. Past
+            # MAX_BATCH_OPS queued ops of its kind, or for an atomic op
+            # of a table that must translate it against a drained
+            # window (below), busy-reject for a client retry
+            if atomic:
+                room = (self._queued_atomic < self.MAX_BATCH_OPS
+                        and not self._needs_idempotent(ops))
+            else:
+                room = (sum(n for n, _cb in self._write_queue)
+                        - self._queued_atomic
+                        + len(ops) <= self.MAX_BATCH_OPS)
+            if room:
                 self._write_queue.append((len(ops), callback))
                 self._queued_ops.extend(ops)
+                if atomic:
+                    self._queued_atomic += 1
+                    _ATOMIC_QUEUED.increment()
                 return -1
+            if atomic:
+                _ATOMIC_BUSY_REJECTS.increment()
             raise ReplicaBusyError(
                 f"{self.name}: write queue busy (retry)")
+        try:
+            return self._init_prepare(ops, callback)
+        except ReplicaBusyError:
+            _ATOMIC_BUSY_REJECTS.increment()    # only atomic ops raise it
+            raise
+
+    def _needs_idempotent(self, ops: List[WriteOp]) -> bool:
+        """Whether these ops ship as the concrete puts they resolve to
+        (parity: make_idempotent): atomic ops of a duplicated table, or
+        of one whose app-env forces the translation (the
+        atomic-idempotent toggle, enable/disable/get_atomic_idempotent)."""
+        return ((bool(self.duplicators) or self.server.app_envs.get(
+                    "replica.atomic_idempotent") == "true")
+                and any(wo.op in (OP_INCR, OP_CAS, OP_CAM) for wo in ops))
+
+    def _init_prepare(self, ops: List[WriteOp],
+                      callback: Optional[Callable[[List[Any]], None]]
+                      ) -> int:
+        """One mutation of `ops` into the 2PC window: decree, timestamp,
+        plog append, prepares after the plog is durable."""
         decree = self.last_prepared_decree() + 1
         ts = max(int(self.clock() * 1_000_000), self._last_timestamp_us + 1)
         idem_responses = None
-        # forced translation (parity: the atomic-idempotent toggle,
-        # enable/disable/get_atomic_idempotent): the app-env makes atomic
-        # ops ship as concrete puts even without active duplication
-        force_idem = (self.server.app_envs.get(
-            "replica.atomic_idempotent") == "true")
-        if ((self.duplicators or force_idem)
-                and any(wo.op in (OP_INCR, OP_CAS, OP_CAM)
-                        for wo in ops)):
+        if self._needs_idempotent(ops):
             # idempotent translation (parity: make_idempotent,
             # replica_2pc.cpp:283 + idempotent_writer.h): a duplicated
             # table must log atomic ops as the CONCRETE puts they
@@ -655,24 +701,47 @@ class Replica:
         self._drain_write_queue()
 
     def _drain_write_queue(self) -> None:
-        """The round finished: ship everything queued behind it as ONE
-        mutation whose responses split back per original request."""
+        """The window drained: ship what is queued behind it, in order,
+        up to the pipelining depth. The head of the queue is one
+        mutation: an atomic op alone, or every batchable write before
+        the first queued atomic op, whose responses split back per
+        original request."""
         if (not self._write_queue or self._pending_acks
                 or self.status != PartitionStatus.PRIMARY):
             return
-        spans = self._write_queue
-        ops = self._queued_ops
-        self._write_queue = []
-        self._queued_ops = []
+        while (self._write_queue
+               and len(self._pending_acks) < self.PIPELINE_DEPTH):
+            n_spans = n_ops = n_atomic = 0
+            for n, _cb in self._write_queue:
+                if self._queued_ops[n_ops].op in ATOMIC_OPS:
+                    if not n_spans:
+                        n_spans, n_ops, n_atomic = 1, n, 1
+                    break
+                n_spans += 1
+                n_ops += n
+            spans = self._write_queue[:n_spans]
+            ops = self._queued_ops[:n_ops]
+            del self._write_queue[:n_spans]
+            del self._queued_ops[:n_ops]
+            self._queued_atomic -= n_atomic
 
-        def split_responses(responses: List[Any]) -> None:
-            off = 0
-            for n, cb in spans:
-                if cb is not None:
-                    cb(responses[off:off + n])
-                off += n
+            def split_responses(responses: List[Any], spans=spans) -> None:
+                off = 0
+                for n, cb in spans:
+                    if cb is not None:
+                        cb(responses[off:off + n])
+                    off += n
 
-        self.client_write(ops, split_responses)
+            try:
+                self._init_prepare(ops, split_responses)
+            except ReplicaBusyError:
+                # an atomic op whose table began to duplicate while it
+                # waited must see the window drained: back to the head,
+                # for the drain at the end of the round in flight
+                self._write_queue[:0] = spans
+                self._queued_ops[:0] = ops
+                self._queued_atomic += n_atomic
+                return
 
     def _on_group_check(self, src: str, payload: dict) -> None:
         """Parity: on_group_check (replica_check.cpp:212) — heartbeat from
@@ -809,21 +878,22 @@ class Replica:
                         len(sk) for sk in wo.request.sort_keys))
                     err, count, its = ws.translate_multi_remove(wo.request)
                     responses.append((err, count))
-                elif wo.op == OP_INCR:
-                    cu_total += _cu_units(len(wo.request.key))
-                    resp, its = ws.translate_incr(wo.request, ts, now)
+                elif wo.op in (OP_INCR, OP_CAS, OP_CAM):
+                    if wo.op == OP_INCR:
+                        cu_total += _cu_units(len(wo.request.key))
+                        resp, its = ws.translate_incr(wo.request, ts, now)
+                    elif wo.op == OP_CAS:
+                        resp, its = ws.translate_check_and_set(
+                            wo.request, ts, now)
+                    else:
+                        resp, its = ws.translate_check_and_mutate(
+                            wo.request, ts, now)
                     resp.decree = mu.decree
                     responses.append(resp)
-                elif wo.op == OP_CAS:
-                    resp, its = ws.translate_check_and_set(
-                        wo.request, ts, now)
-                    resp.decree = mu.decree
-                    responses.append(resp)
-                elif wo.op == OP_CAM:
-                    resp, its = ws.translate_check_and_mutate(
-                        wo.request, ts, now)
-                    resp.decree = mu.decree
-                    responses.append(resp)
+                    if self.status == PartitionStatus.PRIMARY:
+                        _ATOMIC_APPLIED.increment()
+                        if resp.error == _TRY_AGAIN:
+                            _ATOMIC_CHECK_FAILED.increment()
                 elif wo.op == OP_DUP_PUT:
                     key, user_data, expire_ts, timetag = wo.request
                     applied, its = ws.translate_duplicate_put(

@@ -36,6 +36,7 @@ from pegasus_tpu.base.value_schema import (
 )
 from pegasus_tpu.storage.engine import StorageEngine, WriteBatchItem
 from pegasus_tpu.storage.wal import OP_DEL, OP_PUT
+from pegasus_tpu.utils import tracing
 from pegasus_tpu.utils.errors import StorageStatus
 from pegasus_tpu.server.types import (
     CasCheckType,
@@ -161,6 +162,25 @@ class WriteService:
             return None
         return extract_user_data(self.data_version, hit[0])
 
+    def _check(self, req, resp, now: int) -> Optional[bool]:
+        """The read before a check_and_set / check_and_mutate writes:
+        the check row's current value into `resp` when asked for, then
+        the check against it (None: a malformed int compare). Under
+        the `atomic_check` scope, the atomic layer's self time."""
+        with tracing.layer("atomic_check"):
+            check_value = self._visible_user_data(
+                generate_key(req.hash_key, req.check_sort_key), now)
+            if req.return_check_value:
+                resp.check_value_returned = True
+                if check_value is not None:
+                    resp.check_value_exist = True
+                    resp.check_value = check_value
+            try:
+                return cas_check_passed(req.check_type, req.check_operand,
+                                        check_value)
+            except ValueError:
+                return None
+
     # -- translate phase ------------------------------------------------
 
     def translate_put(self, key: bytes, user_data: bytes, expire_ts: int,
@@ -227,20 +247,20 @@ class WriteService:
         expire_ts_seconds: 0 keeps the old TTL, >0 resets, <0 clears."""
         now = epoch_now() if now is None else now
         resp = IncrResponse()
-        old = self._visible(req.key, now)
-        if old is None:
-            old_int, old_ets = 0, 0
-        else:
-            raw, old_ets = old
-            data = extract_user_data(self.data_version, raw)
-            if data == b"":
-                old_int = 0
+        with tracing.layer("atomic_check"):
+            old = self._visible(req.key, now)
+            if old is None:
+                old_int, old_ets = 0, 0
             else:
+                raw, old_ets = old
+                data = extract_user_data(self.data_version, raw)
                 try:
-                    old_int = _parse_int64(data)
+                    old_int = 0 if data == b"" else _parse_int64(data)
                 except ValueError:
-                    resp.error = int(StorageStatus.INVALID_ARGUMENT)
-                    return resp, []
+                    old_int = None
+        if old_int is None:
+            resp.error = int(StorageStatus.INVALID_ARGUMENT)
+            return resp, []
         new_int = old_int + req.increment
         if not (_INT64_MIN <= new_int <= _INT64_MAX):
             resp.error = int(StorageStatus.INVALID_ARGUMENT)
@@ -264,17 +284,8 @@ class WriteService:
                                            List[WriteBatchItem]]:
         now = epoch_now() if now is None else now
         resp = CheckAndSetResponse()
-        check_key = generate_key(req.hash_key, req.check_sort_key)
-        check_value = self._visible_user_data(check_key, now)
-        if req.return_check_value:
-            resp.check_value_returned = True
-            if check_value is not None:
-                resp.check_value_exist = True
-                resp.check_value = check_value
-        try:
-            passed = cas_check_passed(req.check_type, req.check_operand,
-                                      check_value)
-        except ValueError:
+        passed = self._check(req, resp, now)
+        if passed is None:
             resp.error = int(StorageStatus.INVALID_ARGUMENT)
             return resp, []
         if not passed:
@@ -299,17 +310,8 @@ class WriteService:
         if not req.mutate_list:
             resp.error = int(StorageStatus.INVALID_ARGUMENT)
             return resp, []
-        check_key = generate_key(req.hash_key, req.check_sort_key)
-        check_value = self._visible_user_data(check_key, now)
-        if req.return_check_value:
-            resp.check_value_returned = True
-            if check_value is not None:
-                resp.check_value_exist = True
-                resp.check_value = check_value
-        try:
-            passed = cas_check_passed(req.check_type, req.check_operand,
-                                      check_value)
-        except ValueError:
+        passed = self._check(req, resp, now)
+        if passed is None:
             resp.error = int(StorageStatus.INVALID_ARGUMENT)
             return resp, []
         if not passed:

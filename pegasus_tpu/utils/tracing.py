@@ -158,7 +158,8 @@ def profiling() -> bool:
 # ---- layers --------------------------------------------------------------
 
 LAYER_KEYS = ("client", "rpc", "gate", "coord", "overlay", "index",
-              "decode", "dispatch", "repl", "engine", "compact", "other")
+              "decode", "dispatch", "repl", "engine", "compact", "atomic",
+              "other")
 
 # span or stage name -> layer key. A name is looked up whole, then by
 # what stands before its first "." (client.<op>, prepare.<dst>,
@@ -204,6 +205,10 @@ LAYER_OF = {
     "committed_applied": "repl", "replied": "repl",
     # storage/engine.py
     "engine": "engine",
+    # server/write_service.py: the read before an atomic write (incr,
+    # check_and_set, check_and_mutate) and its check, on every replica
+    # at apply
+    "atomic_check": "atomic",
     # an env-triggered manual compaction on its own thread: the root
     # compact.run (partition_server), the bulk path's stage threads
     # compact.read / compact.filter (compact_pipeline), and the stages

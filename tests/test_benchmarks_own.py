@@ -1,7 +1,8 @@
-"""The benchmark's own tests of the cell `prefix_scan.p64r3`
+"""The benchmark's own tests of the cells `prefix_scan.p64r3`
 (benchmarks/tests/test_prefix_scan_cell.py, test_prefix_scan_steps.py)
-and of the trace reductions (test_trace_reduce.py, test_trace_layers.py)
-as cases of the tier-1 run, which collects `tests/` alone. The modules
+and `ycsb_f.p64r3` (test_ycsb_f_cell.py), and of the trace reductions
+(test_trace_reduce.py, test_trace_layers.py) as cases of the tier-1
+run, which collects `tests/` alone. The modules
 stay runnable by hand as benchmarks/README.md says; their tests and
 fixtures have names of their own, so no module imported here shadows
 another's. `pscan_later_metrics` joins the cell's later metrics to the
@@ -21,3 +22,4 @@ from benchmarks.tests.test_prefix_scan_cell import *  # noqa: F401,F403
 from benchmarks.tests.test_prefix_scan_steps import *  # noqa: F401,F403
 from benchmarks.tests.test_trace_layers import *  # noqa: F401,F403
 from benchmarks.tests.test_trace_reduce import *  # noqa: F401,F403
+from benchmarks.tests.test_ycsb_f_cell import *  # noqa: F401,F403

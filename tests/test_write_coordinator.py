@@ -262,8 +262,9 @@ def test_restart_recovers_acked_writes_with_stale_engine_wal(tmp_path):
 
 
 def test_write_queue_overload_raises_typed_busy(tmp_path):
-    """Queue-full and non-batchable-behind-in-flight both raise
-    ReplicaBusyError (stub maps it to ERR_BUSY — retryable)."""
+    """Behind an in-flight window an atomic op queues as a mutation of
+    its own; a full queue of either kind raises ReplicaBusyError (stub
+    maps it to ERR_BUSY — retryable)."""
     loop = SimLoop(seed=0)
     net = SimNetwork(loop)
     r = Replica("r1", str(tmp_path / "r1"), net,
@@ -275,16 +276,22 @@ def test_write_queue_overload_raises_typed_busy(tmp_path):
     for i in range(r.PIPELINE_DEPTH):
         assert r.client_write(
             [WriteOp(OP_PUT, (k(b"h%d" % i, b"s"), b"v", 0))]) > 0
-    # an atomic op cannot batch behind the in-flight round
-    with pytest.raises(ReplicaBusyError):
-        r.client_write([WriteOp(OP_INCR,
-                                IncrRequest(k(b"c", b"s"), 1, 0))])
+    # an atomic op waits behind the in-flight round, not refused
+    assert r.client_write([WriteOp(OP_INCR,
+                                   IncrRequest(k(b"c", b"s"), 1, 0))]) == -1
     # batchable ops coalesce until the queue cap, then typed busy
     batch = [WriteOp(OP_PUT, (k(b"q", b"s%03d" % i), b"v", 0))
              for i in range(r.MAX_BATCH_OPS)]
     assert r.client_write(batch) == -1
     with pytest.raises(ReplicaBusyError):
         r.client_write([WriteOp(OP_PUT, (k(b"q2", b"s"), b"v", 0))])
+    # atomic ops queue up to the same cap, then typed busy
+    for _ in range(r.MAX_BATCH_OPS - 1):
+        assert r.client_write([WriteOp(
+            OP_INCR, IncrRequest(k(b"c", b"s"), 1, 0))]) == -1
+    with pytest.raises(ReplicaBusyError):
+        r.client_write([WriteOp(OP_INCR,
+                                IncrRequest(k(b"c", b"s"), 1, 0))])
     r.close()
 
 
