@@ -45,6 +45,9 @@ _PROGRAM_COUNTERS = {
 # whole microseconds, always on (scan_coordinator._WaveClock)
 _MASK_STEP_US = tuple(_FILTER_ENT.counter(f"mask_{step}_us")
                       for step in ("stack", "launch", "fetch"))
+# of `mask_programs`, those whose stack of blocks was concatenated
+# inside the program (always on)
+_MASK_STACKED = _FILTER_ENT.counter("mask_stacked_programs")
 
 
 def note_mask_steps(stack_ns: int, launch_ns: int, fetch_ns: int) -> None:
@@ -54,11 +57,14 @@ def note_mask_steps(stack_ns: int, launch_ns: int, fetch_ns: int) -> None:
 
 
 def note_filter_program(rows: int, nbytes: int,
-                        kind: str = "filter") -> None:
+                        kind: str = "filter", stacked: bool = False) -> None:
     """One dispatched program: its padded rows, and the bytes of the
-    columns it was handed plus the masks / expire_ts it gives back."""
+    columns it was handed plus the masks / expire_ts it gives back.
+    `stacked`: a mask program that concatenated its blocks itself."""
     programs, c_rows, c_bytes, traced, bytes_traced = _PROGRAM_COUNTERS[kind]
     programs.increment()
+    if stacked:
+        _MASK_STACKED.increment()
     c_rows.increment(rows)
     c_bytes.increment(nbytes)
     if tracing.frame_span() is not None or tracing.profiling():

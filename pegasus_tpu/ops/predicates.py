@@ -240,9 +240,8 @@ def static_block_predicate(block: RecordBlock,
     scan_block_predicate (pegasus_server_impl.cpp:2392-2401)."""
     hash_filter = hash_filter or FilterSpec.none()
     sort_filter = sort_filter or FilterSpec.none()
-    pidx_is_array = not isinstance(pidx, int)
-    if (validate_hash and not pidx_is_array
-            and (partition_version < 0 or pidx > partition_version)):
+    if validate_hash and (partition_version < 0
+                          or pidx > partition_version):
         if pack:
             return jnp.zeros((block.capacity // 8,), dtype=jnp.uint8)
         return jnp.zeros((block.capacity,), dtype=bool)
@@ -252,13 +251,87 @@ def static_block_predicate(block: RecordBlock,
         jnp.asarray(block.hashkey_len), jnp.asarray(block.valid),
         hash_filter.pattern, hash_filter.pattern_len,
         sort_filter.pattern, sort_filter.pattern_len,
-        jnp.asarray(pidx, jnp.uint32)
-        if not pidx_is_array else jnp.asarray(pidx),
+        jnp.asarray(pidx, jnp.uint32),
         jnp.asarray(partition_version & 0xFFFFFFFF, jnp.uint32),
         hash_filter.filter_type, sort_filter.filter_type, validate_hash,
         hash_lo=(jnp.asarray(block.hash_lo) if use_hash_lo
                  else jnp.zeros((1,), jnp.uint32)),
         use_hash_lo=use_hash_lo, pack=pack)
+
+
+def _stack_operands(blocks, validate_hash: bool) -> tuple:
+    """A stack's block operands for the stacked programs: one tuple a
+    column, of every block's array. `hash_lo` goes for all blocks or
+    for none: a stack mixing them computes the hash on device, so a
+    (width, cap) has at most two operand structures."""
+    use_hash_lo = validate_hash and all(b.hash_lo is not None
+                                        for b in blocks)
+    return (tuple(b.keys for b in blocks),
+            tuple(b.key_len for b in blocks),
+            tuple(b.hashkey_len for b in blocks),
+            tuple(b.valid for b in blocks),
+            tuple(b.hash_lo for b in blocks) if use_hash_lo else None)
+
+
+def _concat_stack(keys, key_len, hashkey_len, valid, hash_lo, pidx):
+    """Inside a trace: the stack's columns concatenated and its
+    per-block `pidx` vector expanded to a per-record column, for the
+    single-stack program bodies to take as they are. The expansion is
+    a broadcast: `jnp.repeat` lowers to a gather, which the TPU runs a
+    scalar at a time."""
+    cap = keys[0].shape[0]
+    cat = jnp.concatenate
+    return (cat(keys), cat(key_len), cat(hashkey_len), cat(valid),
+            cat(hash_lo) if hash_lo is not None
+            else jnp.zeros((1,), jnp.uint32),
+            jnp.broadcast_to(pidx[:, None], (len(keys), cap)).reshape(-1))
+
+
+@functools.partial(jax.jit, static_argnames=("hash_filter_type",
+                                             "sort_filter_type",
+                                             "validate_hash", "pack"))
+def _stacked_static_block_predicate(keys, key_len, hashkey_len, valid,
+                                    hash_lo, pidx,
+                                    hash_pattern, hash_pattern_len,
+                                    sort_pattern, sort_pattern_len,
+                                    partition_version,
+                                    hash_filter_type: int,
+                                    sort_filter_type: int,
+                                    validate_hash: bool,
+                                    pack: bool) -> jax.Array:
+    keys, key_len, hashkey_len, valid, lo, pidx = _concat_stack(
+        keys, key_len, hashkey_len, valid, hash_lo, pidx)
+    return _static_block_predicate(
+        keys, key_len, hashkey_len, valid,
+        hash_pattern, hash_pattern_len, sort_pattern, sort_pattern_len,
+        pidx, partition_version, hash_filter_type, sort_filter_type,
+        validate_hash, hash_lo=lo, use_hash_lo=hash_lo is not None,
+        pack=pack)
+
+
+def stacked_static_block_predicate(blocks, pidx,
+                                   hash_filter: Optional[FilterSpec] = None,
+                                   sort_filter: Optional[FilterSpec] = None,
+                                   validate_hash: bool = False,
+                                   partition_version: int = -1,
+                                   pack: bool = False) -> jax.Array:
+    """static_block_predicate over a stack of same-shaped blocks in ONE
+    jitted call: the blocks' columns are concatenated inside the
+    program, not by eager dispatches before it. `pidx`: uint32[S], the
+    owning partition of each block. bool[S*cap] (or packed), block i's
+    mask at [i*cap, (i+1)*cap). A per-block pidx has no reject-all gate:
+    a block whose pidx exceeds the partition version matches no record
+    by the hash test itself."""
+    hash_filter = hash_filter or FilterSpec.none()
+    sort_filter = sort_filter or FilterSpec.none()
+    return _stacked_static_block_predicate(
+        *_stack_operands(blocks, validate_hash),
+        np.asarray(pidx, np.uint32),
+        hash_filter.pattern, hash_filter.pattern_len,
+        sort_filter.pattern, sort_filter.pattern_len,
+        np.uint32(partition_version & 0xFFFFFFFF),
+        hash_filter.filter_type, sort_filter.filter_type, validate_hash,
+        pack)
 
 
 def host_alive_mask(expire_ts: np.ndarray, now: int) -> np.ndarray:
@@ -587,28 +660,66 @@ def multi_static_block_predicate_submit(block: RecordBlock, filters,
     (callers group by exactly that). The split-safety reject-all gate
     matches static_block_predicate.
     """
-    pidx_is_array = not isinstance(pidx, int)
-    cap = block.capacity
-    if (validate_hash and not pidx_is_array
-            and (partition_version < 0 or pidx > partition_version)):
-        return jnp.zeros((len(filters), cap // 8), dtype=jnp.uint8)
+    if validate_hash and (partition_version < 0
+                          or pidx > partition_version):
+        return jnp.zeros((len(filters), block.capacity // 8),
+                         dtype=jnp.uint8)
     hf0, sf0 = filters[0]
-    hash_patterns = jnp.stack([hf.pattern for hf, _sf in filters])
-    hash_plens = jnp.stack([hf.pattern_len for hf, _sf in filters])
-    sort_patterns = jnp.stack([sf.pattern for _hf, sf in filters])
-    sort_plens = jnp.stack([sf.pattern_len for _hf, sf in filters])
     use_hash_lo = validate_hash and block.hash_lo is not None
     return _multi_static_block_predicate(
         jnp.asarray(block.keys), jnp.asarray(block.key_len),
         jnp.asarray(block.hashkey_len), jnp.asarray(block.valid),
-        hash_patterns, hash_plens, sort_patterns, sort_plens,
-        jnp.asarray(pidx, jnp.uint32)
-        if not pidx_is_array else jnp.asarray(pidx),
+        *_flavor_operands(filters),
+        jnp.asarray(pidx, jnp.uint32),
         jnp.asarray(partition_version & 0xFFFFFFFF, jnp.uint32),
         hf0.filter_type, sf0.filter_type, validate_hash,
         hash_lo=(jnp.asarray(block.hash_lo) if use_hash_lo
                  else jnp.zeros((1,), jnp.uint32)),
         use_hash_lo=use_hash_lo)
+
+
+def _flavor_operands(filters) -> tuple:
+    """The flavor axis: (hash patterns [K, P], their lengths [K], sort
+    patterns, their lengths)."""
+    return (jnp.stack([hf.pattern for hf, _sf in filters]),
+            jnp.stack([hf.pattern_len for hf, _sf in filters]),
+            jnp.stack([sf.pattern for _hf, sf in filters]),
+            jnp.stack([sf.pattern_len for _hf, sf in filters]))
+
+
+@functools.partial(jax.jit, static_argnames=("hash_filter_type",
+                                             "sort_filter_type",
+                                             "validate_hash"))
+def _stacked_multi_static_block_predicate(keys, key_len, hashkey_len,
+                                          valid, hash_lo, pidx,
+                                          hash_patterns, hash_plens,
+                                          sort_patterns, sort_plens,
+                                          partition_version,
+                                          hash_filter_type: int,
+                                          sort_filter_type: int,
+                                          validate_hash: bool) -> jax.Array:
+    keys, key_len, hashkey_len, valid, lo, pidx = _concat_stack(
+        keys, key_len, hashkey_len, valid, hash_lo, pidx)
+    return _multi_static_block_predicate(
+        keys, key_len, hashkey_len, valid,
+        hash_patterns, hash_plens, sort_patterns, sort_plens,
+        pidx, partition_version, hash_filter_type, sort_filter_type,
+        validate_hash, hash_lo=lo, use_hash_lo=hash_lo is not None)
+
+
+def stacked_multi_static_block_predicate_submit(blocks, filters,
+                                                validate_hash: bool, pidx,
+                                                partition_version: int):
+    """multi_static_block_predicate_submit over a stack of same-shaped
+    blocks in ONE jitted call, the stack concatenated inside the
+    program (stacked_static_block_predicate's rules: `pidx` uint32[S],
+    no reject-all gate). Returns the device uint8[K, S*cap//8]."""
+    hf0, sf0 = filters[0]
+    return _stacked_multi_static_block_predicate(
+        *_stack_operands(blocks, validate_hash),
+        np.asarray(pidx, np.uint32), *_flavor_operands(filters),
+        np.uint32(partition_version & 0xFFFFFFFF),
+        hf0.filter_type, sf0.filter_type, validate_hash)
 
 
 def unpack_masks(packed, count: int) -> np.ndarray:

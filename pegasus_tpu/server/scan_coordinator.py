@@ -4,9 +4,9 @@ The SURVEY §2.6 dispatch model realized: partitions are the batch
 dimension of ONE device program. A node hosting many partitions of a
 table receives one multi-partition scan message, plans each partition's
 batch, stacks every uncached block ACROSS partitions (same key width →
-one [B*cap, W] program with a per-record partition-index column for the
-stale-split check), evaluates once, and hands each partition its masks
-back. Per-flush device dispatches drop from
+one program over a stack of blocks, each with its partition index for
+the stale-split check), evaluates once, and hands each partition its
+masks back. Per-flush device dispatches drop from
 O(partitions × blocks) to O(key-width buckets).
 
 Two further batch axes cut what each dispatch costs (a fixed launch
@@ -44,6 +44,8 @@ from pegasus_tpu.ops.predicates import (
     FT_NO_FILTER,
     FilterSpec,
     multi_static_block_predicate_submit,
+    stacked_multi_static_block_predicate_submit,
+    stacked_static_block_predicate,
     static_block_predicate,
     unpack_masks,
 )
@@ -280,34 +282,44 @@ def stacked_block_submit(blocks, validate: bool, pv: int,
     (group, cap, packed_keep_device_array). Buckets by (key width,
     capacity) so differently-capped tail blocks can never misalign mask
     slices; fixed STACK_CHUNK keeps exactly two compiled shapes per key
-    width ([cap, W] and [STACK_CHUNK*cap, W]) — variable stack sizes
-    made every batch a fresh XLA compile. A stack mixing hash_lo and
-    non-hash_lo blocks drops the precomputed column (the kernel computes
-    the hash on device instead). `clock`: the wave's _WaveClock, which
-    times each chunk's stacking and launch."""
+    width ([cap, W] and a stack of STACK_CHUNK) — variable stack sizes
+    made every batch a fresh XLA compile. A stack is one jitted call
+    that concatenates its blocks inside the program; one mixing hash_lo
+    and non-hash_lo blocks drops the precomputed column (the kernel
+    computes the hash on device instead). `clock`: the wave's
+    _WaveClock, which times each chunk's stacking and launch."""
     hft, hfp, sft, sfp = filter_key or (FT_NO_FILTER, b"",
                                         FT_NO_FILTER, b"")
     hash_f = FilterSpec.make(hft, hfp)
     sort_f = FilterSpec.make(sft, sfp)
-    for group, cap, stacked, pidx in _stacked_chunks(blocks):
+    for group, cap, stack, pidx in _stacked_chunks(blocks):
         if clock is not None:
             clock.lap(STACK)
         with tracing.layer("dispatch.launch"):
-            keep = static_block_predicate(
-                stacked, hash_filter=hash_f, sort_filter=sort_f,
-                validate_hash=validate, pidx=pidx, partition_version=pv,
-                pack=True)
+            stacked = len(stack) > 1
+            if stacked:
+                keep = stacked_static_block_predicate(
+                    stack, pidx, hash_filter=hash_f, sort_filter=sort_f,
+                    validate_hash=validate, partition_version=pv,
+                    pack=True)
+            else:
+                keep = static_block_predicate(
+                    stack[0], hash_filter=hash_f, sort_filter=sort_f,
+                    validate_hash=validate, pidx=pidx,
+                    partition_version=pv, pack=True)
             # key matrix + key_len, hashkey_len (4 B each) + valid, the
             # hash column where it is used, the packed mask back; a
-            # stack also reads a pidx column, after its six columns
-            # were gathered on the device (read and written once)
-            rows, width = stacked.keys.shape
+            # stack also reads a pidx column, after its columns were
+            # concatenated (read and written once: XLA writes the
+            # concatenated copy inside the program)
+            rows, width = len(stack) * cap, stack[0].keys.shape[1]
             columns = width + 9 + (
-                4 if validate and stacked.hash_lo is not None else 0)
+                4 if validate and all(b.hash_lo is not None
+                                      for b in stack) else 0)
             note_filter_program(
                 rows, rows * columns + rows // 8
-                + (rows * (4 + 2 * (width + 17)) if len(group) > 1 else 0),
-                kind="mask")
+                + (rows * (4 + 2 * (width + 17)) if stacked else 0),
+                kind="mask", stacked=stacked)
         if clock is not None:
             clock.lap(LAUNCH)
         yield group, cap, keep
@@ -318,10 +330,10 @@ STACK, LAUNCH, FETCH = range(3)
 
 class _WaveClock:
     """A wave of mask programs in its three steps on the host's clock:
-    STACK (a chunk's blocks concatenated on the device), LAUNCH (the
-    jitted call, which uploads a stack's pidx column and queues the
-    program) and FETCH (the wait for the wave's packed masks and their
-    copy to the host). Each step boundary is one clock read that ends
+    STACK (a chunk's blocks listed and its pidx vector built on the
+    host), LAUNCH (the jitted call, which uploads a stack's pidx vector
+    and queues the program that concatenates the stack) and FETCH (the
+    wait for the wave's packed masks and their copy to the host). Each step boundary is one clock read that ends
     one step and starts the next, so the steps add up to the wave's
     wall; `close` counts them (`engine`/`mask_{stack,launch,fetch}_us`)
     and returns that wall, which the drift audit takes."""
@@ -351,12 +363,11 @@ MULTI_FLAVOR_MAX = 64
 
 
 def _stacked_chunks(blocks):
-    """Shared chunking: yields (group, cap, stacked RecordBlock, pidx)
-    where pidx is a scalar (single block) or per-record column."""
-    import jax.numpy as jnp
-
-    from pegasus_tpu.ops.record_block import RecordBlock
-
+    """Shared chunking: yields (group, cap, stack, pidx). A chunk of one
+    block: stack [its block], pidx its scalar. A longer chunk: stack
+    padded to STACK_CHUNK blocks by repeating its first, pidx their
+    uint32[STACK_CHUNK] partition indices; the stacked programs
+    concatenate the blocks themselves."""
     buckets: "OrderedDict[tuple, list]" = OrderedDict()
     for tag, dev, pidx in blocks:
         key = (int(dev.keys.shape[1]), int(dev.keys.shape[0]))
@@ -366,27 +377,16 @@ def _stacked_chunks(blocks):
             chunk = group[off:off + STACK_CHUNK]
             if len(chunk) == 1:
                 tag, dev, pidx = chunk[0]
-                yield chunk, cap, dev, pidx
+                yield chunk, cap, [dev], pidx
                 continue
             padded = chunk + [chunk[0]] * (STACK_CHUNK - len(chunk))
             with tracing.layer("dispatch.stack"):
-                pidx_col = np.concatenate([
-                    np.full(cap, pidx, dtype=np.uint32)
-                    for _t, _d, pidx in padded])
-                all_hash_lo = all(d.hash_lo is not None
-                                  for _t, d, _p in padded)
-                stacked = RecordBlock(
-                    jnp.concatenate([d.keys for _t, d, _p in padded]),
-                    jnp.concatenate([d.key_len for _t, d, _p in padded]),
-                    jnp.concatenate([d.hashkey_len
-                                     for _t, d, _p in padded]),
-                    jnp.concatenate([d.expire_ts for _t, d, _p in padded]),
-                    jnp.concatenate([d.valid for _t, d, _p in padded]),
-                    (jnp.concatenate([d.hash_lo for _t, d, _p in padded])
-                     if all_hash_lo else None))
+                stack = [d for _t, d, _p in padded]
+                pidx_vec = np.fromiter((p for _t, _d, p in padded),
+                                       dtype=np.uint32, count=STACK_CHUNK)
             # the scope closes before the yield: the consumer's frames
             # are not the stack's children
-            yield chunk, cap, stacked, pidx_col
+            yield chunk, cap, stack, pidx_vec
 
 
 def _fetch_wave(arrays: list) -> list:
@@ -478,11 +478,15 @@ def _eval_cross_partition_multi(flavors: dict, validate: bool,
     submitted = []
     with tracing.layer("dispatch.wave"):
         clock = _WaveClock()
-        for group, cap, stacked, pidx in _stacked_chunks(blocks):
+        for group, cap, stack, pidx in _stacked_chunks(blocks):
             clock.lap(STACK)
             with tracing.layer("dispatch.launch"):
-                packed = multi_static_block_predicate_submit(
-                    stacked, specs, validate, pidx, pv)
+                if len(stack) > 1:
+                    packed = stacked_multi_static_block_predicate_submit(
+                        stack, specs, validate, pidx, pv)
+                else:
+                    packed = multi_static_block_predicate_submit(
+                        stack[0], specs, validate, pidx, pv)
             clock.lap(LAUNCH)
             submitted.append((group, cap, packed))
         with tracing.layer("dispatch.fetch"):

@@ -192,10 +192,10 @@ LAYER_OF = {
     # device predicate programs: call to mask on the host
     "dispatch": "dispatch", "pushdown": "dispatch",
     # the steps of a wave of mask programs (dispatch.wave,
-    # server/scan_coordinator.py): a chunk's blocks and pidx column
-    # concatenated into one stack; the jitted call, which uploads the
-    # pidx column and queues the program; the wait for the wave's
-    # packed masks and their copy to the host
+    # server/scan_coordinator.py): a chunk's blocks listed and its
+    # per-block pidx vector built; the jitted call, which uploads the
+    # vector and queues the program that concatenates the stack; the
+    # wait for the wave's packed masks and their copy to the host
     "dispatch.stack": "dispatch", "dispatch.launch": "dispatch",
     "dispatch.fetch": "dispatch",
     # replica/: 2PC, plog, group commit

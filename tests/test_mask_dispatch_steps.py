@@ -3,7 +3,11 @@ a traced root a wave of mask programs is a `dispatch.wave` frame with
 `dispatch.stack`, `dispatch.launch` and `dispatch.fetch` children, and
 with tracing off the always-on counters `engine`/`mask_stack_us`,
 `mask_launch_us` and `mask_fetch_us` add up to the wall time the drift
-audit records. Small blocks of 32 rows on the CPU, one partition.
+audit records. A stack of more than one block is one jitted call that
+concatenates the blocks inside the program: `engine`/
+`mask_stacked_programs` counts those programs, and a second wave of
+other stacks of the same shape compiles nothing. Small blocks of 32
+rows on the CPU, one partition.
 """
 
 import threading
@@ -13,6 +17,7 @@ import numpy as np
 import pytest
 
 from pegasus_tpu.base.key_schema import generate_key
+from pegasus_tpu.ops import predicates
 from pegasus_tpu.ops.predicates import FT_MATCH_PREFIX, FT_NO_FILTER
 from pegasus_tpu.ops.record_block import build_record_block
 from pegasus_tpu.server.scan_coordinator import (
@@ -41,13 +46,21 @@ def blocks():
     return [(i, _block(i), 0) for i in range(STACK_CHUNK)]
 
 
+@pytest.fixture(scope="module")
+def more_blocks():
+    """Three stacks' worth, other rows than `blocks`."""
+    return [(i, _block(STACK_CHUNK + i), 0) for i in range(3 * STACK_CHUNK)]
+
+
 def _engine():
-    """mask programs and the three step counters, summed."""
-    out = dict.fromkeys(("programs",) + STEPS, 0)
+    """mask programs, the stacked ones among them and the three step
+    counters, summed."""
+    out = dict.fromkeys(("programs", "stacked") + STEPS, 0)
     for ent in METRICS.snapshot(entity_type="engine"):
         m = ent["metrics"]
         if "mask_programs" in m:
             out["programs"] += m["mask_programs"]["value"]
+            out["stacked"] += m["mask_stacked_programs"]["value"]
             for s in STEPS:
                 out[s] += m[f"mask_{s}_us"]["value"]
     return out
@@ -108,6 +121,7 @@ def test_the_step_counters_add_up_to_the_audited_wall(blocks, height):
         _wave(blocks[:height])
     d = {k: v - before[k] for k, v in _engine().items()}
     assert d["programs"] == 1
+    assert d["stacked"] == (height > 1)
     assert d["launch"] > 0 and d["fetch"] > 0
     if height > 1:
         assert d["stack"] > 0
@@ -160,3 +174,31 @@ def test_the_multi_flavor_wave_has_the_same_steps(blocks):
         "dispatch.fetch", "dispatch.launch", "dispatch.stack"]
     d = {k: v - before[k] for k, v in _engine().items()}
     assert d["stack"] > 0 and d["launch"] > 0 and d["fetch"] > 0
+
+
+@pytest.mark.parametrize("height,programs,stacked", [
+    (1, 1, 0), (2, 1, 1), (STACK_CHUNK, 1, 1), (STACK_CHUNK + 1, 2, 1),
+    (STACK_CHUNK + 2, 2, 2), (2 * STACK_CHUNK + 1, 3, 2)])
+def test_stacked_programs_count_the_multi_block_programs(
+        more_blocks, height, programs, stacked):
+    """A wave's chunks of more than one block each add one to
+    `mask_stacked_programs`; a single block's program adds nothing."""
+    _wave(more_blocks[:height])
+    before = _engine()
+    masks = _wave(more_blocks[:height])
+    d = {k: v - before[k] for k, v in _engine().items()}
+    assert [tag for tag, _m in masks] == list(range(height))
+    assert (d["programs"], d["stacked"]) == (programs, stacked)
+
+
+def test_other_stacks_of_one_shape_compile_nothing(blocks, more_blocks):
+    """The stacked program is compiled once a (width, cap, operand
+    structure): a wave of other 16-block stacks, from other blocks,
+    finds it in the jitted function's cache."""
+    _wave(blocks)
+    size = predicates._stacked_static_block_predicate._cache_size()
+    before = _engine()
+    masks = _wave(more_blocks)
+    d = {k: v - before[k] for k, v in _engine().items()}
+    assert len(masks) == 3 * STACK_CHUNK and d["stacked"] == 3
+    assert predicates._stacked_static_block_predicate._cache_size() == size
