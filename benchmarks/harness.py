@@ -3,9 +3,10 @@ window, verify what the window received, reduce the metrics.
 
 run.py (the chip) and rehearse.py (a CPU rehearsal at a tiny size) both
 call run_cell. Nothing about a cell lives here: the deployment comes
-from configs/<config>.json, the mix from traffic/<mix>.json, each op
-kind from ops/<kind>.py, each per-layer metric from
-layer_metrics/<metric>.json and its reader from readers/<reader>.py.
+from configs/<config>.json, its rows from loaders/<loader>.py, the mix
+from traffic/<mix>.json, each op kind from ops/<kind>.py, each
+per-layer metric from layer_metrics/<metric>.json and its reader from
+readers/<reader>.py.
 """
 
 from __future__ import annotations
@@ -26,8 +27,7 @@ import numpy as np
 
 from benchmarks import faults as fault_mod
 from benchmarks.generator import HERE, Schedule, op_module
-from benchmarks.reference import (Model, epoch_now, make_records,
-                                  partition_of)
+from benchmarks.reference import Model, epoch_now, partition_of
 
 _NULL = contextlib.nullcontext()
 TRACE_PROBES = 4    # probe operations at the start of a traced slice
@@ -141,6 +141,15 @@ def reader_module(name: str):
     return importlib.import_module(f"benchmarks.readers.{name}")
 
 
+def table_rows(config: dict, seed: int, now: int):
+    """The loaded table as of `now`, (hk, sk, value, expire_ts), from
+    benchmarks/loaders/<loader>.py found by the config's `loader`
+    (`ycsb` where it names none)."""
+    loader = importlib.import_module(
+        f"benchmarks.loaders.{config.get('loader', 'ycsb')}")
+    return loader.rows(seed, config, now)
+
+
 def percentile(values, p: float) -> float:
     """Nearest-rank percentile of a non-empty list."""
     s = sorted(values)
@@ -154,12 +163,14 @@ class Cluster:
         from pegasus_tpu.tools.cluster import SimCluster
 
         self.config = config
+        self.next_expiry = math.inf
+        envs = config.get("table_envs")
         self.sim = SimCluster(os.path.join(workdir, "sim"),
                               n_nodes=config["nodes"])
         try:
             self.app_id = self.sim.create_table(
                 config["table"], partition_count=config["partitions"],
-                replica_count=config["replicas"])
+                replica_count=config["replicas"], envs=envs)
             self.client = self.sim.client(config["table"])
             self.client.refresh_config()
             gpids = [(self.app_id, p) for p in range(config["partitions"])]
@@ -174,16 +185,29 @@ class Cluster:
             if short:
                 raise RuntimeError(f"partitions {short} have fewer than "
                                    f"{config['replicas']} replicas")
+            # the table's envs are on every replica before its first row
+            unset = [p for p, rs in enumerate(self.replicas_of)
+                     if any(r.server.app_envs.get(k) != v
+                            for r in rs for k, v in (envs or {}).items())]
+            if unset:
+                raise RuntimeError(f"partitions {unset} lack the table "
+                                   f"envs {envs}")
         except BaseException:
             self.sim.close()
             raise
 
     def load(self, seed: int, now: int, fault) -> list:
-        """The table through the primaries' 2PC in batches of 1000
-        rows, then flush and compaction to L1 on every replica. Returns
-        the hashkeys of the first and the last unexpired row of every
-        partition (hashkeys are of one length and numbered upwards, so
-        the store's key order is the order they are made in)."""
+        """The table as the config's loader makes it as of `now`,
+        through the primaries' 2PC in batches of 1000 rows, then flush
+        and compaction to L1 on every replica. The compaction filters
+        TTL under the engine's own clock, or, where the config gives
+        `load_compaction_age_s`, as of that many seconds before `now`:
+        the rows that expired since then stay on disk, as between an
+        operator's compactions. Returns the hashkeys of the first and
+        the last row live at `now` of every partition (a loader yields
+        a hashkey's rows together and hashkeys of one length in key
+        order). Keeps in `next_expiry` the soonest expire_ts after
+        `now`, math.inf where no loaded row has one."""
         from pegasus_tpu.base.key_schema import generate_key
         from pegasus_tpu.client.table import compact_partitions_parallel
         from pegasus_tpu.replica.mutation import WriteOp
@@ -193,14 +217,15 @@ class Cluster:
         per_pidx = [[] for _ in range(c["partitions"])]
         first, last = {}, {}
         prev_hk = p = None
-        for n, (hk, sk, value, ets) in enumerate(make_records(
-                seed, c["records"], c["fields"], c["field_length"],
-                c["expired_share"], now), 1):
+        for n, (hk, sk, value, ets) in enumerate(
+                table_rows(c, seed, now), 1):
             if hk != prev_hk:       # rows of one record come together
                 prev_hk, p = hk, partition_of(hk, c["partitions"])
-            if ets == 0:
+            if ets == 0 or ets > now:
                 first.setdefault(p, hk)
                 last[p] = hk
+                if ets and ets < self.next_expiry:
+                    self.next_expiry = ets
             if fault_mod.drops_loaded_row(fault, n):
                 continue    # acknowledged to the reference, never stored
             per_pidx[p].append(
@@ -223,7 +248,9 @@ class Cluster:
         servers = [r.server for rs in self.replicas_of for r in rs]
         for srv in servers:
             srv.flush()
-        compact_partitions_parallel(servers)
+        age = c.get("load_compaction_age_s")
+        compact_partitions_parallel(
+            servers, **({} if age is None else {"now": now - age}))
         return [hk for p in sorted(first) for hk in (first[p], last[p])]
 
     def decree_spread(self) -> int:
@@ -379,11 +406,15 @@ def run_cell(cell: str, config: dict, traffic: dict, seed: int,
         t_verify = time.perf_counter()
         spread = cluster.decree_spread()
         model = Model(config["partitions"])
-        for row in make_records(seed, config["records"], config["fields"],
-                                config["field_length"],
-                                config["expired_share"], load_now):
+        for row in table_rows(config, seed, load_now):
             model.put(*row)
         now_ts = epoch_now()
+        if cluster.next_expiry <= now_ts:
+            # the replies sent before it saw the row, the later ones
+            # not: one clock cannot judge both
+            raise RuntimeError(
+                f"a loaded row expired at {cluster.next_expiry}, inside the "
+                f"run (loaded at {load_now}, verified at {now_ts})")
         attempted = failed = wrong = 0
         latencies = {"read": [], "write": []}
         done_by_kind = dict.fromkeys(kinds[:len(traffic["ops"])], 0)
