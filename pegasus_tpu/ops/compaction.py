@@ -13,7 +13,11 @@ Parity: KeyWithTTLCompactionFilter::Filter
    KEEP here, the opposite of the scan path's reject).
 
 Evaluated for a whole columnar batch in one XLA program, vs the reference's
-per-record scalar Filter() callback during RocksDB compaction.
+per-record scalar Filter() callback during RocksDB compaction. A pass with
+no user rules over compressed blocks reads no key bytes: the engine hands
+a pipeline window's expire_ts and hash_lo columns to ttl_filter_window on
+the device where placement_verdict("ttl") says "device", and evaluates
+each block with encoded_drop_mask on the host where it says "host-XLA".
 """
 
 from __future__ import annotations
@@ -218,6 +222,71 @@ def encoded_drop_mask(enc, now: int, default_ttl: int, pidx: int,
         drop = drop | ((np.asarray(enc.hash_lo) & pv)
                        != np.uint32(pidx & 0xFFFFFFFF))
     return drop, (new_ets if want_ets else None)
+
+
+@functools.partial(jax.jit, static_argnames=("validate_hash", "want_ets"))
+def ttl_filter_window(expire_ts, hash_lo, rows, now, default_ttl, pidx,
+                      partition_version, validate_hash: bool,
+                      want_ets: bool):
+    """encoded_drop_mask over a pipeline window's blocks at once, on
+    the device: `expire_ts` / `hash_lo` are the blocks' columns
+    stacked and padded to a power-of-two bucket (`hash_lo` a
+    placeholder of one element unless `validate_hash`), `rows` the
+    real rows at their head. No key byte is read. Returns the drop
+    mask bit-packed, and the rewritten expire_ts where `want_ets`."""
+    with jax.named_scope("pegasus_compact_ttl_filter"):
+        now = jnp.asarray(now, jnp.uint32)
+        default_ttl = jnp.asarray(default_ttl, jnp.uint32)
+        new_ets = jnp.where((default_ttl != 0) & (expire_ts == 0),
+                            now + default_ttl, expire_ts)
+        drop = ttl_expired(new_ets, now)
+        if validate_hash:
+            pv = jnp.asarray(partition_version, jnp.uint32)
+            drop = drop | ((hash_lo & pv) != jnp.asarray(pidx, jnp.uint32))
+        drop = drop & (jnp.arange(expire_ts.shape[0]) < rows)
+        packed = jnp.packbits(drop)
+        return (packed, new_ets) if want_ets else (packed,)
+
+
+def ttl_filter_submit(blocks, now: int, default_ttl: int, pidx: int,
+                      partition_version: int, validate_hash: bool,
+                      want_ets: bool):
+    """Dispatch ttl_filter_window over `blocks` ([(tag, block)], any
+    block with `count`, `expire_ts` and `hash_lo`) without waiting.
+    Returns the token ttl_filter_drain takes."""
+    spans, pos = [], 0
+    for tag, blk in blocks:
+        spans.append((tag, pos, blk.count))
+        pos += blk.count
+    cap = _row_bucket(pos)
+    ets = np.zeros(cap, dtype=np.uint32)
+    hash_lo = np.zeros(cap if validate_hash else 1, dtype=np.uint32)
+    for (_tag, lo, n), (_t, blk) in zip(spans, blocks):
+        ets[lo:lo + n] = blk.expire_ts
+        if validate_hash:
+            hash_lo[lo:lo + n] = blk.hash_lo
+    out = ttl_filter_window(
+        ets, hash_lo, np.int32(pos), np.uint32(now & 0xFFFFFFFF),
+        np.uint32(default_ttl & 0xFFFFFFFF), np.uint32(pidx & 0xFFFFFFFF),
+        np.uint32(max(partition_version, 0) & 0xFFFFFFFF),
+        validate_hash=validate_hash, want_ets=want_ets)
+    # the two columns and five scalars up; the packed mask and the
+    # rewritten expire_ts back
+    note_filter_program(cap, 4 * (cap + hash_lo.size + 5) + cap // 8
+                        + (4 * cap if want_ets else 0))
+    return spans, cap, out
+
+
+def ttl_filter_drain(token):
+    """Wait for one ttl_filter_submit and yield (tag, drop, new_ets or
+    None) a block, as encoded_drop_mask gives them."""
+    spans, cap, out = token
+    fetched = jax.device_get(out)
+    drop_all = np.unpackbits(fetched[0], count=cap).astype(bool)
+    ets_all = fetched[1] if len(fetched) > 1 else None
+    for tag, lo, n in spans:
+        yield (tag, drop_all[lo:lo + n],
+               None if ets_all is None else ets_all[lo:lo + n])
 
 
 def mesh_compact_step(keys, key_len, hashkey_len, expire_ts, present,

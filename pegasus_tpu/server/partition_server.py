@@ -147,6 +147,11 @@ _SCAN_ROWS_EVALUATED = METRICS.entity(
     "storage", "node").counter("scan_rows_evaluated")
 _SCAN_ROWS_RETURNED = METRICS.entity(
     "storage", "node").counter("scan_rows_returned")
+# rows a read examined and skipped as expired, scan and point paths
+# alike: the node-wide twin of each replica's abnormal_read_count
+# (upstream's read-side expire counter)
+_SCAN_ROWS_EXPIRED = METRICS.entity(
+    "storage", "node").counter("scan_rows_expired")
 _OVERLAY_ROWS_WALKED = METRICS.entity(
     "storage", "node").counter("overlay_rows_walked")
 _SCAN_MERGE_PATH_REQUESTS = METRICS.entity(
@@ -604,6 +609,7 @@ class PartitionServer:
                 trigger = parsed
             else:
                 setattr(self, attr, parsed)
+        self.write_service.default_ttl = self._default_ttl
         if full_set:
             self.app_envs = dict(envs)
         else:
@@ -776,6 +782,11 @@ class PartitionServer:
             import traceback
 
             traceback.print_exc()
+
+    def _note_expired(self, rows: int = 1) -> None:
+        """`rows` a read examined and skipped as expired."""
+        self._abnormal_reads.increment(rows)
+        _SCAN_ROWS_EXPIRED.increment(rows)
 
     def _apply_usage_scenario(self, scenario: str) -> None:
         """Parity: the usage-scenario dynamic tuning
@@ -1011,7 +1022,7 @@ class PartitionServer:
             else:
                 value, ets = hit
                 if check_if_ts_expired(now, ets):
-                    self._abnormal_reads.increment()
+                    self._note_expired()
                     if pc is not None:
                         pc.expired_rows += 1
                     status = int(StorageStatus.NOT_FOUND)
@@ -1052,7 +1063,7 @@ class PartitionServer:
             return int(StorageStatus.NOT_FOUND), 0
         _, ets = hit
         if check_if_ts_expired(now, ets):
-            self._abnormal_reads.increment()
+            self._note_expired()
             return int(StorageStatus.NOT_FOUND), 0
         return int(StorageStatus.OK), (ets - now) if ets > 0 else -1
 
@@ -1086,7 +1097,7 @@ class PartitionServer:
                 continue
             value, ets = hit
             if check_if_ts_expired(now, ets):
-                self._abnormal_reads.increment()
+                self._note_expired()
                 continue
             data = extract_user_data(self.data_version, value)
             resp.data.append(FullData(fk.hash_key, fk.sort_key, data))
@@ -1898,7 +1909,7 @@ class PartitionServer:
                 bytes_out += size
                 out.append(resp)
         if expired_total:
-            self._abnormal_reads.increment(expired_total)
+            self._note_expired(expired_total)
         self.cu.add_read_units(cu_total)
         self.workload.note_point(len(ops), len(uniq), vsizes)
         pc = state.get("perf")
@@ -2063,6 +2074,7 @@ class PartitionServer:
             self._register_flavor(validate_hash, filter_key,
                                   time.monotonic())
 
+        examined = 0
         for window, after in _scan_windows(sorted_runs, start_key,
                                            stop_key, limiter):
             keeps = self._static_keep_window(
@@ -2071,11 +2083,12 @@ class PartitionServer:
             stopped = False
             for (ckey, blk, lo, hi), static_keep in zip(window, keeps):
                 n = blk.count
+                examined += hi - lo
                 ets = blk.expire_ts
                 alive = host_alive_mask(ets, now)
                 expired = int(np.count_nonzero(~alive[lo:hi]))
                 if expired:
-                    self._abnormal_reads.increment(expired)
+                    self._note_expired(expired)
                 keep = static_keep[:n] & alive
                 if value_filter is not None:
                     # the pushdown value leg joins the mask algebra:
@@ -2102,6 +2115,15 @@ class PartitionServer:
                         stop_early = True
                         break
                 if stop_early or not limiter.valid():
+                    # the limiter counts a window's rows as they enter
+                    # it; a one-page read that has found no row yet
+                    # reads on until it has examined that many itself
+                    # (rows expired or filtered out), since an empty
+                    # page reads as the end of its range
+                    if (one_page and not out and not stop_early
+                            and not limiter.time_exceeded()
+                            and not limiter.count_exceeded(examined)):
+                        continue
                     if not stop_early:
                         resume_key = _after(blk.key_at(n - 1))
                     exhausted = False
@@ -2129,7 +2151,7 @@ class PartitionServer:
             partition_version=self.partition_version)
         expired = int(np.asarray(masks.expired).sum())
         if expired:
-            self._abnormal_reads.increment(expired)
+            self._note_expired(expired)
         return np.asarray(masks.keep)
 
     def on_multi_get(self, req: MultiGetRequest) -> MultiGetResponse:
@@ -2184,7 +2206,7 @@ class PartitionServer:
                     continue
                 value, ets = hit
                 if check_if_ts_expired(now, ets):
-                    self._abnormal_reads.increment()
+                    self._note_expired()
                     continue
                 data = (b"" if req.no_value
                         else extract_user_data(self.data_version, value))
@@ -2524,7 +2546,7 @@ class PartitionServer:
                 if mesh is not None:
                     state = mesh["agg_state"]
                     if mesh["expired"]:
-                        self._abnormal_reads.increment(mesh["expired"])
+                        self._note_expired(mesh["expired"])
                     if tracer is not None:
                         tracer.add_point("block_scan")
                         tracer.add_point("pushdown")
@@ -2568,7 +2590,7 @@ class PartitionServer:
                     alive = host_alive_mask(blk.expire_ts, now)
                     expired = int(np.count_nonzero(~alive[lo:hi]))
                     if expired:
-                        self._abnormal_reads.increment(expired)
+                        self._note_expired(expired)
                     keep = static_keep[:n] & alive
                     if vf is not None:
                         vmask = self._value_mask(ckey, blk, vf)
@@ -3338,6 +3360,14 @@ class PartitionServer:
                         resume_key = _after(key)
                         stop_early = True
                         break
+            if not kvs and frontier is not None:
+                # a capped plan none of whose rows is live (all expired,
+                # say): an empty page reads as the end of the range, so
+                # the request is served by the per-request path, which
+                # reads on past them under the iteration limit
+                out.append(self._serve_scan_batch(req, start_key,
+                                                  stop_key))
+                continue
             if stop_early:
                 exhausted = False
             elif frontier is not None:  # a capped plan
@@ -3368,7 +3398,7 @@ class PartitionServer:
         # batch-accumulated accounting: one metrics/capacity call per
         # state, not per request (identical totals)
         if total_expired:
-            self._abnormal_reads.increment(total_expired)
+            self._note_expired(total_expired)
         self.cu.add_read_units(total_read_cu)
         # mask-evaluated rows = every row of every unique planned block
         # (the kernels see whole blocks); survivors vs evaluated is the
@@ -3452,7 +3482,7 @@ class PartitionServer:
             if value is TOMBSTONE:
                 return None  # shadows the base
             if check_if_ts_expired(now, ets):
-                self._abnormal_reads.increment()
+                self._note_expired()
                 return None  # expired: hidden AND shadows the base
             if validate and not check_key_hash(key, self.pidx,
                                                self.partition_version):

@@ -49,8 +49,11 @@ class RangeReadLimiter:
     def iteration_count(self) -> int:
         return self._count
 
-    def count_exceeded(self) -> bool:
-        return self._max_count > 0 and self._count >= self._max_count
+    def count_exceeded(self, count: int | None = None) -> bool:
+        """Whether the rows counted, or `count` rows, use up the
+        budget."""
+        n = self._count if count is None else count
+        return self._max_count > 0 and n >= self._max_count
 
     def time_exceeded(self) -> bool:
         return (self._threshold_ns > 0 and

@@ -27,7 +27,11 @@ the only `now`-dependent predicate — is applied host-side from the
 block's expire_ts column at assembly time (ops/predicates.py
 static_block_predicate). A block therefore needs exactly one device
 evaluation in its lifetime, and steady-state serving performs zero
-device round-trips.
+device round-trips. Each row a read skips as expired counts in
+`storage`/`scan_rows_expired`. Dropping expired rows from disk is the
+manual compaction's: a pass with no user rules filters TTL on the
+device where placement_verdict("ttl") says so, else on the host
+(StorageEngine.manual_compact).
 """
 
 from __future__ import annotations

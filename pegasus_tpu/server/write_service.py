@@ -18,6 +18,19 @@ primary-assigned timestamp) so every replica writes identical value bytes
 Batching rule parity (mutation.cpp:390,553): multiple put/remove-class
 requests may share one mutation; atomic ops (incr/cas/cam) never batch
 with anything else.
+
+Default TTL (parity: rocksdb_wrapper::db_expire_ts): in a table whose
+`default_ttl` env is non-zero, every row a client write stores without
+a TTL (expire_ts 0) is stored with now + default_ttl, in the value
+header and the expire_ts column alike: put, multi_put, the row an incr
+writes (its TTL after incr's own rule: kept, reset or cleared), the row
+check_and_set sets and each put of check_and_mutate. `now` is the
+applying replica's own clock, as upstream's db_expire_ts reads it on
+each replica, and the clock reads judge expiry by (the mutation's
+timestamp is the primary's clock, which a simulated cluster runs on
+simulated time), so replicas store the same expiry to within the
+seconds their applies lie apart. A duplicated write keeps the
+expire_ts its origin stored.
 """
 
 from __future__ import annotations
@@ -134,6 +147,9 @@ class WriteService:
         # through — standalone AND replicated — so the op-mix/batch-size
         # profile feeds here exactly once per applied mutation
         self.workload = None
+        # the table env `default_ttl` in seconds, 0 for none (kept by
+        # PartitionServer.update_app_envs)
+        self.default_ttl = 0
 
     # -- helpers --------------------------------------------------------
 
@@ -145,6 +161,13 @@ class WriteService:
                   else int(time.time() * 1_000_000))
             timetag = generate_timetag(ts, self.cluster_id, False)
         return generate_value(self.data_version, user_data, expire_ts, timetag)
+
+    def _db_expire_ts(self, expire_ts: int) -> int:
+        """The expire_ts a client write stores a row with: its own, or
+        now + default_ttl where it has none and the table a default."""
+        if expire_ts or not self.default_ttl:
+            return expire_ts
+        return epoch_now() + self.default_ttl
 
     def _visible(self, key: bytes, now: int
                  ) -> Optional[Tuple[bytes, int]]:
@@ -186,6 +209,7 @@ class WriteService:
     def translate_put(self, key: bytes, user_data: bytes, expire_ts: int,
                       timestamp_us: Optional[int] = None
                       ) -> List[WriteBatchItem]:
+        expire_ts = self._db_expire_ts(expire_ts)
         value = self._make_value(user_data, expire_ts, timestamp_us)
         return [WriteBatchItem(OP_PUT, key, value, expire_ts)]
 
@@ -200,6 +224,9 @@ class WriteService:
         op in a mutation shares the primary-assigned timestamp, so the
         per-op sweep produced identical tags anyway) — byte-identical
         to translate_put called per op."""
+        if self.default_ttl:
+            default_ets = self._db_expire_ts(0)
+            reqs = [(key, ud, ets or default_ets) for key, ud, ets in reqs]
         timetag = 0
         if self.data_version >= 1:
             ts = (timestamp_us if timestamp_us is not None
@@ -220,7 +247,8 @@ class WriteService:
                             ) -> Tuple[int, List[WriteBatchItem]]:
         if not req.kvs:
             return int(StorageStatus.INVALID_ARGUMENT), []
-        expire_ts = expire_ts_from_ttl(req.expire_ts_seconds, now)
+        expire_ts = self._db_expire_ts(
+            expire_ts_from_ttl(req.expire_ts_seconds, now))
         items = [
             WriteBatchItem(
                 OP_PUT, generate_key(req.hash_key, kv.key),
@@ -323,8 +351,9 @@ class WriteService:
             if m.operation == MutateOperation.MO_DELETE:
                 items.append(WriteBatchItem(OP_DEL, key))
             else:
-                ets = (expire_ts_from_ttl(m.set_expire_ts_seconds, now)
-                       if m.set_expire_ts_seconds > 0 else 0)
+                ets = self._db_expire_ts(
+                    expire_ts_from_ttl(m.set_expire_ts_seconds, now)
+                    if m.set_expire_ts_seconds > 0 else 0)
                 items.append(WriteBatchItem(
                     OP_PUT, key, self._make_value(m.value, ets, timestamp_us),
                     ets))

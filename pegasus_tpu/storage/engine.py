@@ -13,9 +13,10 @@ knows exactly which decree it contains. Here:
   durable (replay contract preserved).
 - boot: recover last_flushed_decree = max over SST metas, then replay WAL
   frames with decree > last_flushed_decree into the memtable.
-- manual_compact(): full merge through the device TTL/stale-split filter
-  (ops/compaction.compaction_filter_block) — the manual-compaction path
-  (src/server/pegasus_manual_compact_service.h:48).
+- manual_compact(): full compaction through the TTL / default-TTL /
+  stale-split filter and the table's user rules — the manual-compaction
+  path (src/server/pegasus_manual_compact_service.h:48); see its
+  docstring for where each kind of pass evaluates the filter.
 """
 
 from __future__ import annotations
@@ -129,6 +130,11 @@ class StorageEngine:
         self._ev_blocks_chained = ev.counter("compact_blocks_chained")
         self._ev_blocks_spliced = ev.counter("compact_blocks_spliced")
         self._ev_overlay_rows = ev.counter("compact_overlay_rows")
+        # the blocks of rule-less passes over compressed blocks whose
+        # filter ran as ops/compaction.ttl_filter_window on the device,
+        # and as encoded_drop_mask on the host
+        self._ev_ttl_device_blocks = ev.counter("compact_ttl_device_blocks")
+        self._ev_ttl_host_blocks = ev.counter("compact_ttl_host_blocks")
 
         # replay WAL beyond the flushed watermark
         self._wal_path = os.path.join(data_dir, "wal.log")
@@ -370,7 +376,10 @@ class StorageEngine:
             compaction_eval_submit,
             encoded_drop_mask,
             rules_workload,
+            ttl_filter_drain,
+            ttl_filter_submit,
         )
+        from pegasus_tpu.ops.placement import placement_verdict
         from pegasus_tpu.storage.compact_governor import GOVERNOR
         from pegasus_tpu.storage.compact_pipeline import (
             CompactPipeline,
@@ -421,11 +430,17 @@ class StorageEngine:
         # direct compute on compressed blocks: a ruleset that touches
         # no key bytes (TTL + default-TTL rewrite + stale-split)
         # evaluates straight off the encoded block's raw predicate
-        # columns — no key-matrix rebuild, no value-heap inflate, no
-        # device program; unchanged blocks then copy verbatim
+        # columns — no key-matrix rebuild, no value-heap inflate;
+        # unchanged blocks then copy verbatim. A window's such blocks
+        # go to the device as one program where the placement policy
+        # sends compute-trivial programs there, else each is
+        # evaluated on the host (encoded_drop_mask)
         def direct(run) -> bool:
             return (operations is None
                     and getattr(run, "codec", None) is not None)
+
+        ttl_on_device = (operations is None
+                         and placement_verdict("ttl") == "device")
 
         def load(entry):
             """READ stage: one entry's blocks off disk, paced by the
@@ -468,17 +483,28 @@ class StorageEngine:
                 else:
                     # whole window pre-filtered on the mesh: nothing
                     # in flight, eager-forward straight to WRITE
-                    return items, [], served
+                    return items, [], served, None
             blocks = [((run, i), blk, pidx)
                       for run, i, blk, is_direct, _n in items
                       if not is_direct]
             host_done = {}
-            for run, i, blk, is_direct, _n in items:
-                if is_direct:
-                    host_done[(run, i)] = encoded_drop_mask(
+            direct_blocks = [((run, i), blk)
+                             for run, i, blk, is_direct, _n in items
+                             if is_direct]
+            ttl_pend = None
+            if direct_blocks and ttl_on_device:
+                ttl_pend = ttl_filter_submit(
+                    direct_blocks, now_s, default_ttl, pidx,
+                    partition_version, do_validate,
+                    want_ets=ttl_may_change)
+                self._ev_ttl_device_blocks.increment(len(direct_blocks))
+            elif direct_blocks:
+                for tag, blk in direct_blocks:
+                    host_done[tag] = encoded_drop_mask(
                         blk, now_s, default_ttl, pidx,
                         partition_version, do_validate,
                         want_ets=ttl_may_change)
+                self._ev_ttl_host_blocks.increment(len(direct_blocks))
             # placed only where a program is dispatched: the link probe
             # behind it (once a process: 16 MiB each way) is not a
             # host-direct compaction's to pay
@@ -489,19 +515,26 @@ class StorageEngine:
                     workload=rules_workload(operations)),
                 want_ets=ttl_may_change) if blocks else []
             mark("compact_filter_submit")
-            return items, pend, host_done
+            return items, pend, host_done, ttl_pend
+
+        def in_flight(token) -> bool:
+            """Whether a window's masks wait on a device program."""
+            return bool(token[1]) or token[3] is not None
 
         def drain_window(token):
             """FILTER stage phase 2: materialize one window's masks."""
-            items, pend, host_done = token
+            items, pend, host_done, ttl_pend = token
             got = {}
             for tag, drop, new_ets in compaction_eval_drain(
                     pend, want_ets=ttl_may_change):
                 got[tag] = (drop, new_ets)
+            if ttl_pend is not None:
+                for tag, drop, new_ets in ttl_filter_drain(ttl_pend):
+                    got[tag] = (drop, new_ets)
             out = []
             for run, i, blk, is_direct, nbytes in items:
-                # host_done holds both direct-on-encoded masks and
-                # mesh-served ones; device programs land in got
+                # host_done holds both host direct-on-encoded masks
+                # and mesh-served ones; device programs land in got
                 m = host_done.get((run, i))
                 if m is None:
                     m = got[(run, i)]
@@ -534,7 +567,7 @@ class StorageEngine:
                 # submit has no in-flight device program to hide:
                 # forward it immediately instead of holding the
                 # one-window lookahead
-                eager=lambda token: not token[1])
+                eager=lambda token: not in_flight(token))
             results = pipe.results()
         else:
             def inline_results():
@@ -554,7 +587,7 @@ class StorageEngine:
                     if pending is not None:
                         yield from drain_window(pending)
                         pending = None
-                    if not token[1]:
+                    if not in_flight(token):
                         yield from drain_window(token)
                     else:
                         pending = token
@@ -578,7 +611,21 @@ class StorageEngine:
                        rules_filter=None,
                        now: Optional[int] = None,
                        publish_lock=None) -> None:
-        """Full compaction with the device TTL/stale-split filter.
+        """Full compaction through the TTL filter: the default-TTL
+        rewrite, the user rules, expiry and the stale-split drop.
+
+        Where the filter runs: a pass under a parsed ruleset, or over
+        uncompressed blocks, evaluates the fused program
+        (ops/compaction.make_compaction_eval) on the device that
+        choose_eval_device picks for its workload class. A pass with
+        no rules over compressed blocks (the default codec) reads only
+        their expire_ts and hash_lo columns: one ttl_filter_window
+        program a pipeline window on the device where
+        placement_verdict("ttl") says "device", encoded_drop_mask
+        block by block on the host where it says "host-XLA" (a host
+        without an accelerator, or a far link). Either way the output
+        files are the same bytes. The per-record merge path below
+        evaluates compaction_filter_block.
 
         `rules_filter(keys, expire_ts, now) -> (drop, new_ets)` is the
         optional user-specified compaction hook (compaction_rules.py),
@@ -717,8 +764,10 @@ class StorageEngine:
         stands, would compile where it first dispatches it: on the
         block path (the path `manual_compact` takes once the run's
         flush has frozen the memtable) the fused program at the
-        buckets of its windows, on the per-record path the filter
-        programs at the buckets of its batches; the placement probe.
+        buckets of its windows (a rule-less pass over compressed
+        blocks: the TTL program, where it is placed on the device), on
+        the per-record path the filter programs at the buckets of its
+        batches; the placement probe.
         A window's rows are known to within the overlay's (an overlay
         row adds a row to a spliced block, replaces one, or takes one):
         every bucket in that reach is compiled. Rows of the store's
@@ -733,8 +782,10 @@ class StorageEngine:
             compaction_eval_drain,
             compaction_eval_submit,
             rules_workload,
+            ttl_filter_drain,
+            ttl_filter_submit,
         )
-        from pegasus_tpu.storage.compact_pipeline import pipeline_window
+        from pegasus_tpu.ops.placement import placement_verdict
 
         lsm = self.lsm
         first = next((t for t in list(lsm.l1_runs) + list(lsm.l0)
@@ -747,7 +798,8 @@ class StorageEngine:
         snap = (lsm.bulk_compact_snapshot(frozen=True)
                 if rules_filter is None or operations is not None else None)
         n_mem = len(lsm.memtable)   # the run's flush makes it an L0
-        batches, windows = set(), set()
+        batches, windows, ttl_windows = set(), set(), set()
+        compressed = getattr(first, "codec", None) is not None
         if snap is None:
             rows = n_mem + sum(t.total_count
                                for t in lsm.l0 + lsm.l1_runs)
@@ -756,20 +808,28 @@ class StorageEngine:
         # the block path evaluates on the device with a parsed ruleset,
         # or with none over an uncompressed store
         elif operations is not None or (
-                rules_filter is None and getattr(first, "codec", None) is None):
-            counts = [bm.count for t in snap.chain for bm in t.blocks]
-            k = n_mem + sum(t.total_count for t in snap.overlay)
-            win = pipeline_window()
-            for off in range(0, max(len(counts), 1), win):
-                n = sum(counts[off:off + win])
+                rules_filter is None and not compressed):
+            for n, k in self._window_rows(snap, n_mem):
                 bucket = _row_bucket(max(n - k, 1))
                 while bucket <= _row_bucket(n + k):
                     windows.add(bucket)
                     bucket <<= 1
+        # and with none over a compressed store where the TTL program
+        # is placed on the device: at every bucket up to the one above
+        # the largest window's, since a pass that drops rows leaves the
+        # next pass's windows smaller, and the rows written while the
+        # run waits in the pool can carry a window past its bucket
+        elif placement_verdict("ttl") == "device":
+            top = 2 * max(_row_bucket(n + k)
+                          for n, k in self._window_rows(snap, n_mem))
+            bucket = _row_bucket(1)
+            while bucket <= top:
+                ttl_windows.add(bucket)
+                bucket <<= 1
         shapes = (rules_filter, first.blocks[0].key_width,
                   frozenset(_row_bucket(n) for n in batches),
-                  frozenset(windows), do_validate, bool(default_ttl),
-                  getattr(first, "codec", None) is not None)
+                  frozenset(windows), frozenset(ttl_windows), do_validate,
+                  bool(default_ttl), compressed)
         if shapes in _WARMED:
             return
         blk = first.read_block(0)
@@ -795,7 +855,26 @@ class StorageEngine:
                     now_s, default_ttl, partition_version, do_validate,
                     operations=operations, eval_device=eval_device,
                     want_ets=ttl_may_change), want_ets=ttl_may_change))
+        for bucket in ttl_windows:
+            list(ttl_filter_drain(ttl_filter_submit(
+                [(i, blk) for i in range(max(1, bucket // blk.count))],
+                now_s, default_ttl, pidx, partition_version, do_validate,
+                want_ets=bool(default_ttl))))
         _WARMED.add(shapes)
+
+    @staticmethod
+    def _window_rows(snap, n_mem: int):
+        """[(rows, overlay rows)] a pipeline window of the block path
+        over `snap`: the chain blocks' rows of each window, and the
+        rows the overlay (and the memtable the run's flush adds to
+        it) may add to or take from any window."""
+        from pegasus_tpu.storage.compact_pipeline import pipeline_window
+
+        counts = [bm.count for t in snap.chain for bm in t.blocks]
+        k = n_mem + sum(t.total_count for t in snap.overlay)
+        win = pipeline_window()
+        return [(sum(counts[off:off + win]), k)
+                for off in range(0, max(len(counts), 1), win)]
 
     def _compact_with_epilogue(self, body,
                                advance_watermark: bool = True) -> None:

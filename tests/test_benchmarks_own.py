@@ -1,6 +1,8 @@
 """The benchmark's own tests of the cells `prefix_scan.p64r3`
-(benchmarks/tests/test_prefix_scan_cell.py, test_prefix_scan_steps.py)
-and `ycsb_f.p64r3` (test_ycsb_f_cell.py), and of the trace reductions
+(benchmarks/tests/test_prefix_scan_cell.py, test_prefix_scan_steps.py),
+`ycsb_f.p64r3` (test_ycsb_f_cell.py) and `ttl_compact.p64r3`
+(test_ttl_cell.py), of how a configuration's table is made
+(test_table_loader.py), and of the trace reductions
 (test_trace_reduce.py, test_trace_layers.py) as cases of the tier-1
 run, which collects `tests/` alone. The modules
 stay runnable by hand as benchmarks/README.md says; their tests and
@@ -20,6 +22,8 @@ from benchmarks.tests.pscan_later_metrics import (  # noqa: F401
 )
 from benchmarks.tests.test_prefix_scan_cell import *  # noqa: F401,F403
 from benchmarks.tests.test_prefix_scan_steps import *  # noqa: F401,F403
+from benchmarks.tests.test_table_loader import *  # noqa: F401,F403
 from benchmarks.tests.test_trace_layers import *  # noqa: F401,F403
 from benchmarks.tests.test_trace_reduce import *  # noqa: F401,F403
+from benchmarks.tests.test_ttl_cell import *  # noqa: F401,F403
 from benchmarks.tests.test_ycsb_f_cell import *  # noqa: F401,F403
