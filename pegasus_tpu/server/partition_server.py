@@ -238,7 +238,7 @@ def _lower_bound(blk, key: bytes) -> int:
 
 
 def _scan_windows(sorted_runs, start_key: bytes, stop_key: Optional[bytes],
-                  limiter: RangeReadLimiter):
+                  limiter: RangeReadLimiter, one_page: bool):
     """The look-ahead windows of a ranged read over the sorted L1 runs,
     in key order: lists of up to LOOKAHEAD (ckey, blk, lo, hi). A
     boundary block's rows outside [start_key, stop_key) are trimmed off
@@ -247,7 +247,13 @@ def _scan_windows(sorted_runs, start_key: bytes, stop_key: Optional[bytes],
     as the block enters a window. Fetching one window past the stop
     point costs unused masks, never correctness. Each window comes with
     the range's blocks after it (_blocks_after): what a stack's padding
-    may be filled with."""
+    may be filled with.
+
+    A read that may page on (`one_page` false) ends a window at the
+    block that uses up the limiter's count budget: its page ends after
+    that block, and the stack fill gives it the device's look-ahead, so
+    no block is fetched, counted or looked up for a page that will not
+    consume it."""
 
     def ranged_blocks():
         for ri, run in enumerate(sorted_runs):
@@ -269,6 +275,8 @@ def _scan_windows(sorted_runs, start_key: bytes, stop_key: Optional[bytes],
                 hi = _lower_bound(blk, stop_key)
             limiter.add_count(hi - lo)
             window.append(((run.path, bm.offset), blk, lo, hi))
+            if not one_page and limiter.count_exceeded():
+                break
         if not window:
             return
         yield window, _blocks_after(sorted_runs, ri, bm, stop_key)
@@ -2076,7 +2084,7 @@ class PartitionServer:
 
         examined = 0
         for window, after in _scan_windows(sorted_runs, start_key,
-                                           stop_key, limiter):
+                                           stop_key, limiter, one_page):
             keeps = self._static_keep_window(
                 window, validate_hash, filter_key,
                 fill=None if one_page else after)
@@ -2116,11 +2124,13 @@ class PartitionServer:
                         break
                 if stop_early or not limiter.valid():
                     # the limiter counts a window's rows as they enter
-                    # it; a one-page read that has found no row yet
+                    # it. A paging read's window ends at the block that
+                    # uses up the budget, so it reads to its window's
+                    # end; a one-page read that has found no row yet
                     # reads on until it has examined that many itself
                     # (rows expired or filtered out), since an empty
                     # page reads as the end of its range
-                    if (one_page and not out and not stop_early
+                    if (not (one_page and out) and not stop_early
                             and not limiter.time_exceeded()
                             and not limiter.count_exceeded(examined)):
                         continue
@@ -2578,8 +2588,9 @@ class PartitionServer:
                         tracer.add_point("assemble")
                     return resp
 
+            examined = 0
             for window, after in _scan_windows(sorted_runs, start_key,
-                                               stop, limiter):
+                                               stop, limiter, req.one_page):
                 keeps = self._static_keep_window(
                     window, validate, filter_key,
                     fill=None if req.one_page else after)
@@ -2587,6 +2598,7 @@ class PartitionServer:
                 for (ckey, blk, lo, hi), static_keep in zip(window,
                                                             keeps):
                     n = blk.count
+                    examined += hi - lo
                     alive = host_alive_mask(blk.expire_ts, now)
                     expired = int(np.count_nonzero(~alive[lo:hi]))
                     if expired:
@@ -2608,7 +2620,11 @@ class PartitionServer:
                                 sel, heap=blk.value_heap,
                                 value_offs=blk.value_offs, hdr=hdr,
                                 key_at=blk.key_at)
-                    if not limiter.valid():
+                    # a paging read's window ends at the block that
+                    # uses up the budget: it reads to its window's end
+                    if not limiter.valid() and (
+                            req.one_page or limiter.time_exceeded()
+                            or limiter.count_exceeded(examined)):
                         resume_key = _after(blk.key_at(n - 1))
                         exhausted = False
                         stopped = True

@@ -5,8 +5,9 @@ blocks with the range's next blocks whose mask is not cached, so a
 partition of b blocks takes ceil(b / STACK_CHUNK) mask programs a fresh
 pattern where it took one a page. Against the plain reference
 (benchmarks/reference_prefix.py), on one partition of 32-row blocks
-whose pages end after one block, as the cell's 1,024-row blocks do
-under the 1,000-row iteration budget.
+whose pages end after one block under a 32-row iteration budget. And
+where a paging read's window and page end: at the block whose rows use
+up the budget, each block entered, counted and looked up once.
 """
 
 import math
@@ -19,7 +20,7 @@ from benchmarks.reference_prefix import prefix_rows
 from pegasus_tpu.base.key_schema import generate_key, restore_key
 from pegasus_tpu.ops.predicates import FT_MATCH_PREFIX
 from pegasus_tpu.ops.pushdown import PushdownSpec
-from pegasus_tpu.server.partition_server import PartitionServer
+from pegasus_tpu.server.partition_server import LOOKAHEAD, PartitionServer
 from pegasus_tpu.server.scan_coordinator import STACK_CHUNK, stacked_block_eval
 from pegasus_tpu.server.types import (
     SCAN_CONTEXT_ID_COMPLETED,
@@ -44,14 +45,15 @@ class Moved:
     """What a read moved: mask programs, look-ups, filled blocks."""
 
     NAMES = (("engine", "mask_programs"), ("storage", "mask_cache_miss"),
-             ("storage", "mask_cache_hit"), ("storage", "mask_fill_blocks"))
+             ("storage", "mask_cache_hit"), ("storage", "mask_fill_blocks"),
+             ("storage", "scan_rows_evaluated"))
 
     def __enter__(self):
         self._before = [_counter(*n) for n in self.NAMES]
         return self
 
     def __exit__(self, *exc):
-        (self.programs, self.miss, self.hit, self.fill) = (
+        (self.programs, self.miss, self.hit, self.fill, self.rows) = (
             _counter(*n) - b for n, b in zip(self.NAMES, self._before))
 
 
@@ -189,7 +191,9 @@ def test_no_fill_for_one_page_or_a_window_without_a_miss(p40, case):
         rows, pages = _drain(srv, _req(pattern, one_page=case == "one_page"))
     assert m.fill == 0
     if case == "one_page":
-        assert pages == 1 and m.programs == 1 and m.miss == 8
+        # the guard that a one-page read keeps its window of LOOKAHEAD
+        # blocks: with no fill it is the read's only device look-ahead
+        assert pages == 1 and m.programs == 1 and m.miss == LOOKAHEAD
         assert rows == _want(model, pattern)[:len(rows)]
     else:
         assert (m.programs, m.miss) == (0, 0) and m.hit > 0
@@ -211,8 +215,8 @@ def test_a_fresh_pattern_takes_one_program_a_stack_of_blocks(request,
     assert pages >= b - 8
     assert m.programs == math.ceil(b / STACK_CHUNK)
     assert m.miss + m.fill == b
-    # a window misses once a stack: its first, then one a stack after
-    assert m.miss == 8 + (m.programs - 1)
+    # a paging window is the page's one block: it misses once a stack
+    assert m.miss == m.programs
     with Moved() as again:
         assert _drain(srv, _req(pattern))[0] == rows
     assert (again.programs, again.miss, again.fill) == (0, 0, 0)
@@ -281,3 +285,92 @@ def test_count_pushdown_counts_the_same_with_and_without_a_fill(p21,
     with Moved() as unfilled:
         assert count() == want
     assert unfilled.fill == 0 and unfilled.programs > filled.programs
+
+
+# ---- where a paging read's window and page end --------------------------
+
+
+@pytest.fixture
+def budget():
+    """Sets the iteration budget for one test: a page of a read that
+    pages on ends after the block whose rows use it up."""
+    def set_to(rows):
+        FLAGS.set("pegasus.server", "rocksdb_max_iteration_count", rows)
+    yield set_to
+    set_to(ROWS)
+
+
+def _pages(srv, req):
+    """Every page of a scanner: [(rows, resume key)], the resume key
+    the one the server keeps for the next page, None on the last."""
+    pages = []
+    resp = srv.on_get_scanner(req)
+    while True:
+        assert resp.error == OK
+        rows = [restore_key(kv.key) + (kv.value,) for kv in resp.kvs]
+        if resp.context_id == SCAN_CONTEXT_ID_COMPLETED:
+            pages.append((rows, None))
+            return pages
+        ctx = srv._scan_cache._contexts[resp.context_id]
+        pages.append((rows, ctx.resume_key))
+        resp = srv.on_scan(resp.context_id)
+
+
+def _want_pages(model, pattern, budget_rows):
+    """The reference's pages of a whole-partition read over blocks of
+    ROWS rows each under a budget of `budget_rows`: a page is the
+    blocks whose rows use the budget up and resumes right after the
+    last of them; the page that reaches the range's end with budget
+    left is the last, else an empty page ends the read."""
+    keys = sorted(model.rows[0])        # the store's keys: all on disk
+    step = -(-budget_rows // ROWS) * ROWS
+    want = _want(model, pattern)
+    pages = []
+    for at in range(0, len(keys), step):
+        first, last = keys[at], keys[min(at + step, len(keys)) - 1]
+        rows = [r for r in want if first <= generate_key(*r[:2]) <= last]
+        used_up = at + step <= len(keys)
+        pages.append((rows, last + b"\x00" if used_up else None))
+    if pages[-1][1] is not None:
+        pages.append(([], None))
+    return pages
+
+
+PAGE_BUDGETS = [
+    (ROWS, 1),                          # blocks of the budget's size
+    (ROWS - 7, 1),                      # ... or larger: a block a page
+    (2 * ROWS + 16, 3),                 # the block that uses it up
+    (LOOKAHEAD * ROWS + 1, LOOKAHEAD + 1)]    # past one window
+
+
+@pytest.mark.parametrize("budget_rows, blocks_a_page", PAGE_BUDGETS)
+def test_a_paging_read_ends_its_window_and_page_where_the_budget_ends(
+        p40, budget, budget_rows, blocks_a_page):
+    srv, model = p40
+    b = len(_blocks(srv))
+    budget(budget_rows)
+    pattern = b"user000000"                 # records 0..99: 1,000 rows
+    with Moved() as m:
+        pages = _pages(srv, _req(pattern))
+    assert pages == _want_pages(model, pattern, budget_rows)
+    assert len(pages) == -(-b // blocks_a_page) + (b % blocks_a_page == 0)
+    # each block entered, counted and looked up once
+    assert m.rows == b * ROWS
+    assert m.hit + m.miss == b
+
+
+@pytest.mark.parametrize("budget_rows", [rows for rows, _n in PAGE_BUDGETS])
+def test_a_paging_count_reads_each_block_once(p21, budget, budget_rows):
+    srv, model = p21
+    budget(budget_rows)
+    pattern = b"user0000001"
+    req = _req(pattern, pushdown=PushdownSpec(aggregate="count"))
+    with Moved() as m:
+        resp = srv.on_get_scanner(req)
+        while resp.context_id != SCAN_CONTEXT_ID_COMPLETED:
+            assert resp.error == OK
+            resp = srv.on_scan(resp.context_id)
+    assert resp.error == OK and resp.pushdown_applied
+    assert resp.agg["count"] == len(_want(model, pattern))
+    assert m.rows == len(_blocks(srv)) * ROWS
+    assert m.hit + m.miss == len(_blocks(srv))
